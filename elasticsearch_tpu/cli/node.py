@@ -14,8 +14,10 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextvars
 import os
 import signal
+from concurrent.futures import ThreadPoolExecutor
 
 # Opt-in runtime lockdep witness (ES_TPU_LOCKDEP=1): install BEFORE the
 # node stack imports create their module/instance locks, so a live node
@@ -26,16 +28,27 @@ from ..common import lockdep as _lockdep
 _lockdep.install()
 
 
-def _wrap_handler(handle, owner=None):
+def _wrap_handler(handle, pool, owner=None):
     """Adapt a REST ``handle`` to the HttpServer's 4-tuple form: collect
     the echoed response headers (Trace-Id, X-Opaque-Id) per request.
-    ``owner`` keeps the ``__self__`` link HttpServer.start uses to
-    advertise the real bound address (http_publish_address)."""
-    def handler(method, path, query, body, headers=None):
+    Requests execute on ``pool`` (as ``ClusterNode.start_http`` does):
+    run inline on the event loop they would serialize, and the
+    micro-batcher — whose batch is whatever requests are in flight
+    together — could never see more than one. ``owner`` keeps the
+    ``__self__`` link HttpServer.start uses to advertise the real bound
+    address (http_publish_address)."""
+    async def handler(method, path, query, body, headers=None):
+        # copy_context: context-bound request state (deprecation
+        # warnings, the trace context) follows the request to its thread
+        ctx = contextvars.copy_context()
         rh = {}
-        status, ct, out = handle(method, path, query, body,
-                                 headers=headers, resp_headers=rh)
-        return status, ct, out, rh
+
+        def run():
+            status, ct, out = ctx.run(handle, method, path, query, body,
+                                      headers=headers, resp_headers=rh)
+            return status, ct, out, rh
+
+        return await asyncio.get_running_loop().run_in_executor(pool, run)
     if owner is not None:
         handler.__self__ = owner
     return handler
@@ -60,7 +73,21 @@ def main(argv=None) -> int:
     if args.jax_platform:
         import jax
         jax.config.update("jax_platforms", args.jax_platform)
+    # this process is the one that holds the chip: compiled serving
+    # steps persist across node starts, and a node whose backend came up
+    # on the CPU unasked refuses to start instead of serving host-side
+    from ..common import runtime
+    cache_dir = runtime.enable_compile_cache()
+    devices = runtime.require_accelerator(
+        allow_cpu=args.jax_platform == "cpu")
+    print(f"[{args.name}] jax {devices[0].platform} "
+          f"[{devices[0].device_kind}] x{len(devices)}, compile cache "
+          f"{cache_dir}", flush=True)
     os.makedirs(args.data, exist_ok=True)
+    # one request thread per slot of a full micro-batch
+    from ..search.microbatch import MAX_BATCH
+    pool = ThreadPoolExecutor(max_workers=MAX_BATCH,
+                              thread_name_prefix="es-rest-http")
 
     if args.transport_port is not None and args.seed:
         peers = {}
@@ -71,7 +98,7 @@ def main(argv=None) -> int:
         from ..node.cluster_node import ClusterNode
         node = ClusterNode(args.name, args.host, args.transport_port,
                            peers, args.data)
-        handler = _wrap_handler(node.rest.handle)
+        handler = _wrap_handler(node.rest.handle, pool)
         print(f"[{args.name}] cluster node up: transport "
               f"{args.host}:{args.transport_port}, peers "
               f"{sorted(peers)}")
@@ -81,7 +108,7 @@ def main(argv=None) -> int:
         api = RestAPI(IndicesService(args.data),
                       cluster_name=args.cluster_name,
                       node_name=args.name)
-        handler = _wrap_handler(api.handle, owner=api)
+        handler = _wrap_handler(api.handle, pool, owner=api)
         node = None
 
     from ..rest.http_server import HttpServer
@@ -91,7 +118,7 @@ def main(argv=None) -> int:
                          pass_headers=True)
         await srv.start()
         print(f"[{args.name}] HTTP listening on "
-              f"http://{args.host}:{args.port}")
+              f"http://{args.host}:{args.port}", flush=True)
         stop = asyncio.Event()
         loop = asyncio.get_running_loop()
         for sig in (signal.SIGINT, signal.SIGTERM):
@@ -105,6 +132,7 @@ def main(argv=None) -> int:
     try:
         asyncio.run(serve())
     finally:
+        pool.shutdown(wait=False, cancel_futures=True)
         if node is not None:
             node.stop()
     return 0

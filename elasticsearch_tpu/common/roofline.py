@@ -30,12 +30,12 @@ it. This module closes that loop:
   pattern).
 
 The ceiling resolves once per process (:func:`peak_bandwidth_gbps`):
-``ES_TPU_ROOFLINE_BW_GBPS`` env override, then the
-``roofline.peak_bandwidth_gbps`` cluster setting, then a per-platform
-default (v5e HBM 819 GB/s; CPU a nominal 10 GB/s DDR stream — the
-container measures 1.2-2.0 GB/s numpy streams, so CPU efficiencies
-read 10-20%, which is fine: the health indicator judges windowed DRIFT
-against the session's own watermark, never the absolute level).
+``ES_TPU_ROOFLINE_BW_GBPS`` env override, else the :data:`DEVICE_PEAKS`
+row of the device JAX reports (``device_kind``). A device that is not in
+the table is an error naming the kind, never a default: an efficiency
+against an invented ceiling is worse than none. The CPU row is nominal
+(the health indicator judges windowed DRIFT against the session's own
+watermark, never the absolute level).
 
 Everything here is O(1) per dispatch (a few float ops + two histogram
 observes); estpulint treats this module like ``common/telemetry`` for
@@ -51,8 +51,9 @@ from typing import Dict, Optional, Tuple
 from .settings import CLUSTER_SETTINGS, Setting
 
 __all__ = [
-    "KERNEL_FAMILIES", "peak_bandwidth_gbps",
-    "peak_stream_bandwidth_gbps", "audit", "audit_totals",
+    "KERNEL_FAMILIES", "DEVICE_PEAKS", "UnknownDeviceError",
+    "peak_bandwidth_gbps", "peak_stream_bandwidth_gbps", "audit",
+    "audit_totals",
     "model_bytes_bm25_eager", "model_bytes_bm25_dense",
     "model_bytes_bm25_pruned", "model_bytes_knn_exact",
     "model_bytes_knn_ivf", "model_bytes_agg", "model_bytes_streamed",
@@ -85,14 +86,26 @@ SETTING_EFF_MIN = CLUSTER_SETTINGS.register(
     Setting.int_setting("dispatch_efficiency.min_dispatches", 8,
                         scope="cluster", dynamic=True, min_value=1))
 
-#: per-platform bandwidth ceilings (GB/s) when nothing overrides:
-#: tpu = v5e HBM (ROOFLINE.md machine model); cpu/other = nominal DDR
-_PLATFORM_BW = {"tpu": 819.0, "gpu": 819.0, "cpu": 10.0}
+#: bandwidth ceilings (GB/s) keyed by the ``device_kind`` JAX reports,
+#: each with its source. ``hbm``: device memory, what resident-plane
+#: kernels audit against. ``host_link``: the host→device stream the
+#: warm-tier ``*_streamed`` kernels ride (a per-dispatch ``device_put``).
+DEVICE_PEAKS: Dict[str, dict] = {
+    "TPU v5 lite": {
+        "hbm": 819.0, "host_link": 32.0,
+        "source": "Google Cloud documentation, \"TPU v5e\": 16 GB HBM at "
+                  "819 GB/s per chip; host link nominal PCIe Gen4 x16, "
+                  "not measured"},
+    "cpu": {
+        "hbm": 10.0, "host_link": 10.0,
+        "source": "nominal DDR stream of the test container; no CPU "
+                  "deployment is measured against it"},
+}
 
-#: host→device stream ceilings (GB/s) for the warm-tier ``*_streamed``
-#: kernels: a per-dispatch ``device_put`` rides PCIe/host-DMA, not HBM
-#: (v5e ~32 GB/s host link; CPU "stream" is a memcpy at DDR speed)
-_PLATFORM_STREAM_BW = {"tpu": 32.0, "gpu": 32.0, "cpu": 10.0}
+
+class UnknownDeviceError(LookupError):
+    """The device JAX reports has no row in :data:`DEVICE_PEAKS` and no
+    override names its ceiling."""
 
 
 def _envf(name: str) -> Optional[float]:
@@ -131,57 +144,42 @@ _PEAK_LOCK = threading.Lock()
 _PEAK: Dict[str, float] = {}
 
 
-def _resolve_peak(key: str, env_name: str, table: Dict[str, float]) -> float:
+def _resolve_peak(column: str, env_name: str) -> float:
     with _PEAK_LOCK:
-        v = _PEAK.get(key)
+        v = _PEAK.get(column)
     if v is not None:
         return v
     env = _envf(env_name)
     if env is not None and env > 0:
         v = env
     else:
-        platform = "cpu"
-        try:
-            import jax
-            platform = str(getattr(jax.devices()[0], "platform", "cpu"))
-        except Exception:   # noqa: BLE001 — no backend: CPU ceiling
-            pass
-        v = table.get(platform, table["cpu"])
+        import jax
+        kind = str(jax.devices()[0].device_kind)
+        row = DEVICE_PEAKS.get(kind)
+        if row is None:
+            raise UnknownDeviceError(
+                f"no bandwidth ceiling for device kind [{kind}]: add a "
+                f"sourced row to roofline.DEVICE_PEAKS or set {env_name}")
+        v = float(row[column])
     with _PEAK_LOCK:
-        _PEAK[key] = v
+        _PEAK[column] = v
     return v
 
 
 def peak_bandwidth_gbps() -> float:
-    """The machine's bandwidth ceiling, resolved once per process
-    (env override > platform default; the first audit pays one
-    ``jax.devices()`` probe, every later call is a dict read)."""
-    return _resolve_peak("v", "ES_TPU_ROOFLINE_BW_GBPS", _PLATFORM_BW)
+    """The device's memory-bandwidth ceiling, resolved once per process
+    (env override, else the device's table row; the first audit pays
+    one ``jax.devices()`` probe, every later call is a dict read).
+    Raises :class:`UnknownDeviceError` for a device without a row."""
+    return _resolve_peak("hbm", "ES_TPU_ROOFLINE_BW_GBPS")
 
 
 def peak_stream_bandwidth_gbps() -> float:
     """The host→device stream ceiling the ``*_streamed`` (warm-tier)
     kernels audit against: ``ES_TPU_ROOFLINE_STREAM_GBPS`` env override,
-    then the ``roofline.stream_bandwidth_gbps`` cluster setting, then
-    the platform's host-link default. Same once-per-process resolution
-    as :func:`peak_bandwidth_gbps`."""
-    with _PEAK_LOCK:
-        v = _PEAK.get("stream")
-    if v is not None:
-        return v
-    env = _envf("ES_TPU_ROOFLINE_STREAM_GBPS")
-    if env is None or env <= 0:
-        try:
-            s = float(SETTING_STREAM_BW.default)
-            env = s if s > 0 else None
-        except Exception:   # noqa: BLE001 — settings service optional
-            env = None
-    if env is not None and env > 0:
-        with _PEAK_LOCK:
-            _PEAK["stream"] = env
-        return env
-    return _resolve_peak("stream", "ES_TPU_ROOFLINE_STREAM_GBPS",
-                         _PLATFORM_STREAM_BW)
+    else the device's ``host_link`` column. Same once-per-process
+    resolution as :func:`peak_bandwidth_gbps`."""
+    return _resolve_peak("host_link", "ES_TPU_ROOFLINE_STREAM_GBPS")
 
 
 def _reset_peak_for_tests() -> None:

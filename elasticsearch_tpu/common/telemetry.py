@@ -882,8 +882,10 @@ def _live_array_bytes() -> Tuple[int, int]:
 
 
 def device_stats_doc() -> dict:
-    """The nodes-stats ``device`` section: per-device platform +
-    memory_stats (TPU backends report bytes_in_use / peak_bytes_in_use),
+    """The nodes-stats ``device`` section: per-device platform and
+    device_kind (as JAX reports them — a client that stays off JAX names
+    the device from here) + memory_stats (TPU backends report
+    bytes_in_use / peak_bytes_in_use),
     a live-array byte total via ``jax.live_arrays`` where available, and
     the process-lifetime watermark of that total."""
     doc: dict = {"devices": [], "compiles": {}, "transfer": {}}
@@ -894,8 +896,8 @@ def device_stats_doc() -> dict:
         return {"devices": [], "error": str(e)[:200]}
     live_bytes, peak = _live_array_bytes()
     for d in devs:
-        ent = {"id": int(getattr(d, "id", 0)),
-               "platform": str(getattr(d, "platform", "unknown"))}
+        ent = {"id": int(d.id), "platform": str(d.platform),
+               "device_kind": str(d.device_kind)}
         try:
             ms = d.memory_stats()
             if ms:

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .topk import batched_blockwise_topk
-from .sorted_merge import bm25_merge_candidates
+from .sorted_merge import bm25_merge_candidates, vmap_queries
 
 NEG_INF = float("-inf")
 
@@ -121,11 +121,16 @@ def build_dense_rows(shard: dict, dense_tids: np.ndarray, impacts: np.ndarray,
 
 
 def dense_stream_topk(W, dense_blocks, *, k: int,
-                      min_should_match: int = 1):
+                      min_should_match: int = 1, u_ids=None):
     """Batched streaming top-k over the dense tier.
 
-    W:            f32[B, T] per-query idf·boost weights over dense rows.
+    W:            f32[B, T] per-query idf·boost weights over dense rows
+                  (f32[B, U] over the used-row slots with ``u_ids``).
     dense_blocks: bf16[n_blk, T, C] block-major impact rows.
+    u_ids:        optional i32[U] used-row ids: each streamed block is
+                  narrowed to those rows as it is read, so only U of the
+                  T rows move and no [n_blk, U, C] working set is ever
+                  materialized.
     Returns (vals f32[B, k], docs i32[B, k], n_matched i32[B]) of docs
     scored by dense terms alone (unmatched docs masked to -inf);
     ``n_matched`` counts ALL dense-tier-matched docs, not just the top-k.
@@ -138,6 +143,8 @@ def dense_stream_topk(W, dense_blocks, *, k: int,
     def step(carry, xs):
         best_v, best_i, n_matched = carry
         blk_idx, blk = xs
+        if u_ids is not None:
+            blk = jnp.take(blk, u_ids, axis=0)
         s = lax.dot_general(W, blk.astype(jnp.float32),
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -174,11 +181,12 @@ def dense_stream_topk(W, dense_blocks, *, k: int,
 
 
 def gather_dense_for_candidates(dense_blocks, cand_docs, dense_rid, dense_w,
-                                *, n_pad: int):
+                                *, n_pad: int, u_ids=None):
     """Per-candidate dense-tier contributions for ONE query.
 
     dense_blocks: bf16[n_blk, T, C]; cand_docs: i32[M] (n_pad = absent);
-    dense_rid/dense_w: i32[Qd] / f32[Qd] (w = 0 on padding slots).
+    dense_rid/dense_w: i32[Qd] / f32[Qd] (w = 0 on padding slots);
+    with ``u_ids`` i32[U], ``dense_rid`` holds slots into it.
     Returns (add f32[M], match_cnt f32[M]).
     """
     C = dense_blocks.shape[2]
@@ -189,7 +197,8 @@ def gather_dense_for_candidates(dense_blocks, cand_docs, dense_rid, dense_w,
     cnt = jnp.zeros(cand_docs.shape, jnp.float32)
     Qd = dense_rid.shape[0]
     for j in range(Qd):
-        row_vals = dense_blocks[blk_i, dense_rid[j], off].astype(jnp.float32)
+        rid = dense_rid[j] if u_ids is None else u_ids[dense_rid[j]]
+        row_vals = dense_blocks[blk_i, rid, off].astype(jnp.float32)
         w = dense_w[j]
         hit = (row_vals > 0) & (w > 0) & (cand_docs < n_pad)
         add = add + jnp.where(hit, w * row_vals, 0.0)
@@ -220,11 +229,14 @@ def merge_topk_lists(vals_a, docs_a, vals_b, docs_b, *, k: int,
 def tiered_bm25_topk(postings_docs, postings_impact, dense_blocks,
                      starts, lengths, idfw, dense_rid, dense_w, W,
                      *, n_pad: int, L: int, k: int,
-                     min_should_match: int = 1, with_count: bool = False):
+                     min_should_match: int = 1, with_count: bool = False,
+                     u_ids=None):
     """Full tiered scoring of a query batch against ONE shard partition.
 
     Shapes: starts/lengths i32[B, Q], idfw f32[B, Q], dense_rid i32[B, Qd],
-    dense_w f32[B, Qd], W f32[B, T]. Returns (vals f32[B, k],
+    dense_w f32[B, Qd], W f32[B, T]. With ``u_ids`` i32[U] (the batch's
+    used dense rows) ``W`` is f32[B, U] and ``dense_rid`` holds slots
+    into ``u_ids``. Returns (vals f32[B, k],
     docs i32[B, k]) — plus i32[B] exact match counts when ``with_count``
     (total = sparse candidates + dense-matched − overlap, each tier counted
     in its own full pass; requires min_should_match == 1, where a doc's
@@ -237,7 +249,7 @@ def tiered_bm25_topk(postings_docs, postings_impact, dense_blocks,
             postings_docs, postings_impact, st_q, ln_q, iw_q,
             n_pad=n_pad, L=L)
         add, cnt = gather_dense_for_candidates(
-            dense_blocks, sdocs, rid_q, dw_q, n_pad=n_pad)
+            dense_blocks, sdocs, rid_q, dw_q, n_pad=n_pad, u_ids=u_ids)
         gscore = gscore + add
         gcount = gcount + cnt
         matched = is_last & (sdocs < n_pad) & (gcount >= min_should_match)
@@ -254,10 +266,12 @@ def tiered_bm25_topk(postings_docs, postings_impact, dense_blocks,
         return vals, out_docs.astype(jnp.int32), \
             jnp.sum(matched.astype(jnp.int32)) - overlap
 
-    cand_vals, cand_docs, cand_net = jax.vmap(per_query)(
-        starts, lengths, idfw, dense_rid, dense_w)
+    cand_vals, cand_docs, cand_net = vmap_queries(
+        per_query, (starts, lengths, idfw, dense_rid, dense_w),
+        slots_per_query=starts.shape[1] * L)
     dense_vals, dense_docs, dense_n = dense_stream_topk(
-        W, dense_blocks, k=k, min_should_match=min_should_match)
+        W, dense_blocks, k=k, min_should_match=min_should_match,
+        u_ids=u_ids)
     vals, docs = merge_topk_lists(cand_vals, cand_docs, dense_vals,
                                   dense_docs, k=k, n_pad=n_pad)
     if with_count:

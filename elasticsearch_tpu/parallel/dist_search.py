@@ -33,23 +33,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    # older jax: same API surface but the replication-check kwarg is
-    # spelled check_rep — adapt so call sites can use the current spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
 
 from ..ops.bm25 import DEFAULT_B, DEFAULT_K1, idf_weight
 from ..ops.fused_query import (bisect_exact_scores, bool_bm25_topk_body,
                                knn_raw_to_score, rescore_reorder_body,
                                rrf_fuse_body, sum_fuse_body)
-from ..ops.sorted_merge import bm25_topk_merge_body, make_impacts
+from ..ops.sorted_merge import (bm25_topk_merge_body, make_impacts,
+                                vmap_queries)
 from ..ops.tiered_bm25 import (build_dense_rows, split_tiers,
                                tiered_bm25_topk)
 from ..ops.topk import batched_blockwise_topk
@@ -186,7 +178,8 @@ def build_bm25_topk_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
                     min_should_match=min_should_match,
                     with_count=with_count)
 
-            return jax.vmap(per_query)(st_s, ln_s, idfw)     # [B_loc, kk]
+            return vmap_queries(per_query, (st_s, ln_s, idfw),
+                                slots_per_query=Q * L)       # [B_loc, kk]
 
         out = jax.vmap(per_shard, in_axes=(0, 0, 1, 1),
                        out_axes=1)(pd, pi, st, ln)
@@ -230,10 +223,11 @@ def build_tiered_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
 
     ``U``: used-row gather width. A query batch touches only the dense
     rows its terms map to — usually a small subset of T_pad — so when
-    ``U < T_pad`` the step first gathers the batch's used rows
-    (``u_ids i32[S, U]``) into a [n_blk, U, C] working set and streams
-    THAT through the matmul: HBM traffic and MXU work drop from
-    T_pad·n_pad to U·n_pad per dispatch. ``W`` / ``dense_rid`` are then
+    ``U < T_pad`` each streamed block is narrowed to the batch's used
+    rows (``u_ids i32[S, U]``) as it is read: HBM traffic and MXU work
+    drop from T_pad·n_pad to U·n_pad per dispatch, with no gathered
+    copy of the tier (a [n_blk, U, C] working set cost 3.3 GB of
+    temporaries at n_pad=2^23, U=64). ``W`` / ``dense_rid`` are then
     slot-indexed ([B, S, U] / slot ids). Exact: unused rows have zero
     weight everywhere.
     """
@@ -248,12 +242,10 @@ def build_tiered_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
     def body(pd, pi, dense, st, ln, idfw, rid, dw, W, u_ids):
         def per_shard(pd_s, pi_s, dense_s, st_s, ln_s, rid_s, dw_s, W_s,
                       u_s):
-            if gathered:
-                dense_s = jnp.take(dense_s, u_s, axis=1)
             return tiered_bm25_topk(
                 pd_s, pi_s, dense_s, st_s, ln_s, idfw, rid_s, dw_s, W_s,
                 n_pad=n_pad, L=L, k=kk, min_should_match=min_should_match,
-                with_count=with_count)
+                with_count=with_count, u_ids=u_s if gathered else None)
 
         out = jax.vmap(per_shard,
                        in_axes=(0, 0, 0, 1, 1, 1, 1, 1, 0),
@@ -332,8 +324,13 @@ def _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn, *, similarity: str,
     (vals f32[B, kk], local idx i32[B, kk])."""
 
     def score_block(vecs_b, vn_b, exists_b):
+        # HIGHEST: the TPU's default matmul precision rounds f32 operands
+        # to bf16 (~1e-3 on a cosine) — this is the EXACT scan, held to
+        # f32 by its tests. It also keeps XLA from hoisting a bf16
+        # convert of the whole corpus out of the block loop.
         dots = jnp.einsum("bd,nd->bn", qq, vecs_b,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=lax.Precision.HIGHEST)
         if similarity == "l2_norm":
             # -||q - v||² expanded to ride the MXU; ||v||² is the
             # cached pack-time column, never recomputed per query
@@ -358,11 +355,18 @@ def _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn, *, similarity: str,
     v0, i0 = batched_blockwise_topk(
         score_block(vecs_blk[0], vn_blk[0], exists_blk[0]), kk)
 
-    def step_blk(carry, xs):
+    def step_blk(carry, b_idx):
         acc_v, acc_i = carry
-        b_idx, vecs_b, vn_b, exists_b = xs
+        # blocks are read in place by index: handing the scan
+        # ``vecs_blk[1:]`` as xs materializes a second copy of the whole
+        # corpus per dispatch (2x the corpus in HBM; refused by the TPU
+        # compiler at 2^22 x 768)
         bv, bi = batched_blockwise_topk(
-            score_block(vecs_b, vn_b, exists_b), kk)
+            score_block(
+                lax.dynamic_index_in_dim(vecs_blk, b_idx, keepdims=False),
+                lax.dynamic_index_in_dim(vn_blk, b_idx, keepdims=False),
+                lax.dynamic_index_in_dim(exists_blk, b_idx,
+                                         keepdims=False)), kk)
         gi = bi.astype(jnp.int32) + b_idx * blk
         cat_v = jnp.concatenate([acc_v, bv], axis=1)
         cat_i = jnp.concatenate([acc_i, gi], axis=1)
@@ -374,8 +378,7 @@ def _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn, *, similarity: str,
 
     (vals, idx), _ = lax.scan(
         step_blk, (v0, i0.astype(jnp.int32)),
-        (jnp.arange(1, nb, dtype=jnp.int32), vecs_blk[1:],
-         vn_blk[1:], exists_blk[1:]))
+        jnp.arange(1, nb, dtype=jnp.int32))
     return vals, idx
 
 
@@ -506,11 +509,7 @@ def _device_linalg() -> bool:
     assignment matmuls then run through jnp (MXU); the CPU backend uses
     BLAS directly (XLA:CPU runs well under numpy's sgemm here, same
     verdict as search_host vs the jitted step)."""
-    import jax
-    try:
-        return jax.devices()[0].platform != "cpu"
-    except Exception:   # noqa: BLE001 — no backend: host math
-        return False
+    return jax.devices()[0].platform != "cpu"
 
 
 def _assign_clusters(x: np.ndarray, centroids: np.ndarray, l2: bool,
@@ -531,7 +530,8 @@ def _assign_clusters(x: np.ndarray, centroids: np.ndarray, l2: bool,
         if on_dev:
             s = jnp.einsum("nd,cd->nc", jnp.asarray(xb),
                            jnp.asarray(centroids),
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=lax.Precision.HIGHEST)
             if l2:
                 s = 2.0 * s - jnp.asarray(c2)[None, :]
             out[lo: lo + chunk] = np.asarray(jnp.argmax(s, axis=1),
@@ -902,7 +902,8 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
             safe_rows = jnp.clip(cand_rows, 0, n_pad - 1)
             cvecs = jnp.take(vecs_s, safe_rows, axis=0)    # [B, R, d]
             ex = jnp.einsum("bd,brd->br", qq, cvecs,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=lax.Precision.HIGHEST)
             if l2:
                 cvn = jnp.take(vn_s, safe_rows)
                 ex = 2.0 * ex - cvn - qn[:, None]
@@ -1460,8 +1461,13 @@ def build_pruned_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, k: int,
                         unsafe.astype(jnp.int32),
                         pruned.astype(jnp.int32), n_sc)
 
-            return jax.vmap(per_query)(sched_s, w_s, rho_s, slack_s,
-                                       st_s, ln_s, idfw)
+            # one n_pad-wide f32 accumulator per query rides the scan
+            # carry: the same slot bound as the sorted merge keeps a
+            # B=64 dispatch at n_pad=2^23 from holding 8 GB of them
+            return vmap_queries(
+                per_query,
+                (sched_s, w_s, rho_s, slack_s, st_s, ln_s, idfw),
+                slots_per_query=n_pad)
 
         out = jax.vmap(per_shard,
                        in_axes=(0, 0, 0, 0, 0, 0, 1, 1, 1, 1, 1, 1),
@@ -1559,14 +1565,17 @@ def build_bool_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int,
                 return vals, docs, cnt
 
             if rescore:
-                return jax.vmap(per_query)(
-                    st_s, ln_s, idfw, cbits, req, neg, shd, msm,
-                    st2_s, ln2_s, iw2)
+                return vmap_queries(
+                    per_query,
+                    (st_s, ln_s, idfw, cbits, req, neg, shd, msm,
+                     st2_s, ln2_s, iw2), slots_per_query=Q * L)
             z2 = jnp.zeros((1,), jnp.int32)
             zf = jnp.zeros((1,), jnp.float32)
-            return jax.vmap(lambda a, b, c, d, e, f, g, h: per_query(
-                a, b, c, d, e, f, g, h, z2, z2, zf))(
-                st_s, ln_s, idfw, cbits, req, neg, shd, msm)
+            return vmap_queries(
+                lambda a, b, c, d, e, f, g, h: per_query(
+                    a, b, c, d, e, f, g, h, z2, z2, zf),
+                (st_s, ln_s, idfw, cbits, req, neg, shd, msm),
+                slots_per_query=Q * L)
 
         if rescore:
             out = jax.vmap(per_shard, in_axes=(0, 0, 1, 1, 1, 1),
@@ -1674,16 +1683,18 @@ def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
                     with_count=True, nc=nc)
 
             if rescore:
-                tv, td, cnt = jax.vmap(per_query)(
-                    st_s, ln_s, idfw, cbits, req, neg, shd, msm,
-                    st2_s, ln2_s, iw2)
+                tv, td, cnt = vmap_queries(
+                    per_query,
+                    (st_s, ln_s, idfw, cbits, req, neg, shd, msm,
+                     st2_s, ln2_s, iw2), slots_per_query=Q * L)
             else:
                 z2 = jnp.zeros((1,), jnp.int32)
                 zf = jnp.zeros((1,), jnp.float32)
-                tv, td, cnt = jax.vmap(
+                tv, td, cnt = vmap_queries(
                     lambda a, b, c, d, e, f, g, h: per_query(
-                        a, b, c, d, e, f, g, h, z2, z2, zf))(
-                    st_s, ln_s, idfw, cbits, req, neg, shd, msm)
+                        a, b, c, d, e, f, g, h, z2, z2, zf),
+                    (st_s, ln_s, idfw, cbits, req, neg, shd, msm),
+                    slots_per_query=Q * L)
             kv, kd = _knn_shard_scan(kv_s, kn_s, ke_s, qq, qn,
                                      similarity=similarity,
                                      n_pad=n_pad_k, dim=dim, kk=kk_k,
@@ -2379,9 +2390,10 @@ class DistributedSearchPlane:
                    for si in range(S)]
         max_used = max((r.size for r in u_lists), default=0)
         U = min(T, max(16, round_up_pow2(max(max_used, 1))))
-        # the gather moves ~3x the U rows through HBM (read + write the
-        # working set, then the matmul re-reads it), so it only pays when
-        # the batch touches well under a third of the dense tier
+        # the one-third bar dates from a gather that wrote a working set
+        # and re-read it (~3x the U rows through HBM); blocks are now
+        # narrowed as they stream, so the bar is conservative — left where
+        # it is until a chip run measures the row-gathered stream
         if 3 * U > T:
             U = T
         if U < T:
@@ -3234,6 +3246,11 @@ class DistributedSearchPlane:
         bad = np.flatnonzero(unsafe)
         if bad.size:
             bad_q = [queries[i] for i in bad]
+            # pad to a power of two like the micro-batcher does: a raw
+            # count of unsafe queries would compile one eager program per
+            # distinct count, off the (B-pow2 x k x L-rung) lattice
+            bad_q += [[] for _ in range(
+                round_up_pow2(len(bad_q), 1) - len(bad_q))]
             ev = self.search(bad_q, k=k, Q=Q,
                              L=self.ladder_L(self.max_run_len(bad_q)),
                              tiered=self.T_pad > 0 or None,

@@ -85,6 +85,7 @@ def empty_serving_stats() -> Dict[str, int]:
         "deduped_queries": 0,
         "delta_queries": 0, "delta_time_in_millis": 0,
         "warmed_shapes": 0, "warmup_time_in_millis": 0,
+        "warmup_failures": 0,
         "queue_time_in_millis": 0, "prep_time_in_millis": 0,
         "dispatch_time_in_millis": 0, "fetch_time_in_millis": 0,
         # serving-mesh topology (max-merged across batchers — every
@@ -203,6 +204,8 @@ class PlaneMicroBatcher:
         self.delta_ms = 0.0
         self.warmed_shapes = 0
         self.warmup_ms = 0.0
+        #: warm-up runs stopped by a shape that failed to compile or run
+        self.warmup_failures = 0
         self._retired = False
         self.stage_totals_ms: Dict[str, float] = {s: 0.0 for s in STAGES}
         self.stage_samples: Dict[str, deque] = {
@@ -620,8 +623,12 @@ class PlaneMicroBatcher:
                 # "bandwidth" would misattribute a host regression)
                 exec_ms = plane_stages.get(
                     "dispatch_ms", (t_done - t_call) * 1e3)
-                audit = _rf.audit(kernel, model_b, exec_ms,
-                                  exemplar=exemplar)
+                try:
+                    audit = _rf.audit(kernel, model_b, exec_ms,
+                                      exemplar=exemplar)
+                except _rf.UnknownDeviceError as e:
+                    # the record still lands, saying why it has no audit
+                    audit = {"error": str(e)}
             me = threading.current_thread()
             return _dp.record(
                 ts_ms=round(wall(q_start), 3),
@@ -672,6 +679,7 @@ class PlaneMicroBatcher:
         def _run():
             t0 = time.perf_counter()
             n = 0
+            failed = None
             for fn in shapes:
                 if self._retired:
                     # the plane was superseded (refresh rebuilt it):
@@ -681,12 +689,25 @@ class PlaneMicroBatcher:
                 try:
                     fn()
                     n += 1
-                except Exception:   # noqa: BLE001 — warmup must never
-                    break           # take down serving
+                except Exception as e:   # noqa: BLE001 — warmup must
+                    failed = e           # never take down serving
+                    break
             with self._cond:
                 racedep.note_write("microbatch.stats", self)
                 self.warmed_shapes += n
                 self.warmup_ms += (time.perf_counter() - t0) * 1e3
+                if failed is not None:
+                    self.warmup_failures += 1
+            if failed is not None:
+                # a shape the compiler refuses leaves the rest of the
+                # lattice cold: journal it (counted by event type), or a
+                # node that compiled nothing looks warm
+                from ..common import flightrec as _fr
+                _fr.record("warmup_failed",
+                           plane=type(self.plane).__name__,
+                           kind=self.kind, shapes_warmed=n,
+                           shapes_planned=len(shapes),
+                           error=repr(failed)[:500])
             # process-cumulative credit: survives this batcher's
             # retirement, so compile_churn windows stay honest across
             # generation swaps (see telemetry.record_warmed_shapes)
@@ -770,6 +791,7 @@ class PlaneMicroBatcher:
                 delta_time_in_millis=int(self.delta_ms),
                 warmed_shapes=self.warmed_shapes,
                 warmup_time_in_millis=int(self.warmup_ms),
+                warmup_failures=self.warmup_failures,
                 mesh_shard_devices=self.mesh_shard_devices,
                 mesh_replica_devices=self.mesh_replica_devices)
             for name in STAGES:

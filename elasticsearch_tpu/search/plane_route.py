@@ -966,8 +966,13 @@ class ServingPlaneCache:
                 from ..common import flightrec as _fr
                 _fr.record("plane_swap", kind=kind, field=field,
                            trigger=trigger, ms=round(swap_ms, 3))
-            except Exception:   # noqa: BLE001 — a failed repack must
-                pass            # never take down serving; retried later
+            except Exception as e:   # noqa: BLE001 — a failed repack
+                # must never take down serving (the old generation keeps
+                # serving and the next refresh retries) but it is
+                # journaled and counted by event type, not passed over
+                from ..common import flightrec as _fr
+                _fr.record("plane_repack_failed", kind=kind, field=field,
+                           trigger=trigger, error=repr(e)[:500])
             finally:
                 with self._gen_lock:
                     self._repacking.discard((kind, field))

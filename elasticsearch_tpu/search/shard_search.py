@@ -203,11 +203,15 @@ class ShardSearcher:
             return 1.0 / (1.0 - raw) if raw < 0 else raw + 1.0
         return 1.0 / (1.0 + max(0.0, -raw))        # l2_norm
 
-    def _knn_candidates(self, spec: dict) -> List[Tuple[float, int, int]]:
+    def _knn_candidates(self, spec: dict,
+                        serving_out: Optional[list] = None
+                        ) -> List[Tuple[float, int, int]]:
         """Brute-force kNN for one knn clause: einsum per segment + top-k
         (reference: the 8.x ``_knn_search``/``knn`` section; scoring per
         ``x-pack/plugin/vectors`` brute force, but one matmul per segment
-        instead of a per-doc script loop)."""
+        instead of a per-doc script loop). ``serving_out`` receives the
+        kNN plane dispatch's stage timings + metadata when that route
+        served (the Profile API's ``serving_knn`` section)."""
         field = spec.get("field")
         qv = spec.get("query_vector")
         if field is None or qv is None:
@@ -264,6 +268,11 @@ class ShardSearcher:
                                                 nprobe=nprobe,
                                                 rerank=rerank)
                 _attribute_dispatch(knn_stages, knn_info)
+                if serving_out is not None:
+                    serving_out.append({
+                        "stages_ms": {s: round(ms, 3)
+                                      for s, ms in knn_stages.items()},
+                        **knn_info})
                 cands = [
                     (self._knn_score_from_raw(ft.similarity, float(v))
                      * boost, si, d)
@@ -680,6 +689,7 @@ class ShardSearcher:
 
         # --- knn section ---------------------------------------------------
         knn_rankings: List[List[Tuple[float, int, int]]] = []
+        knn_serving: List[dict] = []
         if knn_override is not None:
             # the coordinator already reduced per-shard knn candidates to
             # the GLOBAL top-k and handed us this shard's slice
@@ -687,7 +697,8 @@ class ShardSearcher:
         elif knn_spec:
             specs = knn_spec if isinstance(knn_spec, list) else [knn_spec]
             for spec in specs:
-                knn_rankings.append(self._knn_candidates(spec))
+                knn_rankings.append(self._knn_candidates(
+                    spec, serving_out=knn_serving if profile_on else None))
 
         max_score: Optional[float] = None
         if knn_rankings:
@@ -930,6 +941,11 @@ class ShardSearcher:
                 prof_shape = shape_id or _fr.current_shape()
                 if prof_shape:
                     shard_prof["serving"]["shape"] = prof_shape
+            if knn_serving:
+                # the knn plane's own dispatches (one per knn clause): a
+                # knn-only or two-dispatch hybrid request has no lexical
+                # plane dispatch to report under "serving"
+                shard_prof["serving_knn"] = knn_serving
             if planner_doc is not None:
                 # the one-dispatch planner's verdict + lowering cost:
                 # operators bisecting a fused-path regression see which

@@ -1,5 +1,10 @@
 #!/usr/bin/env python
-"""Multichip serving measurement at ONE virtual-device count.
+"""Multi-device serving measurement at ONE VIRTUAL-device count.
+
+A virtual-CPU-mesh tool, not a chip run: the devices are XLA:CPU devices
+in one process, so its parity, per-device-bytes and compile-count gates
+carry over to a TPU mesh and its timings do not (``python chip_smoke.py
+--chips 4`` is the run on four real chips).
 
 One process per device count: the XLA host-platform device count is
 fixed per process (``--xla_force_host_platform_device_count`` is read at
