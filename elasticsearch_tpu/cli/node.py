@@ -27,6 +27,8 @@ from ..common import lockdep as _lockdep
 
 _lockdep.install()
 
+from ..common import tracing as _tracing   # noqa: E402 — after lockdep
+
 
 def _wrap_handler(handle, pool, owner=None):
     """Adapt a REST ``handle`` to the HttpServer's 4-tuple form: collect
@@ -48,7 +50,11 @@ def _wrap_handler(handle, pool, owner=None):
                                       headers=headers, resp_headers=rh)
             return status, ct, out, rh
 
-        return await asyncio.get_running_loop().run_in_executor(pool, run)
+        fut = asyncio.get_running_loop().run_in_executor(pool, run)
+        # http[in] ends where the request leaves the event loop; what it
+        # waits for a pool thread shows as the gap before rest[parse]
+        _tracing.handoff()
+        return await fut
     if owner is not None:
         handler.__self__ = owner
     return handler

@@ -22,24 +22,41 @@ logs and task descriptions. Here:
   data-node span only joins the trace if the RPC payload carried the
   context).
 
-Overhead per request: 2-4 spans × (one 8-byte urandom id + one dict +
-one deque append under lock) — well inside the ≤2% serving budget.
+Every span has a twin on the profiler's clock: while a ``jax.profiler``
+session is active (an operator's capture, the benchmark's ``--trace 1``)
+a span also opens a ``jax.profiler.TraceAnnotation`` of the same name,
+so it lands in the host plane of the same ``.xplane.pb`` as the device's
+events, on one clock. A traced span's twin carries ``trace_id`` /
+``span_id`` / ``parent`` and no more (its attributes are in the store
+under that span id; :data:`_LINK_KEYS` names the exception). Threads
+that serve no single request (the micro-batcher's dispatchers) get the
+twin only, with their scalar attributes: those spans carry the dispatch
+``seq`` that the requests' ``plane_dispatch`` spans point at. With no
+session nothing is formatted and nothing is written. (That twin is why
+this module, alone under ``common/``, imports ``jax``: the profiler's
+front end only, no backend is initialised by it.)
+
+Cost per span, with and without a session: PERF.md §6 "PR 25" has the
+measured numbers.
 """
 
 from __future__ import annotations
 
 import contextvars
-import os
+import random
 import threading
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["TraceStore", "DEFAULT_STORE", "span", "current_trace_id",
-           "current_span_id", "wire_headers", "new_trace_id",
-           "set_opaque_id", "current_opaque_id"]
+from jax.profiler import TraceAnnotation
 
-#: (trace_id, span_id) of the active span on this context, or None
+__all__ = ["TraceStore", "DEFAULT_STORE", "span", "open_span", "handoff",
+           "Phases", "current_trace_id", "current_span_id",
+           "wire_headers", "new_trace_id", "set_opaque_id",
+           "current_opaque_id"]
+
+#: the innermost open traced span (a :class:`SpanHandle`) on this
+#: context, or None
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
     "es_trace_ctx", default=None)
 #: the request's X-Opaque-Id (slow-log / task stamping), or None
@@ -47,26 +64,34 @@ _OPAQUE: contextvars.ContextVar = contextvars.ContextVar(
     "es_opaque_id", default=None)
 
 
+#: ids come from a generator seeded from the OS once, not from
+#: ``os.urandom``: that is a system call made with the interpreter lock
+#: released, and on a node whose request threads queue for that lock a
+#: span that gives it up pays a full wait for it (PERF.md §6 "PR 25")
+_IDS = random.Random()
+
+
 def new_trace_id() -> str:
-    return os.urandom(16).hex()
+    return "%032x" % _IDS.getrandbits(128)
 
 
 def _new_span_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _IDS.getrandbits(64)
 
 
 def current() -> Optional[Tuple[str, str]]:
-    return _CTX.get()
+    ctx = _CTX.get()
+    return (ctx.trace_id, ctx.span_id) if ctx else None
 
 
 def current_trace_id() -> Optional[str]:
     ctx = _CTX.get()
-    return ctx[0] if ctx else None
+    return ctx.trace_id if ctx else None
 
 
 def current_span_id() -> Optional[str]:
     ctx = _CTX.get()
-    return ctx[1] if ctx else None
+    return ctx.span_id if ctx else None
 
 
 def set_opaque_id(opaque: Optional[str]):
@@ -83,7 +108,7 @@ def wire_headers() -> Optional[Dict[str, str]]:
     ctx = _CTX.get()
     if ctx is None:
         return None
-    out = {"trace.id": ctx[0], "parent.span.id": ctx[1]}
+    out = {"trace.id": ctx.trace_id, "parent.span.id": ctx.span_id}
     opaque = _OPAQUE.get()
     if opaque:
         out["x-opaque-id"] = opaque
@@ -171,13 +196,18 @@ class TraceStore:
     def recent(self, n: int = 50, min_ms: Optional[float] = None,
                tenant: Optional[str] = None) -> List[dict]:
         """The newest-first trace index: one row per retained trace with
-        its root span's name, start and duration (``GET /_trace`` — the
+        its request's action, start and duration (``GET /_trace`` — the
         listing that makes an evicted id's 404 explainable and lets
-        ``trace_dump.py --last`` stop guessing). ``min_ms`` keeps only
-        traces whose root took at least that long; ``tenant`` keeps
-        only traces whose root carries that X-Opaque-Id — both filter
-        BEFORE the ``n`` cap, so "the slowest tenant's last 50" works
-        on a busy store."""
+        ``trace_dump.py --last`` stop guessing). A request served over
+        HTTP roots at ``http[in]``, which ends at the hand-off to the
+        handler's thread: the row is the request, not that edge —
+        ``root`` and ``tenant`` are those of the first span under the
+        root that names an action (``rest[<action>]``; the root itself
+        where none does), ``took_ms`` runs from the root's start to the
+        last end under it. ``min_ms`` keeps only traces that took at
+        least that long; ``tenant`` keeps only traces of that
+        X-Opaque-Id — both filter BEFORE the ``n`` cap, so "the slowest
+        tenant's last 50" works on a busy store."""
         n = int(n)
         if n <= 0:
             return []
@@ -188,18 +218,36 @@ class TraceStore:
         for tid, spans in reversed(items):
             row = {"trace_id": tid, "span_count": len(spans)}
             if spans:
+                def start(s):
+                    return s.get("start_ms", 0)
+                kids: Dict[Optional[str], List[dict]] = {}
+                for s in spans:
+                    kids.setdefault(s.get("parent_span_id"), []).append(s)
                 ids = {s.get("span_id") for s in spans}
                 roots = [s for s in spans
                          if s.get("parent_span_id") not in ids]
-                root = min(roots or spans,
-                           key=lambda s: s.get("start_ms", 0))
-                row.update(root=root.get("name"),
-                           start_ms=root.get("start_ms"),
-                           took_ms=root.get("took_ms"))
-                node = root.get("node")
+                root = min(roots or spans, key=start)
+                # the root's subtree (a scroll's later requests under
+                # the same trace id are other roots)
+                under, todo, seen = [], [root], set()
+                while todo:
+                    s = todo.pop()
+                    if s.get("span_id") not in seen:
+                        seen.add(s.get("span_id"))
+                        under.append(s)
+                        todo.extend(kids.get(s.get("span_id"), ()))
+                end_ms = max(start(s) + s.get("took_ms", 0)
+                             for s in under)
+                request = min((s for s in under
+                               if (s.get("attrs") or {}).get("action")),
+                              key=start, default=root)
+                start_ms = start(root)
+                row.update(root=request.get("name"), start_ms=start_ms,
+                           took_ms=round(end_ms - start_ms, 3))
+                node = request.get("node") or root.get("node")
                 if node:
                     row["node"] = node
-                row_tenant = (root.get("attrs") or {}).get("tenant")
+                row_tenant = (request.get("attrs") or {}).get("tenant")
                 if row_tenant:
                     row["tenant"] = row_tenant
             if min_ms is not None and \
@@ -224,83 +272,192 @@ class TraceStore:
 DEFAULT_STORE = TraceStore()
 
 
+_SCALARS = (str, int, float, bool)
+#: the attributes a traced span's twin carries besides its ids: what
+#: links it to a span of another thread (a request's ``plane_dispatch``
+#: to the dispatcher's ``batch[...]`` spans of that ``seq``)
+_LINK_KEYS = ("dispatch_seq",)
+#: the profiler encodes an annotation as ``name#k=v,k=v#``
+_STAT_UNSAFE = str.maketrans("#,", "__")
+
+
+def _stats(attrs: dict) -> dict:
+    """The scalar attributes, as annotation stats."""
+    return {k: v.translate(_STAT_UNSAFE) if isinstance(v, str) else v
+            for k, v in attrs.items() if isinstance(v, _SCALARS)}
+
+
 class SpanHandle:
-    """Yielded by :func:`span` so the body can attach attributes and
-    read the ids."""
+    """One open span: yielded by :func:`span` / returned by
+    :func:`open_span` so the body can attach attributes and read the
+    ids. ``trace_id`` is None for a span outside any trace, which has
+    only its profiler twin. A handle is its span's own state: the thread
+    that runs the span opens and closes it."""
 
-    __slots__ = ("trace_id", "span_id", "attrs")
+    __slots__ = ("name", "trace_id", "span_id", "parent_span_id", "attrs",
+                 "node", "store", "manual", "_token", "_t0", "_start_ms",
+                 "_ann", "_ann_keys")
 
-    def __init__(self, trace_id: str, span_id: str, attrs: dict):
+    def __init__(self, name: str, trace_id: Optional[str],
+                 parent_span_id: Optional[str], attrs: Optional[dict],
+                 node: Optional[str], store: Optional[TraceStore],
+                 manual: bool, profiling: bool):
+        self.name = name
         self.trace_id = trace_id
-        self.span_id = span_id
-        self.attrs = attrs
+        self.span_id = _new_span_id()
+        self.parent_span_id = parent_span_id
+        self.attrs = dict(attrs) if attrs else {}
+        self.node = node
+        self.store = store
+        self.manual = manual
+        self._ann = self._ann_keys = None
+        if profiling:
+            if trace_id is None:
+                self._ann_keys = tuple(self.attrs)
+                self._ann = TraceAnnotation(name, **_stats(self.attrs))
+            elif parent_span_id:
+                # ids get a letter in front: a stat that looks like a
+                # number is read back as one, and one hex id in a few
+                # thousand does
+                self._ann = TraceAnnotation(
+                    name, trace_id="t" + trace_id,
+                    span_id="s" + self.span_id,
+                    parent="s" + str(parent_span_id))
+            else:
+                self._ann = TraceAnnotation(
+                    name, trace_id="t" + trace_id,
+                    span_id="s" + self.span_id)
+            self._ann.__enter__()
+        self._token = _CTX.set(self) if trace_id is not None else None
+        self._start_ms = time.time() * 1e3
+        self._t0 = time.perf_counter()
+
+    def close(self) -> None:
+        """End the span: idempotent (an edge span ends at the hand-off
+        or when its opener is done, whichever comes first)."""
+        if self._t0 is None:
+            return
+        took_ms = (time.perf_counter() - self._t0) * 1e3
+        self._t0 = None
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            if self.trace_id is None:
+                late = {k: v for k, v in self.attrs.items()
+                        if k not in self._ann_keys}
+            else:
+                late = {k: self.attrs[k] for k in _LINK_KEYS
+                        if k in self.attrs}
+            if late:
+                ann.set_metadata(**_stats(late))
+            ann.__exit__(None, None, None)
+        if self.trace_id is None:
+            return
+        _CTX.reset(self._token)
+        doc = {"trace_id": self.trace_id, "span_id": self.span_id,
+               "parent_span_id": self.parent_span_id, "name": self.name,
+               "start_ms": round(self._start_ms, 3),
+               "took_ms": round(took_ms, 3)}
+        if self.node:
+            doc["node"] = self.node
+        if self.attrs:
+            doc["attrs"] = self.attrs
+        (self.store or DEFAULT_STORE).record(doc)
 
 
-@contextmanager
-def span(name: str, *, node: Optional[str] = None,
-         attrs: Optional[dict] = None,
-         headers: Optional[dict] = None,
-         trace_id: Optional[str] = None,
-         root: bool = False,
-         store: Optional[TraceStore] = None):
-    """One traced span around the body.
+def open_span(name: str, *, node: Optional[str] = None,
+              attrs: Optional[dict] = None,
+              headers: Optional[dict] = None,
+              trace_id: Optional[str] = None,
+              parent_span_id: Optional[str] = None,
+              root: bool = False,
+              store: Optional[TraceStore] = None,
+              manual: bool = True) -> Optional[SpanHandle]:
+    """Open a span and return its handle; the caller ends it with
+    :meth:`SpanHandle.close` (:func:`span` is the ``with`` form).
 
-    Parent resolution order: explicit ``trace_id``, wire ``headers``
-    (cross-node hop), then the ambient context. ``root=True`` mints a
-    fresh trace when none of those yield one (the REST edge); without
-    it, a body running outside any trace records nothing (maintenance
-    paths stay free)."""
-    parent_span: Optional[str] = None
+    Parent resolution order: explicit ``trace_id`` (+ ``parent_span_id``),
+    wire ``headers`` (cross-node hop; the ambient span stays the parent
+    when it is already part of that trace), then the ambient context.
+    ``root=True`` mints a fresh trace when none of those yield one (the
+    HTTP/REST edge). Without a trace the span has only its profiler
+    twin, and with no profiler session either, None is returned: a body
+    running outside any trace records nothing (maintenance paths stay
+    free)."""
+    parent_span = parent_span_id
     tid = trace_id
+    ctx = _CTX.get()
     if tid is None and headers is not None:
         tid, parent_span = parse_incoming(headers)
+        if tid is not None and ctx is not None and ctx.trace_id == tid:
+            parent_span = ctx.span_id
     if tid is None:
-        ctx = _CTX.get()
         if ctx is not None:
-            tid, parent_span = ctx
+            tid, parent_span = ctx.trace_id, ctx.span_id
         elif root:
             tid = new_trace_id()
-    if tid is None:
-        yield None
-        return
-    sid = _new_span_id()
-    sattrs = dict(attrs or {})
-    handle = SpanHandle(tid, sid, sattrs)
-    token = _CTX.set((tid, sid))
-    t0 = time.perf_counter()
-    start_ms = time.time() * 1e3
-    try:
-        yield handle
-    finally:
-        _CTX.reset(token)
-        doc = {"trace_id": tid, "span_id": sid,
-               "parent_span_id": parent_span, "name": name,
-               "start_ms": round(start_ms, 3),
-               "took_ms": round((time.perf_counter() - t0) * 1e3, 3)}
-        if node:
-            doc["node"] = node
-        if sattrs:
-            doc["attrs"] = sattrs
-        (store or DEFAULT_STORE).record(doc)
+    profiling = TraceAnnotation.is_enabled()
+    if tid is None and not profiling:
+        return None
+    return SpanHandle(name, tid, parent_span, attrs, node, store, manual,
+                      profiling)
 
 
-def record_point(name: str, *, took_ms: float = 0.0,
-                 node: Optional[str] = None,
-                 attrs: Optional[dict] = None,
-                 store: Optional[TraceStore] = None) -> None:
-    """Record a leaf span under the AMBIENT context without entering a
-    new one (used to stamp already-measured work, e.g. the micro-batch
-    dispatch whose stage timings arrive after the fact)."""
-    ctx = _CTX.get()
-    if ctx is None:
-        return
-    tid, parent = ctx
-    doc = {"trace_id": tid, "span_id": _new_span_id(),
-           "parent_span_id": parent, "name": name,
-           "start_ms": round(time.time() * 1e3 - took_ms, 3),
-           "took_ms": round(took_ms, 3)}
-    if node:
-        doc["node"] = node
-    if attrs:
-        doc["attrs"] = attrs
-    (store or DEFAULT_STORE).record(doc)
+class span:
+    """One traced span around the body: ``with span(name, ...) as sp``
+    (:func:`open_span`'s arguments). ``sp`` is the :class:`SpanHandle`,
+    or None where nothing is recorded."""
+
+    __slots__ = ("_name", "_kw", "_handle")
+
+    def __init__(self, name: str, **kw):
+        self._name = name
+        self._kw = kw
+
+    def __enter__(self) -> Optional[SpanHandle]:
+        self._handle = open_span(self._name, manual=False, **self._kw)
+        return self._handle
+
+    def __exit__(self, *exc) -> None:
+        if self._handle is not None:
+            self._handle.close()
+
+
+def handoff() -> None:
+    """The request leaves this thread (event loop -> handler pool): an
+    ambient span its opener left open for this (:func:`open_span`) ends
+    here. Spans made under a copy of the context taken before the call
+    keep it as their parent."""
+    h = _CTX.get()
+    if h is not None and h.manual:
+        h.close()
+
+
+class Phases:
+    """Consecutive sibling spans under the ambient span, for a pipeline
+    written as one long function: entering a phase ends the one before,
+    leaving the ``with`` block (:meth:`close`) ends the last, also where
+    the pipeline raises: an open phase left behind would stay the
+    ambient span, and every later annotation of the thread would nest
+    under its twin."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self):
+        self._open: Optional[SpanHandle] = None
+
+    def __enter__(self) -> "Phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def enter(self, name: str, **attrs) -> Optional[SpanHandle]:
+        self.close()
+        self._open = open_span(name, attrs=attrs, manual=False)
+        return self._open
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.close()
+            self._open = None

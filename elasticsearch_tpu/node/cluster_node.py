@@ -258,6 +258,7 @@ class ClusterNode:
         Requests execute on a small pool so blocking RPC fan-outs never
         stall the transport loop."""
         import asyncio
+        from ..common import tracing as _tracing
         from ..rest.http_server import HttpServer
         self._http_pool = ThreadPoolExecutor(
             max_workers=4, thread_name_prefix=f"es-rest-http-{self.node_id}")
@@ -277,7 +278,9 @@ class ClusterNode:
                     headers=headers, resp_headers=rh)
                 return status, ct, out, rh
 
-            return await loop.run_in_executor(self._http_pool, run)
+            fut = loop.run_in_executor(self._http_pool, run)
+            _tracing.handoff()      # http[in] ends at the hand-off
+            return await fut
 
         self.http = HttpServer(handler, host=host, port=port,
                                pass_headers=True)

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import in_named_scope
+
 #: below this many doc-values pairs the host numpy path wins (dispatch
 #: overhead dominates); aggregations consult this before shipping to device
 DEVICE_MIN_PAIRS = 1 << 16
@@ -43,6 +45,7 @@ MAX_DEVICE_BUCKETS = 4096
 
 
 @jax.jit
+@in_named_scope("masked_ordinal_counts")
 def masked_ordinal_counts(offsets, pair_docs, mask):
     """Exact per-ordinal masked pair counts.
 
@@ -60,6 +63,7 @@ def masked_ordinal_counts(offsets, pair_docs, mask):
 
 
 @jax.jit
+@in_named_scope("masked_ordinal_sums")
 def masked_ordinal_sums(offsets, pair_docs, pair_vals, mask):
     """Per-ordinal masked f32 value sums (same layout as
     :func:`masked_ordinal_counts`; f32 cumsum — see precision contract)."""
@@ -70,6 +74,7 @@ def masked_ordinal_sums(offsets, pair_docs, pair_vals, mask):
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets",))
+@in_named_scope("masked_bucket_counts")
 def masked_bucket_counts(bucket_ids, pair_docs, mask, *, n_buckets: int):
     """Low-cardinality masked bucket counts via one-hot reduction.
 
@@ -85,6 +90,7 @@ def masked_bucket_counts(bucket_ids, pair_docs, mask, *, n_buckets: int):
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets",))
+@in_named_scope("masked_bucket_sums")
 def masked_bucket_sums(bucket_ids, pair_docs, pair_vals, mask,
                        *, n_buckets: int):
     """One-hot masked f32 value sums per bucket (MXU-friendly matmul)."""
@@ -97,6 +103,7 @@ def masked_bucket_sums(bucket_ids, pair_docs, pair_vals, mask,
 
 
 @jax.jit
+@in_named_scope("masked_metrics")
 def masked_metrics(pair_docs, pair_vals, mask):
     """One-pass masked (count, sum, min, max) over a pair column.
     Returns (f32 count, f32 sum, f32 min, f32 max) — min/max are +inf/-inf
@@ -110,6 +117,7 @@ def masked_metrics(pair_docs, pair_vals, mask):
 
 
 @jax.jit
+@in_named_scope("masked_rank_prefix")
 def masked_rank_prefix(offsets, pair_docs, mask):
     """Masked-count prefix over a **(ordinal, value)**-sorted pair layout —
     the exact-percentile primitive.
@@ -366,6 +374,7 @@ def distinct_count(seg, field: str) -> int:
 
 
 @jax.jit
+@in_named_scope("masked_register_max")
 def masked_register_max(offsets, pair_docs, pair_rhos, mask):
     """Masked per-register rho max over (register, rho)-sorted pairs.
 

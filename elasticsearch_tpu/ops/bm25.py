@@ -32,11 +32,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import in_named_scope
+
 # Elasticsearch defaults (SimilarityService: BM25 with k1=1.2, b=0.75).
 DEFAULT_K1 = 1.2
 DEFAULT_B = 0.75
 
 
+@in_named_scope("bm25_score_body")
 def bm25_score_body(postings_docs, postings_tf, doc_len, starts, lengths, idf,
                     weights, avgdl, k1, b, *, segment_pad: int, L: int):
     """Score one segment for a bag of query terms into *dense* per-doc
@@ -79,13 +82,13 @@ def bm25_score_body(postings_docs, postings_tf, doc_len, starts, lengths, idf,
 
 
 def _bm25_kernel(segment_pad: int, L: int):
-    def kernel(postings_docs, postings_tf, doc_len, starts, lengths, idf,
-               weights, avgdl, k1, b):
+    def bm25_kernel(postings_docs, postings_tf, doc_len, starts, lengths,
+                    idf, weights, avgdl, k1, b):
         return bm25_score_body(postings_docs, postings_tf, doc_len, starts,
                                lengths, idf, weights, avgdl, k1, b,
                                segment_pad=segment_pad, L=L)
 
-    return jax.jit(kernel)
+    return jax.jit(bm25_kernel)
 
 
 _KERNEL_CACHE: dict = {}

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import in_named_scope
 from .sorted_merge import bm25_merge_candidates
 
 NEG_INF = float("-inf")
@@ -47,6 +48,7 @@ NEG_INF = float("-inf")
 MAX_BOOL_CLAUSES = 8
 
 
+@in_named_scope("bool_bm25_topk_body")
 def bool_bm25_topk_body(postings_docs, postings_impact, starts, lengths,
                         idfw, slot_bits, req_mask, neg_mask, should_mask,
                         msm, *, n_pad: int, L: int, k: int,
@@ -90,6 +92,7 @@ def bool_bm25_topk_body(postings_docs, postings_impact, starts, lengths,
     return vals, out_docs.astype(jnp.int32)
 
 
+@in_named_scope("bisect_exact_scores")
 def bisect_exact_scores(postings_docs, postings_impact, starts, lengths,
                         idfw, cand_docs, *, n_pad: int):
     """Exact f32 scores of ``cand_docs`` i32[R] (``n_pad`` = empty slot)
@@ -160,6 +163,7 @@ def _rank_contrib(ids, list_ids, list_valid, rc):
     return jnp.sum(jnp.where(hit, w[None, :], 0.0), axis=1)
 
 
+@in_named_scope("rrf_fuse_body")
 def rrf_fuse_body(ids_a, ids_b, rc, *, k: int, pad_id: int):
     """Reciprocal-rank fusion of two ranked id lists (unified global id
     space; ``pad_id`` marks empty slots). Contribution order is list a
@@ -178,6 +182,7 @@ def rrf_fuse_body(ids_a, ids_b, rc, *, k: int, pad_id: int):
     return _fused_topk(score, cat, k, pad_id)
 
 
+@in_named_scope("sum_fuse_body")
 def sum_fuse_body(ids_a, vals_a, ids_b, vals_b, *, k: int, pad_id: int):
     """Hybrid linear fusion: docs in both lists sum text + knn scores
     (text first — the host combine dict's accumulation order); docs in
@@ -242,6 +247,7 @@ def rescore_combine(mode: str, primary, secondary, matched, in_window,
     return jnp.where(in_window & matched, ns, ps)
 
 
+@in_named_scope("rescore_reorder_body")
 def rescore_reorder_body(vals, ids, secondary, matched, qw, rw, window,
                          *, mode: str, k: int, pad_id: int):
     """Fused rescore stage: reorder the top ``window`` (a traced scalar

@@ -12,9 +12,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import in_named_scope
+
 
 def _postings_match_kernel(segment_pad: int, L: int):
-    def kernel(postings_docs, starts, lengths):
+    @in_named_scope("postings_match")
+    def postings_match_kernel(postings_docs, starts, lengths):
         """Count, per doc, how many of the Q postings runs contain it.
 
         Returns int32[N]; callers derive masks (>0 → any, ==Q → all).
@@ -28,11 +31,12 @@ def _postings_match_kernel(segment_pad: int, L: int):
             valid.reshape(-1).astype(jnp.int32), mode="drop")
         return matched
 
-    return jax.jit(kernel)
+    return jax.jit(postings_match_kernel)
 
 
 def _range_mask_kernel(segment_pad: int):
-    def kernel(vals_off, docs, lo, hi):
+    @in_named_scope("range_mask")
+    def range_mask_kernel(vals_off, docs, lo, hi):
         """Mask of docs having any (value - base) within [lo, hi].
 
         Bounds are float32 offsets relative to the field's per-segment base;
@@ -44,7 +48,7 @@ def _range_mask_kernel(segment_pad: int):
             in_range, mode="drop")
         return mask
 
-    return jax.jit(kernel)
+    return jax.jit(range_mask_kernel)
 
 
 _MATCH_CACHE: dict = {}

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as np
 
+from . import in_named_scope
+
 NEG_INF = float("-inf")
 
 
@@ -71,6 +73,7 @@ def make_impacts(tf: np.ndarray, docs: np.ndarray, doc_len: np.ndarray,
             ).astype(np.float32)
 
 
+@in_named_scope("bm25_merge_candidates")
 def bm25_merge_candidates(postings_docs, postings_impact, starts, lengths,
                           idfw, *, n_pad: int, L: int, slot_bits=None):
     """Sorted-merge candidate stage shared by the plain top-k kernel and the
@@ -180,6 +183,7 @@ def bm25_merge_candidates(postings_docs, postings_impact, starts, lengths,
     return sdocs, gscore, gcount, is_last
 
 
+@in_named_scope("bm25_topk_merge_body")
 def bm25_topk_merge_body(postings_docs, postings_impact, starts, lengths,
                          idfw, *, n_pad: int, L: int, k: int,
                          min_should_match: int = 1, with_count: bool = False):

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import in_named_scope
 from .topk import batched_blockwise_topk
 from .sorted_merge import bm25_merge_candidates, vmap_queries
 
@@ -120,6 +121,7 @@ def build_dense_rows(shard: dict, dense_tids: np.ndarray, impacts: np.ndarray,
 # ---------------------------------------------------------------------------
 
 
+@in_named_scope("dense_stream_topk")
 def dense_stream_topk(W, dense_blocks, *, k: int,
                       min_should_match: int = 1, u_ids=None):
     """Batched streaming top-k over the dense tier.
@@ -180,6 +182,7 @@ def dense_stream_topk(W, dense_blocks, *, k: int,
     return vals, docs, n_matched
 
 
+@in_named_scope("gather_dense_for_candidates")
 def gather_dense_for_candidates(dense_blocks, cand_docs, dense_rid, dense_w,
                                 *, n_pad: int, u_ids=None):
     """Per-candidate dense-tier contributions for ONE query.
@@ -206,6 +209,7 @@ def gather_dense_for_candidates(dense_blocks, cand_docs, dense_rid, dense_w,
     return add, cnt
 
 
+@in_named_scope("merge_topk_lists")
 def merge_topk_lists(vals_a, docs_a, vals_b, docs_b, *, k: int,
                      n_pad: int):
     """Exact union of two per-query top-k lists that may share docs (the
@@ -226,6 +230,7 @@ def merge_topk_lists(vals_a, docs_a, vals_b, docs_b, *, k: int,
     return -fv[..., :k], fd[..., :k]
 
 
+@in_named_scope("tiered_bm25_topk")
 def tiered_bm25_topk(postings_docs, postings_impact, dense_blocks,
                      starts, lengths, idfw, dense_rid, dense_w, W,
                      *, n_pad: int, L: int, k: int,

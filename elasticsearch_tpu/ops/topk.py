@@ -14,6 +14,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import in_named_scope
+
 NEG_INF = float("-inf")
 
 _BLOCK = 16384          # scores per block in the two-stage path
@@ -23,7 +25,8 @@ _BLOCKWISE_MIN = 1 << 17  # use the two-stage path above this many docs
 def _topk_kernel(n: int, k: int):
     use_blocks = n >= _BLOCKWISE_MIN and n % _BLOCK == 0 and k <= _BLOCK
 
-    def kernel(scores, mask):
+    @in_named_scope("topk")
+    def topk_kernel(scores, mask):
         """scores float32[n]; mask bool[n] (False → excluded). Returns
         (values float32[k], indices int32[k]); excluded slots carry -inf."""
         masked = jnp.where(mask, scores, NEG_INF)
@@ -38,9 +41,10 @@ def _topk_kernel(n: int, k: int):
         vals, idx = jax.lax.top_k(masked, k)
         return vals, idx.astype(jnp.int32)
 
-    return jax.jit(kernel)
+    return jax.jit(topk_kernel)
 
 
+@in_named_scope("batched_blockwise_topk")
 def batched_blockwise_topk(scores, k: int, block: int = _BLOCK):
     """Exact top-k over the last axis of ``scores`` [B, n] via the
     two-stage blockwise path: per-block ``top_k`` then a final ``top_k``

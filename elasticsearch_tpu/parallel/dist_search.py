@@ -36,6 +36,8 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..common import tracing as _tracing
+from ..ops import in_named_scope
 from ..ops.bm25 import DEFAULT_B, DEFAULT_K1, idf_weight
 from ..ops.fused_query import (bisect_exact_scores, bool_bm25_topk_body,
                                knn_raw_to_score, rescore_reorder_body,
@@ -101,6 +103,18 @@ def host_serve_enabled() -> bool:
 # ---------------------------------------------------------------------------
 
 
+def _jit_step(step, family: str):
+    """The jitted SPMD step under its kernel family's name: the XLA module
+    is ``jit_<family>`` (``jit_knn_exact``, not ``jit_body``), a name a
+    profiler trace can be reduced by whatever the batch shape. Each
+    builder's ``body`` opens the scope ``<family>``; its parts open
+    ``score`` (kNN: ``scores`` and ``block_topk``) and ``merge`` under
+    it."""
+    step.__name__ = step.__qualname__ = family
+    return jax.jit(step)
+
+
+@in_named_scope("merge")
 def _global_topk_reduce(vals, idx, *, s_loc: int, kk: int, n_pad: int,
                         out_k: Optional[int] = None, payload=()):
     """Shared ICI reduce: globalize local doc ids, merge the device's own
@@ -165,10 +179,12 @@ def build_bm25_topk_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
     kk = min(k, n_pad)
     out_k = min(k, n_shards * n_pad)
 
+    @in_named_scope("bm25_topk")
     def body(pd, pi, st, ln, idfw):
         assert st.shape[-1] == Q, (
             f"starts last dim {st.shape[-1]} != step Q={Q}")
 
+        @in_named_scope("score")
         def per_shard(pd_s, pi_s, st_s, ln_s):
             def per_query(st_q, ln_q, iw_q):
                 # scatter-free sorted-merge scoring: top-k over the Q*L
@@ -202,7 +218,7 @@ def build_bm25_topk_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
                   P(AXIS_REPLICA, None)),
         out_specs=out_specs,
         check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "bm25_topk")
 
 
 def build_tiered_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
@@ -239,7 +255,9 @@ def build_tiered_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
     out_k = min(k, n_shards * n_pad)
     gathered = U is not None and U < T_pad
 
+    @in_named_scope("bm25_tiered")
     def body(pd, pi, dense, st, ln, idfw, rid, dw, W, u_ids):
+        @in_named_scope("score")
         def per_shard(pd_s, pi_s, dense_s, st_s, ln_s, rid_s, dw_s, W_s,
                       u_s):
             return tiered_bm25_topk(
@@ -273,7 +291,7 @@ def build_tiered_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int, k: int,
                   P(AXIS_SHARD, None)),
         out_specs=out_specs,
         check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "bm25_tiered")
 
 
 #: docs per streamed kNN block (the dense-tier DENSE_BLOCK pattern): the
@@ -323,6 +341,7 @@ def _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn, *, similarity: str,
     ``qn`` the cached ``Σq²`` rows (l2 only). Returns
     (vals f32[B, kk], local idx i32[B, kk])."""
 
+    @in_named_scope("scores")
     def score_block(vecs_b, vn_b, exists_b):
         # HIGHEST: the TPU's default matmul precision rounds f32 operands
         # to bf16 (~1e-3 on a cosine) — this is the EXACT scan, held to
@@ -339,42 +358,51 @@ def _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn, *, similarity: str,
             scores = dots
         return jnp.where(exists_b[None, :], scores, NEG_INF)
 
-    if not use_blocks:
-        vals, idx = batched_blockwise_topk(
-            score_block(vecs_s, vn_s, exists_s), kk)
-        return vals, idx.astype(jnp.int32)
-    nb = n_pad // blk
-    vecs_blk = vecs_s.reshape(nb, blk, dim)
-    vn_blk = vn_s.reshape(nb, blk)
-    exists_blk = exists_s.reshape(nb, blk)
-    # seed the accumulator from block 0 so every carried entry is
-    # a real (value, global index) pair: merges then keep the
-    # lowest global index among equal values — identical tie
-    # order (and identical -inf padding indices) to the one-shot
-    # full-matrix top_k
-    v0, i0 = batched_blockwise_topk(
-        score_block(vecs_blk[0], vn_blk[0], exists_blk[0]), kk)
+    # the running top-k, under its own scope beside ``scores``
+    block_topk = in_named_scope("block_topk")(batched_blockwise_topk)
 
-    def step_blk(carry, b_idx):
-        acc_v, acc_i = carry
-        # blocks are read in place by index: handing the scan
-        # ``vecs_blk[1:]`` as xs materializes a second copy of the whole
-        # corpus per dispatch (2x the corpus in HBM; refused by the TPU
-        # compiler at 2^22 x 768)
-        bv, bi = batched_blockwise_topk(
-            score_block(
-                lax.dynamic_index_in_dim(vecs_blk, b_idx, keepdims=False),
-                lax.dynamic_index_in_dim(vn_blk, b_idx, keepdims=False),
-                lax.dynamic_index_in_dim(exists_blk, b_idx,
-                                         keepdims=False)), kk)
-        gi = bi.astype(jnp.int32) + b_idx * blk
+    @in_named_scope("block_topk")
+    def merge_block(acc_v, acc_i, bv, gi):
         cat_v = jnp.concatenate([acc_v, bv], axis=1)
         cat_i = jnp.concatenate([acc_i, gi], axis=1)
         # earlier blocks sit first: top_k's lowest-position tie
         # preference keeps doc-ascending tie order
         nv, sel = lax.top_k(cat_v, kk)
-        ni = jnp.take_along_axis(cat_i, sel, axis=1)
-        return (nv, ni), None
+        return nv, jnp.take_along_axis(cat_i, sel, axis=1)
+
+    if not use_blocks:
+        vals, idx = block_topk(score_block(vecs_s, vn_s, exists_s), kk)
+        return vals, idx.astype(jnp.int32)
+    nb = n_pad // blk
+    with jax.named_scope("scores"):
+        vecs_blk = vecs_s.reshape(nb, blk, dim)
+        vn_blk = vn_s.reshape(nb, blk)
+        exists_blk = exists_s.reshape(nb, blk)
+
+    def score_block_at(b_idx):
+        # blocks are read in place by index: handing the scan
+        # ``vecs_blk[1:]`` as xs materializes a second copy of the whole
+        # corpus per dispatch (2x the corpus in HBM; refused by the TPU
+        # compiler at 2^22 x 768)
+        with jax.named_scope("scores"):
+            block = [lax.dynamic_index_in_dim(a, b_idx, keepdims=False)
+                     for a in (vecs_blk, vn_blk, exists_blk)]
+        return score_block(*block)
+
+    # seed the accumulator from block 0 so every carried entry is
+    # a real (value, global index) pair: merges then keep the
+    # lowest global index among equal values — identical tie
+    # order (and identical -inf padding indices) to the one-shot
+    # full-matrix top_k
+    with jax.named_scope("scores"):
+        first = (vecs_blk[0], vn_blk[0], exists_blk[0])
+    v0, i0 = block_topk(score_block(*first), kk)
+
+    def step_blk(carry, b_idx):
+        acc_v, acc_i = carry
+        bv, bi = block_topk(score_block_at(b_idx), kk)
+        gi = bi.astype(jnp.int32) + b_idx * blk
+        return merge_block(acc_v, acc_i, bv, gi), None
 
     (vals, idx), _ = lax.scan(
         step_blk, (v0, i0.astype(jnp.int32)),
@@ -428,13 +456,15 @@ def build_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
     # ops/topk.py); n_pad is pow2 so any pow2 block ≤ n_pad divides it
     blk, use_blocks = _knn_blocking(block, n_pad, kk)
 
+    @in_named_scope("knn_exact")
     def body(vecs, vnorm2, exists, q):
-        if similarity == "cosine":
-            qq = q / jnp.maximum(
-                jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
-        else:
-            qq = q
-        qn = jnp.sum(q * q, axis=-1)
+        with jax.named_scope("scores"):
+            if similarity == "cosine":
+                qq = q / jnp.maximum(
+                    jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
+            else:
+                qq = q
+            qn = jnp.sum(q * q, axis=-1)
 
         def per_shard(vecs_s, vn_s, exists_s):
             return _knn_shard_scan(vecs_s, vn_s, exists_s, qq, qn,
@@ -452,7 +482,7 @@ def build_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                   P(AXIS_SHARD, None), P(AXIS_REPLICA, None)),
         out_specs=(P(AXIS_REPLICA, None), P(AXIS_REPLICA, None)),
         check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "knn_exact")
 
 
 # ---------------------------------------------------------------------------
@@ -819,6 +849,7 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
     out_k = min(k, n_shards * n_pad)
     l2 = similarity == "l2_norm"
 
+    @in_named_scope("knn_ivf")
     def body(codes, scale, off, rowid, rcl, vecs, vnorm2, q, probed,
              u_blocks):
         if similarity == "cosine":
@@ -829,6 +860,7 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
         qsum = jnp.sum(qq, axis=-1)                       # [B]
         qn = jnp.sum(q * q, axis=-1)                      # [B]
 
+        @in_named_scope("score")
         def per_shard(codes_s, scale_s, off_s, rowid_s, rcl_s, vecs_s,
                       vn_s, u_s):
             # gather ONLY the probed-union blocks: HBM reads scale with
@@ -937,7 +969,7 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                   P(AXIS_SHARD, None)),
         out_specs=(P(AXIS_REPLICA, None), P(AXIS_REPLICA, None)),
         check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "knn_ivf")
 
 
 # ---------------------------------------------------------------------------
@@ -1384,7 +1416,9 @@ def build_pruned_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, k: int,
     prune_active = kq <= W
     kq_idx = min(kq, W) - 1
 
+    @in_named_scope("bm25_pruned")
     def body(pd, pi, td, tc, ts, to, sched, w, rho, slack, st, ln, idfw):
+        @in_named_scope("score")
         def per_shard(pd_s, pi_s, td_s, tc_s, ts_s, to_s, sched_s, w_s,
                       rho_s, slack_s, st_s, ln_s):
             def per_query(sched_q, w_q, rho_q, slack_q, st_q, ln_q, iw_q):
@@ -1500,7 +1534,7 @@ def build_pruned_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, k: int,
                    P(AXIS_REPLICA), P(AXIS_REPLICA), P(AXIS_REPLICA),
                    P(AXIS_REPLICA)),
         check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "bm25_pruned")
 
 
 # ---------------------------------------------------------------------------
@@ -1543,12 +1577,14 @@ def build_bool_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int,
     pad_id = n_shards * n_pad
     rescore = Q2 > 0
 
+    @in_named_scope("bm25_bool")
     def body(pd, pi, st, ln, idfw, cbits, req, neg, shd, msm, *rest):
         if rescore:
             st2, ln2, iw2, qw, rw, rwin = rest
         else:
             st2 = ln2 = iw2 = qw = rw = rwin = None
 
+        @in_named_scope("score")
         def per_shard(pd_s, pi_s, st_s, ln_s, st2_s, ln2_s):
             def per_query(st_q, ln_q, iw_q, cb_q, req_q, neg_q, shd_q,
                           msm_q, st2_q, ln2_q, iw2_q):
@@ -1593,6 +1629,7 @@ def build_bool_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int,
                 vals, idx, s_loc=s_loc, kk=kk, n_pad=n_pad, out_k=out_k)
         counts = lax.psum(jnp.sum(cnt, axis=1), AXIS_SHARD)
         if rescore:
+            @in_named_scope("rescore")
             def finish(v_q, g_q, sec_q, fnd_q, qw_q, rw_q, rwin_q):
                 g_q = jnp.where(v_q > NEG_INF, g_q, pad_id)
                 return rescore_reorder_body(
@@ -1616,7 +1653,7 @@ def build_bool_bm25_step(mesh: Mesh, *, n_pad: int, Q: int, L: int,
     out_specs = (repl2, repl2) + ((repl1,) if with_count else ())
     step = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=out_specs, check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "bm25_bool")
 
 
 def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
@@ -1660,6 +1697,7 @@ def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
     blk, use_blocks = _knn_blocking(block, n_pad_k, kk_k)
     rescore = Q2 > 0
 
+    @in_named_scope("fused_hybrid")
     def body(pd, pi, kvecs, kvn, kex, st, ln, idfw, cbits, req, neg,
              shd, msm, qv, kboost, rc, wt, wk, *rest):
         if rescore:
@@ -1673,6 +1711,7 @@ def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
             qq = qv
         qn = jnp.sum(qv * qv, axis=-1)
 
+        @in_named_scope("score")
         def per_shard(pd_s, pi_s, kv_s, kn_s, ke_s, st_s, ln_s,
                       st2_s, ln2_s):
             def per_query(st_q, ln_q, iw_q, cb_q, req_q, neg_q, shd_q,
@@ -1746,6 +1785,7 @@ def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
 
         n_f = out_t + out_kn
 
+        @in_named_scope("fuse")
         def finish(tv_q, tg_q, kv_q, kg_q, kb_q, rc_q, wt_q, wk_q,
                    tsec_q, tfnd_q, ksec_q, kfnd_q, qw_q, rw_q, rwin_q):
             pos_t = jnp.arange(out_t, dtype=jnp.int32)
@@ -1812,7 +1852,7 @@ def build_fused_hybrid_step(mesh: Mesh, *, n_pad_t: int, Q: int, L: int,
     out_specs = (repl2, repl2, repl1, repl2, repl2, repl2, repl2)
     step = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=out_specs, check_vma=False)
-    return jax.jit(step)
+    return _jit_step(step, "fused_hybrid")
 
 
 # ---------------------------------------------------------------------------
@@ -2499,103 +2539,112 @@ class DistributedSearchPlane:
         (``prep_ms`` host assembly + upload, ``dispatch_ms`` device step
         incl. any compile, ``fetch_ms`` result sync + decode).
         """
-        t0 = time.perf_counter()
-        B = len(queries)
-        # pad the batch to a replica-axis multiple (the mesh partitions the
-        # batch dim over replicas); padded slots run a no-op query
-        n_repl = self.mesh.shape[AXIS_REPLICA]
-        B_pad = -(-B // n_repl) * n_repl
-        queries = list(queries) + [[] for _ in range(B_pad - B)]
-        needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
-        if Q is None:
-            Q = round_up_pow2(needed_q)
-        elif Q < needed_q:
-            raise ValueError(
-                f"Q={Q} would drop terms from a {needed_q}-term query; "
-                f"pass Q=None to size automatically")
-        (starts, lengths, idfw, dense_rid, dense_hit, max_len,
-         any_dense) = self._lookup(queries, Q, extra_docs=extra_docs,
-                                   extra_df=extra_df)
-        if L is None:
-            L = round_up_pow2(max_len)
-        elif L < max_len:
-            raise ValueError(
-                f"L={L} would truncate a postings run of length {max_len}; "
-                f"pass L=None to size automatically")
-        # L may never exceed the table's sentinel slack (slices would clamp
-        # into foreign runs); L_cap >= max_sparse_df, so no real sparse run
-        # is truncated
-        L = min(L, self.L_cap)
-        np.minimum(lengths, L, out=lengths)
-        repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
-        repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
-        use_tiered = any_dense if tiered is None else (tiered and self.T_pad > 0)
-        if tiered is False and any_dense:
-            raise ValueError("tiered=False but the batch hits dense-tier terms")
-        docs_dev, impacts_dev, dense_dev, stream_b = self._corpus_refs()
-        if use_tiered:
-            U, u_ids, rid_slots, dense_w, W = self._dense_inputs(
-                idfw, dense_rid, dense_hit)
-            step = self._get_step(Q, L, k, tiered=True,
-                                  with_count=with_totals, U=U)
-            shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
-            step_args = (
-                docs_dev, impacts_dev, dense_dev,
-                jax.device_put(starts, repl3),
-                jax.device_put(lengths, repl3),
-                jax.device_put(idfw, repl),
-                jax.device_put(rid_slots, repl3),
-                jax.device_put(dense_w, repl3),
-                jax.device_put(W, repl3),
-                jax.device_put(u_ids, shard2))
-        else:
-            step = self._get_step(Q, L, k, with_count=with_totals)
-            step_args = (
-                docs_dev, impacts_dev,
-                jax.device_put(starts, repl3), jax.device_put(lengths, repl3),
-                jax.device_put(idfw, repl))
-        t1 = time.perf_counter()
-        out = _run_step(self._serial_dispatch, step, *step_args)
-        if stages is not None:
-            # sync here so device time lands in dispatch_ms, not in the
-            # first np.asarray of the fetch below
-            jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        self.n_dispatches += 1
-        from ..common import telemetry as _tm
-        _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
-                                 self.mesh.shape[AXIS_REPLICA])
-        if stages is not None:
-            # per-dispatch compile-cache verdict: profile's serving
-            # section distinguishes a first-shape compile from steady state
-            stages["compile_cache"] = (
-                "miss" if _tm.last_call_compiled() else "hit")
-        vals, gdocs = out[0], out[1]
-        vals = np.asarray(vals)[:B]          # drop replica-padding slots
-        gdocs = np.asarray(gdocs)[:B]
-        # device-transfer accounting: the per-dispatch uploads (resident
-        # hot corpus arrays excluded; a warm plane's per-dispatch corpus
-        # stream counted) + the fetched result rows
-        h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + stream_b + \
-            (rid_slots.nbytes + dense_w.nbytes + W.nbytes + u_ids.nbytes
-             if use_tiered else 0)
-        d2h = vals.nbytes + gdocs.nbytes
-        _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
-        if stream_b:
-            _tm.record_tier_stream_bytes(stream_b)
-        if stages is not None:
-            # per-dispatch bytes for task resource attribution (the
-            # micro-batcher shares them across the batch's slots)
-            stages["h2d_bytes"] = h2d
-            stages["d2h_bytes"] = d2h
-        hits = []
-        for bi in range(B):
-            row = []
-            for v, g in zip(vals[bi], gdocs[bi]):
-                if v == NEG_INF:
-                    break
-                row.append((int(g) // self.n_pad, int(g) % self.n_pad))
-            hits.append(row)
+        with _tracing.Phases() as phases:
+            phases.enter("plane[h2d]")
+            t0 = time.perf_counter()
+            B = len(queries)
+            # pad the batch to a replica-axis multiple (the mesh partitions the
+            # batch dim over replicas); padded slots run a no-op query
+            n_repl = self.mesh.shape[AXIS_REPLICA]
+            B_pad = -(-B // n_repl) * n_repl
+            queries = list(queries) + [[] for _ in range(B_pad - B)]
+            needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
+            if Q is None:
+                Q = round_up_pow2(needed_q)
+            elif Q < needed_q:
+                raise ValueError(
+                    f"Q={Q} would drop terms from a {needed_q}-term query; "
+                    f"pass Q=None to size automatically")
+            (starts, lengths, idfw, dense_rid, dense_hit, max_len,
+             any_dense) = self._lookup(queries, Q, extra_docs=extra_docs,
+                                       extra_df=extra_df)
+            if L is None:
+                L = round_up_pow2(max_len)
+            elif L < max_len:
+                raise ValueError(
+                    f"L={L} would truncate a postings run of length "
+                    f"{max_len}; pass L=None to size automatically")
+            # L may never exceed the table's sentinel slack (slices would clamp
+            # into foreign runs); L_cap >= max_sparse_df, so no real sparse run
+            # is truncated
+            L = min(L, self.L_cap)
+            np.minimum(lengths, L, out=lengths)
+            repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
+            repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
+            use_tiered = any_dense if tiered is None \
+                else (tiered and self.T_pad > 0)
+            if tiered is False and any_dense:
+                raise ValueError(
+                    "tiered=False but the batch hits dense-tier terms")
+            docs_dev, impacts_dev, dense_dev, stream_b = self._corpus_refs()
+            if use_tiered:
+                U, u_ids, rid_slots, dense_w, W = self._dense_inputs(
+                    idfw, dense_rid, dense_hit)
+                step = self._get_step(Q, L, k, tiered=True,
+                                      with_count=with_totals, U=U)
+                shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
+                step_args = (
+                    docs_dev, impacts_dev, dense_dev,
+                    jax.device_put(starts, repl3),
+                    jax.device_put(lengths, repl3),
+                    jax.device_put(idfw, repl),
+                    jax.device_put(rid_slots, repl3),
+                    jax.device_put(dense_w, repl3),
+                    jax.device_put(W, repl3),
+                    jax.device_put(u_ids, shard2))
+            else:
+                step = self._get_step(Q, L, k, with_count=with_totals)
+                step_args = (
+                    docs_dev, impacts_dev,
+                    jax.device_put(starts, repl3),
+                    jax.device_put(lengths, repl3),
+                    jax.device_put(idfw, repl))
+            phases.enter("plane[launch]")
+            t1 = time.perf_counter()
+            out = _run_step(self._serial_dispatch, step, *step_args)
+            if stages is not None:
+                # sync here so device time lands in dispatch_ms, not in the
+                # first np.asarray of the fetch below
+                phases.enter("plane[sync]")
+                jax.block_until_ready(out)
+            phases.enter("plane[d2h]")
+            t2 = time.perf_counter()
+            self.n_dispatches += 1
+            from ..common import telemetry as _tm
+            _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
+                                     self.mesh.shape[AXIS_REPLICA])
+            if stages is not None:
+                # per-dispatch compile-cache verdict: profile's serving
+                # section distinguishes a first-shape compile from steady state
+                stages["compile_cache"] = (
+                    "miss" if _tm.last_call_compiled() else "hit")
+            vals, gdocs = out[0], out[1]
+            vals = np.asarray(vals)[:B]          # drop replica-padding slots
+            gdocs = np.asarray(gdocs)[:B]
+            # device-transfer accounting: the per-dispatch uploads (resident
+            # hot corpus arrays excluded; a warm plane's per-dispatch corpus
+            # stream counted) + the fetched result rows
+            h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + stream_b + \
+                (rid_slots.nbytes + dense_w.nbytes + W.nbytes + u_ids.nbytes
+                 if use_tiered else 0)
+            d2h = vals.nbytes + gdocs.nbytes
+            _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+            if stream_b:
+                _tm.record_tier_stream_bytes(stream_b)
+            if stages is not None:
+                # per-dispatch bytes for task resource attribution (the
+                # micro-batcher shares them across the batch's slots)
+                stages["h2d_bytes"] = h2d
+                stages["d2h_bytes"] = d2h
+            phases.enter("plane[decode]")
+            hits = []
+            for bi in range(B):
+                row = []
+                for v, g in zip(vals[bi], gdocs[bi]):
+                    if v == NEG_INF:
+                        break
+                    row.append((int(g) // self.n_pad, int(g) % self.n_pad))
+                hits.append(row)
         if stages is not None:
             stages["prep_ms"] = (t1 - t0) * 1e3
             stages["dispatch_ms"] = (t2 - t1) * 1e3
@@ -3133,142 +3182,148 @@ class DistributedSearchPlane:
                 tiered=self.T_pad > 0 or None,
                 with_totals=with_totals, stages=stages,
                 extra_docs=extra_docs, extra_df=extra_df)
-        t0 = time.perf_counter()
-        tier = self.blockmax
-        BS = tier.block
-        B = len(queries)
-        n_repl = self.mesh.shape[AXIS_REPLICA]
-        B_pad = -(-B // n_repl) * n_repl
-        queries = list(queries) + [[] for _ in range(B_pad - B)]
-        needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
-        Q = max(self.SERVING_Q_MIN, round_up_pow2(needed_q))
-        (starts, lengths, idfw, _rid, dense_hit, _ml,
-         any_dense) = self._lookup(queries, Q, extra_docs=extra_docs,
+        with _tracing.Phases() as phases:
+            phases.enter("plane[h2d]")
+            t0 = time.perf_counter()
+            tier = self.blockmax
+            BS = tier.block
+            B = len(queries)
+            n_repl = self.mesh.shape[AXIS_REPLICA]
+            B_pad = -(-B // n_repl) * n_repl
+            queries = list(queries) + [[] for _ in range(B_pad - B)]
+            needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
+            Q = max(self.SERVING_Q_MIN, round_up_pow2(needed_q))
+            (starts, lengths, idfw, _rid, dense_hit, _ml,
+             any_dense) = self._lookup(queries, Q, extra_docs=extra_docs,
+                                       extra_df=extra_df)
+            if any_dense:
+                # Zipf-head terms live in the dense streaming-matmul tier —
+                # already the device's fast path for exactly those postings.
+                # Dispatch at the pre-warmed serving shapes (ladder L, Q
+                # floor): a raw pow2 L here would compile off-lattice
+                # mid-traffic
+                return self.search(queries[:B], k=k, tiered=True, Q=Q,
+                                   L=self.ladder_L(
+                                       self.max_run_len(queries[:B])),
+                                   with_totals=with_totals,
+                                   stages=stages, extra_docs=extra_docs,
                                    extra_df=extra_df)
-        if any_dense:
-            # Zipf-head terms live in the dense streaming-matmul tier —
-            # already the device's fast path for exactly those postings.
-            # Dispatch at the pre-warmed serving shapes (ladder L, Q
-            # floor): a raw pow2 L here would compile off-lattice
-            # mid-traffic
-            return self.search(queries[:B], k=k, tiered=True, Q=Q,
-                               L=self.ladder_L(
-                                   self.max_run_len(queries[:B])),
-                               with_totals=with_totals,
-                               stages=stages, extra_docs=extra_docs,
-                               extra_df=extra_df)
-        S = self.n_shards
-        NB = tier.n_blocks
-        P_need = 1
-        per_qs: List[List[tuple]] = []
-        for bi, terms in enumerate(queries):
-            idfw_of = self._query_idfw(terms, extra_docs, extra_df)
-            rows = []
-            for si, sh in enumerate(self.shards):
-                term_rows = [(int(sh["term_ids"][t]), w)
-                             for t, w in idfw_of.items()
-                             if t in sh["term_ids"]]
-                blk, wblk, rho, _tpos, slack = tier.schedule(
-                    si, term_rows)
-                rows.append((blk, wblk, rho, slack))
-                P_need = max(P_need, blk.shape[0])
-            per_qs.append(rows)
-        P_sched = round_up_pow2(P_need)
-        sched = np.full((B_pad, S, P_sched), NB, np.int32)
-        w_arr = np.zeros((B_pad, S, P_sched), np.float32)
-        rho_arr = np.zeros((B_pad, S, P_sched), np.float32)
-        slack_arr = np.zeros((B_pad, S), np.float32)
-        sched_lens = np.zeros((B_pad, S), np.int64)
-        for bi, rows in enumerate(per_qs):
-            for si, (blk, wblk, rho, slack) in enumerate(rows):
-                n = blk.shape[0]
-                sched[bi, si, :n] = blk
-                w_arr[bi, si, :n] = wblk
-                rho_arr[bi, si, :n] = rho
-                slack_arr[bi, si] = slack
-                sched_lens[bi, si] = n
-        kk = min(k, self.n_pad)
-        W = min(round_up_pow2(max(k * Q, 1)), LEX_THETA_WINDOW)
-        R = min(round_up_pow2(max(self.prune_rerank * kk, 64)),
-                self.n_pad)
-        step = self._get_pruned_step(Q, k, P_sched, W, R)
-        dev = tier.device_arrays(self.mesh)
-        repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
-        repl2 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD))
-        repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
-        t1 = time.perf_counter()
-        out = _run_step(
-            self._serial_dispatch, step,
-            self.docs_dev, self.impacts_dev,
-            dev["docs"], dev["codes"], dev["scale"], dev["off"],
-            jax.device_put(sched, repl3),
-            jax.device_put(w_arr, repl3),
-            jax.device_put(rho_arr, repl3),
-            jax.device_put(slack_arr, repl2),
-            jax.device_put(starts, repl3),
-            jax.device_put(lengths, repl3),
-            jax.device_put(idfw, repl))
-        if stages is not None:
-            jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        self.n_dispatches += 1
-        from ..common import telemetry as _tm
-        _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
-                                 self.mesh.shape[AXIS_REPLICA])
-        compiled = _tm.last_call_compiled()
-        gvals = np.asarray(out[0])[:B]
-        gdocs = np.asarray(out[1])[:B]
-        matched = np.asarray(out[2])[:B]
-        unsafe = np.asarray(out[3])[:B] > 0
-        pruned = np.asarray(out[4])[:B] > 0
-        n_sc = np.asarray(out[5])[:B]
-        h2d = sched.nbytes + w_arr.nbytes + rho_arr.nbytes + \
-            slack_arr.nbytes + starts.nbytes + lengths.nbytes + idfw.nbytes
-        d2h = gvals.nbytes + gdocs.nbytes + matched.nbytes * 4
-        _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
-        vals_out = np.full((B, k), NEG_INF, np.float32)
-        wk = min(k, gvals.shape[1])
-        vals_out[:, :wk] = gvals[:, :wk]
-        hits_out: List[List[Tuple[int, int]]] = []
-        totals: List = []
-        for bi in range(B):
-            row = []
-            for v, g in zip(vals_out[bi], gdocs[bi]):
-                if v == NEG_INF:
-                    break
-                row.append((int(g) // self.n_pad, int(g) % self.n_pad))
-            hits_out.append(row)
-            totals.append((int(matched[bi]), "gte") if pruned[bi]
-                          else int(matched[bi]))
-        # rank-safety fallback: queries whose survivor window could not
-        # certify the top-k re-serve through the eager kernel (pruned
-        # results are bit-exact BY CONSTRUCTION, not by luck)
-        bad = np.flatnonzero(unsafe)
-        if bad.size:
-            bad_q = [queries[i] for i in bad]
-            # pad to a power of two like the micro-batcher does: a raw
-            # count of unsafe queries would compile one eager program per
-            # distinct count, off the (B-pow2 x k x L-rung) lattice
-            bad_q += [[] for _ in range(
-                round_up_pow2(len(bad_q), 1) - len(bad_q))]
-            ev = self.search(bad_q, k=k, Q=Q,
-                             L=self.ladder_L(self.max_run_len(bad_q)),
-                             tiered=self.T_pad > 0 or None,
-                             with_totals=True, extra_docs=extra_docs,
-                             extra_df=extra_df)
-            for j, i in enumerate(bad):
-                src = np.asarray(ev[0][j], np.float32)[:k]
-                vals_out[i] = NEG_INF
-                vals_out[i, :src.shape[0]] = src
-                hits_out[i] = list(ev[1][j])[:k]
-                totals[i] = int(ev[2][j])
-        blocks_scored = int(n_sc.sum())
-        blocks_total = int(sched_lens[:B].sum())
-        q_bytes = blocks_scored * BS * 5 + blocks_total * 4
-        x_bytes = B * R * Q * 8 * S
-        _tm.record_lex(blocks_scored=blocks_scored,
-                       blocks_skipped=blocks_total - blocks_scored,
-                       quantized_bytes=q_bytes, exact_bytes=x_bytes)
+            S = self.n_shards
+            NB = tier.n_blocks
+            P_need = 1
+            per_qs: List[List[tuple]] = []
+            for bi, terms in enumerate(queries):
+                idfw_of = self._query_idfw(terms, extra_docs, extra_df)
+                rows = []
+                for si, sh in enumerate(self.shards):
+                    term_rows = [(int(sh["term_ids"][t]), w)
+                                 for t, w in idfw_of.items()
+                                 if t in sh["term_ids"]]
+                    blk, wblk, rho, _tpos, slack = tier.schedule(
+                        si, term_rows)
+                    rows.append((blk, wblk, rho, slack))
+                    P_need = max(P_need, blk.shape[0])
+                per_qs.append(rows)
+            P_sched = round_up_pow2(P_need)
+            sched = np.full((B_pad, S, P_sched), NB, np.int32)
+            w_arr = np.zeros((B_pad, S, P_sched), np.float32)
+            rho_arr = np.zeros((B_pad, S, P_sched), np.float32)
+            slack_arr = np.zeros((B_pad, S), np.float32)
+            sched_lens = np.zeros((B_pad, S), np.int64)
+            for bi, rows in enumerate(per_qs):
+                for si, (blk, wblk, rho, slack) in enumerate(rows):
+                    n = blk.shape[0]
+                    sched[bi, si, :n] = blk
+                    w_arr[bi, si, :n] = wblk
+                    rho_arr[bi, si, :n] = rho
+                    slack_arr[bi, si] = slack
+                    sched_lens[bi, si] = n
+            kk = min(k, self.n_pad)
+            W = min(round_up_pow2(max(k * Q, 1)), LEX_THETA_WINDOW)
+            R = min(round_up_pow2(max(self.prune_rerank * kk, 64)),
+                    self.n_pad)
+            step = self._get_pruned_step(Q, k, P_sched, W, R)
+            dev = tier.device_arrays(self.mesh)
+            repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
+            repl2 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD))
+            repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
+            phases.enter("plane[launch]")
+            t1 = time.perf_counter()
+            out = _run_step(
+                self._serial_dispatch, step,
+                self.docs_dev, self.impacts_dev,
+                dev["docs"], dev["codes"], dev["scale"], dev["off"],
+                jax.device_put(sched, repl3),
+                jax.device_put(w_arr, repl3),
+                jax.device_put(rho_arr, repl3),
+                jax.device_put(slack_arr, repl2),
+                jax.device_put(starts, repl3),
+                jax.device_put(lengths, repl3),
+                jax.device_put(idfw, repl))
+            if stages is not None:
+                phases.enter("plane[sync]")
+                jax.block_until_ready(out)
+            phases.enter("plane[d2h]")
+            t2 = time.perf_counter()
+            self.n_dispatches += 1
+            from ..common import telemetry as _tm
+            _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
+                                     self.mesh.shape[AXIS_REPLICA])
+            compiled = _tm.last_call_compiled()
+            gvals = np.asarray(out[0])[:B]
+            gdocs = np.asarray(out[1])[:B]
+            matched = np.asarray(out[2])[:B]
+            unsafe = np.asarray(out[3])[:B] > 0
+            pruned = np.asarray(out[4])[:B] > 0
+            n_sc = np.asarray(out[5])[:B]
+            h2d = sched.nbytes + w_arr.nbytes + rho_arr.nbytes + \
+                slack_arr.nbytes + starts.nbytes + lengths.nbytes + idfw.nbytes
+            d2h = gvals.nbytes + gdocs.nbytes + matched.nbytes * 4
+            _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+            phases.enter("plane[decode]")
+            vals_out = np.full((B, k), NEG_INF, np.float32)
+            wk = min(k, gvals.shape[1])
+            vals_out[:, :wk] = gvals[:, :wk]
+            hits_out: List[List[Tuple[int, int]]] = []
+            totals: List = []
+            for bi in range(B):
+                row = []
+                for v, g in zip(vals_out[bi], gdocs[bi]):
+                    if v == NEG_INF:
+                        break
+                    row.append((int(g) // self.n_pad, int(g) % self.n_pad))
+                hits_out.append(row)
+                totals.append((int(matched[bi]), "gte") if pruned[bi]
+                              else int(matched[bi]))
+            # rank-safety fallback: queries whose survivor window could not
+            # certify the top-k re-serve through the eager kernel (pruned
+            # results are bit-exact BY CONSTRUCTION, not by luck)
+            bad = np.flatnonzero(unsafe)
+            if bad.size:
+                bad_q = [queries[i] for i in bad]
+                # pad to a power of two like the micro-batcher does: a raw
+                # count of unsafe queries would compile one eager program per
+                # distinct count, off the (B-pow2 x k x L-rung) lattice
+                bad_q += [[] for _ in range(
+                    round_up_pow2(len(bad_q), 1) - len(bad_q))]
+                ev = self.search(bad_q, k=k, Q=Q,
+                                 L=self.ladder_L(self.max_run_len(bad_q)),
+                                 tiered=self.T_pad > 0 or None,
+                                 with_totals=True, extra_docs=extra_docs,
+                                 extra_df=extra_df)
+                for j, i in enumerate(bad):
+                    src = np.asarray(ev[0][j], np.float32)[:k]
+                    vals_out[i] = NEG_INF
+                    vals_out[i, :src.shape[0]] = src
+                    hits_out[i] = list(ev[1][j])[:k]
+                    totals[i] = int(ev[2][j])
+            blocks_scored = int(n_sc.sum())
+            blocks_total = int(sched_lens[:B].sum())
+            q_bytes = blocks_scored * BS * 5 + blocks_total * 4
+            x_bytes = B * R * Q * 8 * S
+            _tm.record_lex(blocks_scored=blocks_scored,
+                           blocks_skipped=blocks_total - blocks_scored,
+                           quantized_bytes=q_bytes, exact_bytes=x_bytes)
         if stages is not None:
             stages["prep_ms"] = (t1 - t0) * 1e3
             stages["dispatch_ms"] = (t2 - t1) * 1e3
@@ -3466,75 +3521,82 @@ class DistributedSearchPlane:
         ladder L, fixed NC unroll). Dense-tier terms cannot ride the
         sparse slice — callers check :meth:`has_dense_terms` first."""
         from ..ops.fused_query import MAX_BOOL_CLAUSES
-        t0 = time.perf_counter()
-        B = len(bool_queries)
-        n_repl = self.mesh.shape[AXIS_REPLICA]
-        B_pad = -(-B // n_repl) * n_repl
-        bool_queries = list(bool_queries) + [
-            {"clauses": [], "msm": 0} for _ in range(B_pad - B)]
-        Q = max(self.SERVING_Q_MIN,
-                round_up_pow2(self.bool_slot_count(bool_queries)))
-        (starts, lengths, idfw, cbits, req, neg, shd, msm, max_len,
-         any_dense) = self.bool_inputs(bool_queries, Q,
-                                       extra_docs=extra_docs,
-                                       extra_df=extra_df)
-        if any_dense:
-            raise ValueError(
-                "bool batch touches dense-tier terms; the sparse-slice "
-                "bool step cannot serve it (fall back)")
-        L = min(self.ladder_L(max_len), self.L_cap)
-        np.minimum(lengths, L, out=lengths)
-        step = self._get_bool_step(Q, L, k, with_count=True,
-                                   nc=MAX_BOOL_CLAUSES)
-        # warm plane: stream the sparse tables per dispatch (the bool
-        # step never reads the dense tier, so only docs/impacts ship)
-        if self.storage_tier == "hot":
-            docs_dev, impacts_dev, stream_b = \
-                self.docs_dev, self.impacts_dev, 0
-        else:
-            _warm = self._warm_host
-            _cs = NamedSharding(self.mesh, P(AXIS_SHARD, None))
-            docs_dev = jax.device_put(_warm["docs"], _cs)
-            impacts_dev = jax.device_put(_warm["impacts"], _cs)
-            stream_b = int(_warm["docs"].nbytes) + \
-                int(_warm["impacts"].nbytes)
-        repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
-        repl1 = NamedSharding(self.mesh, P(AXIS_REPLICA))
-        repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD,
-                                           None))
-        t1 = time.perf_counter()
-        out = _run_step(
-            self._serial_dispatch, step, docs_dev,
-            impacts_dev,
-            jax.device_put(starts, repl3), jax.device_put(lengths, repl3),
-            jax.device_put(idfw, repl), jax.device_put(cbits, repl),
-            jax.device_put(req, repl1), jax.device_put(neg, repl1),
-            jax.device_put(shd, repl1), jax.device_put(msm, repl1))
-        if stages is not None:
-            jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        self.n_dispatches += 1
-        from ..common import telemetry as _tm
-        _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
-                                 self.mesh.shape[AXIS_REPLICA])
-        compiled = _tm.last_call_compiled()
-        vals = np.asarray(out[0])[:B]
-        gdocs = np.asarray(out[1])[:B]
-        counts = np.asarray(out[2])[:B]
-        h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + \
-            cbits.nbytes + 16 * B_pad + stream_b
-        d2h = vals.nbytes + gdocs.nbytes + counts.nbytes
-        _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
-        if stream_b:
-            _tm.record_tier_stream_bytes(stream_b)
-        hits = []
-        for bi in range(B):
-            row = []
-            for v, g in zip(vals[bi], gdocs[bi]):
-                if v == NEG_INF:
-                    break
-                row.append((int(g) // self.n_pad, int(g) % self.n_pad))
-            hits.append(row)
+        with _tracing.Phases() as phases:
+            phases.enter("plane[h2d]")
+            t0 = time.perf_counter()
+            B = len(bool_queries)
+            n_repl = self.mesh.shape[AXIS_REPLICA]
+            B_pad = -(-B // n_repl) * n_repl
+            bool_queries = list(bool_queries) + [
+                {"clauses": [], "msm": 0} for _ in range(B_pad - B)]
+            Q = max(self.SERVING_Q_MIN,
+                    round_up_pow2(self.bool_slot_count(bool_queries)))
+            (starts, lengths, idfw, cbits, req, neg, shd, msm, max_len,
+             any_dense) = self.bool_inputs(bool_queries, Q,
+                                           extra_docs=extra_docs,
+                                           extra_df=extra_df)
+            if any_dense:
+                raise ValueError(
+                    "bool batch touches dense-tier terms; the sparse-slice "
+                    "bool step cannot serve it (fall back)")
+            L = min(self.ladder_L(max_len), self.L_cap)
+            np.minimum(lengths, L, out=lengths)
+            step = self._get_bool_step(Q, L, k, with_count=True,
+                                       nc=MAX_BOOL_CLAUSES)
+            # warm plane: stream the sparse tables per dispatch (the bool
+            # step never reads the dense tier, so only docs/impacts ship)
+            if self.storage_tier == "hot":
+                docs_dev, impacts_dev, stream_b = \
+                    self.docs_dev, self.impacts_dev, 0
+            else:
+                _warm = self._warm_host
+                _cs = NamedSharding(self.mesh, P(AXIS_SHARD, None))
+                docs_dev = jax.device_put(_warm["docs"], _cs)
+                impacts_dev = jax.device_put(_warm["impacts"], _cs)
+                stream_b = int(_warm["docs"].nbytes) + \
+                    int(_warm["impacts"].nbytes)
+            repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
+            repl1 = NamedSharding(self.mesh, P(AXIS_REPLICA))
+            repl3 = NamedSharding(self.mesh, P(AXIS_REPLICA, AXIS_SHARD,
+                                               None))
+            phases.enter("plane[launch]")
+            t1 = time.perf_counter()
+            out = _run_step(
+                self._serial_dispatch, step, docs_dev,
+                impacts_dev,
+                jax.device_put(starts, repl3),
+                jax.device_put(lengths, repl3),
+                jax.device_put(idfw, repl), jax.device_put(cbits, repl),
+                jax.device_put(req, repl1), jax.device_put(neg, repl1),
+                jax.device_put(shd, repl1), jax.device_put(msm, repl1))
+            if stages is not None:
+                phases.enter("plane[sync]")
+                jax.block_until_ready(out)
+            phases.enter("plane[d2h]")
+            t2 = time.perf_counter()
+            self.n_dispatches += 1
+            from ..common import telemetry as _tm
+            _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
+                                     self.mesh.shape[AXIS_REPLICA])
+            compiled = _tm.last_call_compiled()
+            vals = np.asarray(out[0])[:B]
+            gdocs = np.asarray(out[1])[:B]
+            counts = np.asarray(out[2])[:B]
+            h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + \
+                cbits.nbytes + 16 * B_pad + stream_b
+            d2h = vals.nbytes + gdocs.nbytes + counts.nbytes
+            _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+            if stream_b:
+                _tm.record_tier_stream_bytes(stream_b)
+            phases.enter("plane[decode]")
+            hits = []
+            for bi in range(B):
+                row = []
+                for v, g in zip(vals[bi], gdocs[bi]):
+                    if v == NEG_INF:
+                        break
+                    row.append((int(g) // self.n_pad, int(g) % self.n_pad))
+                hits.append(row)
         if stages is not None:
             stages["prep_ms"] = (t1 - t0) * 1e3
             stages["dispatch_ms"] = (t2 - t1) * 1e3
@@ -3928,40 +3990,48 @@ class DistributedKnnPlane:
         where raw scores are the step's similarity values (cosine/dot: the
         dot product; l2_norm: ``-‖q-v‖²``) — callers apply their own
         monotone _score transform."""
-        t0 = time.perf_counter()
-        q = np.asarray(query_vectors, np.float32)
-        if q.ndim != 2 or (self.dim and q.shape[1] != self.dim):
-            raise ValueError(
-                f"query_vectors must be [B, {self.dim}], got {q.shape}")
-        B = q.shape[0]
-        n_repl = self.mesh.shape[AXIS_REPLICA]
-        B_pad = -(-B // n_repl) * n_repl
-        if B_pad != B:
-            q = np.concatenate(
-                [q, np.zeros((B_pad - B, q.shape[1]), np.float32)])
-        step = self._get_step(k)
-        vecs_dev, vnorm2_dev, exists_dev, stream_b = self._corpus_refs()
-        q_dev = jax.device_put(q, NamedSharding(self.mesh,
-                                                P(AXIS_REPLICA, None)))
-        t1 = time.perf_counter()
-        out = _run_step(self._serial_dispatch, step,
-                        vecs_dev, vnorm2_dev, exists_dev, q_dev)
-        if stages is not None:
-            jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        vals, gdocs = out
-        self.n_dispatches += 1
-        from ..common import telemetry as _tm
-        _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
-                                 self.mesh.shape[AXIS_REPLICA])
-        compiled = _tm.last_call_compiled()
-        vals = np.asarray(vals)[:B]
-        gdocs = np.asarray(gdocs)[:B]
-        _tm.record_transfer(h2d_bytes=q.nbytes + stream_b,
-                            d2h_bytes=vals.nbytes + gdocs.nbytes)
-        if stream_b:
-            _tm.record_tier_stream_bytes(stream_b)
-        hits = self._decode_hits(vals, gdocs)
+        with _tracing.Phases() as phases:
+            phases.enter("plane[h2d]")
+            t0 = time.perf_counter()
+            q = np.asarray(query_vectors, np.float32)
+            if q.ndim != 2 or (self.dim and q.shape[1] != self.dim):
+                raise ValueError(
+                    f"query_vectors must be [B, {self.dim}], got {q.shape}")
+            B = q.shape[0]
+            n_repl = self.mesh.shape[AXIS_REPLICA]
+            B_pad = -(-B // n_repl) * n_repl
+            if B_pad != B:
+                q = np.concatenate(
+                    [q, np.zeros((B_pad - B, q.shape[1]), np.float32)])
+            step = self._get_step(k)
+            vecs_dev, vnorm2_dev, exists_dev, stream_b = self._corpus_refs()
+            q_dev = jax.device_put(q, NamedSharding(self.mesh,
+                                                    P(AXIS_REPLICA, None)))
+            phases.enter("plane[launch]", bytes=q.nbytes + stream_b)
+            t1 = time.perf_counter()
+            out = _run_step(self._serial_dispatch, step,
+                            vecs_dev, vnorm2_dev, exists_dev, q_dev)
+            if stages is not None:
+                phases.enter("plane[sync]")
+                jax.block_until_ready(out)
+            d2h_span = phases.enter("plane[d2h]")
+            t2 = time.perf_counter()
+            vals, gdocs = out
+            self.n_dispatches += 1
+            from ..common import telemetry as _tm
+            _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
+                                     self.mesh.shape[AXIS_REPLICA])
+            compiled = _tm.last_call_compiled()
+            vals = np.asarray(vals)[:B]
+            gdocs = np.asarray(gdocs)[:B]
+            if d2h_span is not None:
+                d2h_span.attrs["bytes"] = vals.nbytes + gdocs.nbytes
+            phases.enter("plane[decode]")
+            _tm.record_transfer(h2d_bytes=q.nbytes + stream_b,
+                                d2h_bytes=vals.nbytes + gdocs.nbytes)
+            if stream_b:
+                _tm.record_tier_stream_bytes(stream_b)
+            hits = self._decode_hits(vals, gdocs)
         if stages is not None:
             stages["prep_ms"] = (t1 - t0) * 1e3
             stages["dispatch_ms"] = (t2 - t1) * 1e3
@@ -4143,60 +4213,66 @@ class DistributedKnnPlane:
             # warm plane: the IVF device tier was dropped on demotion —
             # serve the streamed exact scan instead (rank-safe superset)
             return self.search(query_vectors, k=k, stages=stages)
-        t0 = time.perf_counter()
-        tier = self.ivf
-        q = np.asarray(query_vectors, np.float32)
-        if q.ndim != 2 or (self.dim and q.shape[1] != self.dim):
-            raise ValueError(
-                f"query_vectors must be [B, {self.dim}], got {q.shape}")
-        B = q.shape[0]
-        n_repl = self.mesh.shape[AXIS_REPLICA]
-        B_pad = -(-B // n_repl) * n_repl
-        if B_pad != B:
-            q = np.concatenate(
-                [q, np.zeros((B_pad - B, q.shape[1]), np.float32)])
-        qq, _ = self._probe_queries(q)
-        probed = tier.probe(qq, nprobe)
-        u_blocks, Pw = tier.union_blocks(probed, self.n_shards)
-        kk = min(k, self.n_pad)
-        r_cand = max(kk, min(rerank * kk, Pw * tier.block))
-        step = self._get_ivf_step(k, nprobe, r_cand, Pw)
-        dev = tier.device_arrays(self.mesh, self.n_pad)
-        vecs_dev, vnorm2_dev, _exists_dev = self._device_arrays()
-        repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
-        shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
-        q_dev = jax.device_put(q, repl)
-        probed_dev = jax.device_put(probed, repl)
-        u_dev = jax.device_put(u_blocks, shard2)
-        t1 = time.perf_counter()
-        out = _run_step(
-            self._serial_dispatch, step,
-            dev["codes"], dev["scale"], dev["off"], dev["rowid"],
-            dev["rcl"], vecs_dev, vnorm2_dev, q_dev, probed_dev,
-            u_dev)
-        if stages is not None:
-            jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        vals, gdocs = out
-        self.n_dispatches += 1
-        from ..common import telemetry as _tm
-        _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
-                                 self.mesh.shape[AXIS_REPLICA])
-        compiled = _tm.last_call_compiled()
-        vals = np.asarray(vals)[:B]
-        gdocs = np.asarray(gdocs)[:B]
-        h2d = q.nbytes + probed.nbytes + u_blocks.nbytes
-        d2h = vals.nbytes + gdocs.nbytes
-        _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
-        # bytes the pruned scan actually reads from HBM vs the exact
-        # re-rank gather (the ROOFLINE IVF model's two terms)
-        meta_b = 12 + (4 if self.similarity == "l2_norm" else 0)
-        q_bytes = self.n_shards * Pw * tier.block * \
-            (self.dim * tier.quant_bytes_per_dim() + meta_b)
-        x_bytes = self.n_shards * B_pad * r_cand * self.dim * 4
-        self._record_ann(B, nprobe, B_pad * r_cand * self.n_shards,
-                         q_bytes, x_bytes, stages)
-        hits = self._decode_hits(vals, gdocs)
+        with _tracing.Phases() as phases:
+            phases.enter("plane[h2d]")
+            t0 = time.perf_counter()
+            tier = self.ivf
+            q = np.asarray(query_vectors, np.float32)
+            if q.ndim != 2 or (self.dim and q.shape[1] != self.dim):
+                raise ValueError(
+                    f"query_vectors must be [B, {self.dim}], got {q.shape}")
+            B = q.shape[0]
+            n_repl = self.mesh.shape[AXIS_REPLICA]
+            B_pad = -(-B // n_repl) * n_repl
+            if B_pad != B:
+                q = np.concatenate(
+                    [q, np.zeros((B_pad - B, q.shape[1]), np.float32)])
+            qq, _ = self._probe_queries(q)
+            probed = tier.probe(qq, nprobe)
+            u_blocks, Pw = tier.union_blocks(probed, self.n_shards)
+            kk = min(k, self.n_pad)
+            r_cand = max(kk, min(rerank * kk, Pw * tier.block))
+            step = self._get_ivf_step(k, nprobe, r_cand, Pw)
+            dev = tier.device_arrays(self.mesh, self.n_pad)
+            vecs_dev, vnorm2_dev, _exists_dev = self._device_arrays()
+            repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
+            shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
+            q_dev = jax.device_put(q, repl)
+            probed_dev = jax.device_put(probed, repl)
+            u_dev = jax.device_put(u_blocks, shard2)
+            phases.enter("plane[launch]")
+            t1 = time.perf_counter()
+            out = _run_step(
+                self._serial_dispatch, step,
+                dev["codes"], dev["scale"], dev["off"], dev["rowid"],
+                dev["rcl"], vecs_dev, vnorm2_dev, q_dev, probed_dev,
+                u_dev)
+            if stages is not None:
+                phases.enter("plane[sync]")
+                jax.block_until_ready(out)
+            phases.enter("plane[d2h]")
+            t2 = time.perf_counter()
+            vals, gdocs = out
+            self.n_dispatches += 1
+            from ..common import telemetry as _tm
+            _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
+                                     self.mesh.shape[AXIS_REPLICA])
+            compiled = _tm.last_call_compiled()
+            vals = np.asarray(vals)[:B]
+            gdocs = np.asarray(gdocs)[:B]
+            h2d = q.nbytes + probed.nbytes + u_blocks.nbytes
+            d2h = vals.nbytes + gdocs.nbytes
+            _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+            # bytes the pruned scan actually reads from HBM vs the exact
+            # re-rank gather (the ROOFLINE IVF model's two terms)
+            meta_b = 12 + (4 if self.similarity == "l2_norm" else 0)
+            q_bytes = self.n_shards * Pw * tier.block * \
+                (self.dim * tier.quant_bytes_per_dim() + meta_b)
+            x_bytes = self.n_shards * B_pad * r_cand * self.dim * 4
+            self._record_ann(B, nprobe, B_pad * r_cand * self.n_shards,
+                             q_bytes, x_bytes, stages)
+            phases.enter("plane[decode]")
+            hits = self._decode_hits(vals, gdocs)
         if stages is not None:
             stages["prep_ms"] = (t1 - t0) * 1e3
             stages["dispatch_ms"] = (t2 - t1) * 1e3
@@ -4364,129 +4440,136 @@ def fused_search_device(text_plane: "DistributedSearchPlane",
         raise ValueError("fused dispatch needs both planes on one mesh")
     if text_plane.n_shards != knn_plane.n_shards:
         raise ValueError("fused dispatch needs aligned shard counts")
-    t0 = time.perf_counter()
-    mesh = text_plane.mesh
-    B = len(fqs)
-    n_repl = mesh.shape[AXIS_REPLICA]
-    B_pad = -(-B // n_repl) * n_repl
-    dim = max(knn_plane.dim, 1)
-    pad_fq = {"clauses": [], "msm": 0,
-              "qv": np.zeros(dim, np.float32), "kboost": 1.0,
-              "rc": 60.0, "wt": 0, "wk": 0, "k": 0,
-              "rescore": {"terms": [], "qw": 1.0, "rw": 1.0,
-                          "window": 0} if rescore_mode else None}
-    fqs = list(fqs) + [pad_fq] * (B_pad - B)
-    bool_queries = [{"clauses": fq["clauses"], "msm": fq["msm"]}
-                    for fq in fqs]
-    Q = max(text_plane.SERVING_Q_MIN, round_up_pow2(
-        text_plane.bool_slot_count(bool_queries)))
-    (starts, lengths, idfw, cbits, req, neg, shd, msm, max_len,
-     any_dense) = text_plane.bool_inputs(bool_queries, Q,
-                                         extra_docs=extra_docs,
-                                         extra_df=extra_df)
-    if any_dense:
-        raise ValueError("fused batch touches dense-tier terms; the "
-                         "sparse-slice fused step cannot serve it")
-    L = min(text_plane.ladder_L(max_len), text_plane.L_cap)
-    np.minimum(lengths, L, out=lengths)
-    qv = np.stack([np.asarray(fq["qv"], np.float32) for fq in fqs])
-    kboost = np.asarray([fq.get("kboost", 1.0) for fq in fqs],
-                        np.float32)
-    rc = np.asarray([fq.get("rc", 60.0) for fq in fqs], np.float32)
-    wt = np.asarray([fq.get("wt", 0) for fq in fqs], np.int32)
-    wk = np.asarray([fq.get("wk", 0) for fq in fqs], np.int32)
-    W_text = round_up_pow2(max(int(wt.max()), 1))
-    W_knn = round_up_pow2(max(int(wk.max()), 1))
-    from ..ops.fused_query import MAX_BOOL_CLAUSES
-    Q2 = 0
-    rescore_args = ()
-    if rescore_mode is not None:
-        bags2 = [list(fq["rescore"]["terms"]) for fq in fqs]
-        Q2 = max(8, round_up_pow2(max(
-            max((len(set(b)) for b in bags2), default=1), 1)))
-        (st2, ln2, iw2, _dr, _dh, _ml2, dense2) = text_plane._lookup(
-            bags2, Q2, extra_docs=extra_docs, extra_df=extra_df)
-        if dense2:
-            raise ValueError("fused rescore touches dense-tier terms")
-        qw = np.asarray([fq["rescore"]["qw"] for fq in fqs], np.float32)
-        rw = np.asarray([fq["rescore"]["rw"] for fq in fqs], np.float32)
-        rwin = np.asarray([fq["rescore"]["window"] for fq in fqs],
-                          np.int32)
-    step = text_plane.cached_step(
-        ("fused", Q, L, W_text, W_knn, fusion, Q2, rescore_mode,
-         knn_plane.n_pad, dim, knn_plane.similarity),
-        lambda: build_fused_hybrid_step(
-            mesh, n_pad_t=text_plane.n_pad, Q=Q, L=L, W_text=W_text,
-            nc=MAX_BOOL_CLAUSES, n_pad_k=knn_plane.n_pad, dim=dim,
-            similarity=knn_plane.similarity, W_knn=W_knn,
-            k=W_text + W_knn, fusion=fusion,
-            n_shards=text_plane.n_shards, Q2=Q2,
-            rescore_mode=rescore_mode or "total",
-            block=knn_plane.block),
-        "fused_plane")
-    kvecs_dev, kvn_dev, kex_dev, k_stream = knn_plane._corpus_refs()
-    tdocs_dev, timpacts_dev, _tdense, t_stream = \
-        text_plane._corpus_refs()
-    stream_b = k_stream + t_stream
-    repl = NamedSharding(mesh, P(AXIS_REPLICA, None))
-    repl1 = NamedSharding(mesh, P(AXIS_REPLICA))
-    repl3 = NamedSharding(mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
-    args = [tdocs_dev, timpacts_dev,
-            kvecs_dev, kvn_dev, kex_dev,
-            jax.device_put(starts, repl3), jax.device_put(lengths, repl3),
-            jax.device_put(idfw, repl), jax.device_put(cbits, repl),
-            jax.device_put(req, repl1), jax.device_put(neg, repl1),
-            jax.device_put(shd, repl1), jax.device_put(msm, repl1),
-            jax.device_put(qv, repl), jax.device_put(kboost, repl1),
-            jax.device_put(rc, repl1), jax.device_put(wt, repl1),
-            jax.device_put(wk, repl1)]
-    h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + cbits.nbytes \
-        + qv.nbytes + 24 * B_pad + stream_b
-    if Q2:
-        args += [jax.device_put(st2, repl3), jax.device_put(ln2, repl3),
-                 jax.device_put(iw2, repl), jax.device_put(qw, repl1),
-                 jax.device_put(rw, repl1), jax.device_put(rwin, repl1)]
-        h2d += st2.nbytes + ln2.nbytes + iw2.nbytes + 12 * B_pad
-    t1 = time.perf_counter()
-    out = _run_step(text_plane._serial_dispatch, step, *args)
-    if stages is not None:
-        jax.block_until_ready(out)
-    t2 = time.perf_counter()
-    text_plane.n_dispatches += 1
-    knn_plane.n_dispatches += 1
-    from ..common import telemetry as _tm
-    _tm.record_mesh_dispatch(mesh.shape[AXIS_SHARD],
-                             mesh.shape[AXIS_REPLICA])
-    compiled = _tm.last_call_compiled()
-    fvals = np.asarray(out[0])[:B]
-    fids = np.asarray(out[1])[:B]
-    counts = np.asarray(out[2])[:B]
-    tvals = np.asarray(out[3])[:B]
-    tids = np.asarray(out[4])[:B]
-    kvals = np.asarray(out[5])[:B]
-    kids = np.asarray(out[6])[:B]
-    d2h = fvals.nbytes + fids.nbytes + counts.nbytes + tvals.nbytes \
-        + tids.nbytes + kvals.nbytes + kids.nbytes
-    _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
-    if stream_b:
-        _tm.record_tier_stream_bytes(stream_b)
-    UP = max(text_plane.n_pad, knn_plane.n_pad)
+    with _tracing.Phases() as phases:
+        phases.enter("plane[h2d]")
+        t0 = time.perf_counter()
+        mesh = text_plane.mesh
+        B = len(fqs)
+        n_repl = mesh.shape[AXIS_REPLICA]
+        B_pad = -(-B // n_repl) * n_repl
+        dim = max(knn_plane.dim, 1)
+        pad_fq = {"clauses": [], "msm": 0,
+                  "qv": np.zeros(dim, np.float32), "kboost": 1.0,
+                  "rc": 60.0, "wt": 0, "wk": 0, "k": 0,
+                  "rescore": {"terms": [], "qw": 1.0, "rw": 1.0,
+                              "window": 0} if rescore_mode else None}
+        fqs = list(fqs) + [pad_fq] * (B_pad - B)
+        bool_queries = [{"clauses": fq["clauses"], "msm": fq["msm"]}
+                        for fq in fqs]
+        Q = max(text_plane.SERVING_Q_MIN, round_up_pow2(
+            text_plane.bool_slot_count(bool_queries)))
+        (starts, lengths, idfw, cbits, req, neg, shd, msm, max_len,
+         any_dense) = text_plane.bool_inputs(bool_queries, Q,
+                                             extra_docs=extra_docs,
+                                             extra_df=extra_df)
+        if any_dense:
+            raise ValueError("fused batch touches dense-tier terms; the "
+                             "sparse-slice fused step cannot serve it")
+        L = min(text_plane.ladder_L(max_len), text_plane.L_cap)
+        np.minimum(lengths, L, out=lengths)
+        qv = np.stack([np.asarray(fq["qv"], np.float32) for fq in fqs])
+        kboost = np.asarray([fq.get("kboost", 1.0) for fq in fqs],
+                            np.float32)
+        rc = np.asarray([fq.get("rc", 60.0) for fq in fqs], np.float32)
+        wt = np.asarray([fq.get("wt", 0) for fq in fqs], np.int32)
+        wk = np.asarray([fq.get("wk", 0) for fq in fqs], np.int32)
+        W_text = round_up_pow2(max(int(wt.max()), 1))
+        W_knn = round_up_pow2(max(int(wk.max()), 1))
+        from ..ops.fused_query import MAX_BOOL_CLAUSES
+        Q2 = 0
+        rescore_args = ()
+        if rescore_mode is not None:
+            bags2 = [list(fq["rescore"]["terms"]) for fq in fqs]
+            Q2 = max(8, round_up_pow2(max(
+                max((len(set(b)) for b in bags2), default=1), 1)))
+            (st2, ln2, iw2, _dr, _dh, _ml2, dense2) = text_plane._lookup(
+                bags2, Q2, extra_docs=extra_docs, extra_df=extra_df)
+            if dense2:
+                raise ValueError("fused rescore touches dense-tier terms")
+            qw = np.asarray([fq["rescore"]["qw"] for fq in fqs], np.float32)
+            rw = np.asarray([fq["rescore"]["rw"] for fq in fqs], np.float32)
+            rwin = np.asarray([fq["rescore"]["window"] for fq in fqs],
+                              np.int32)
+        step = text_plane.cached_step(
+            ("fused", Q, L, W_text, W_knn, fusion, Q2, rescore_mode,
+             knn_plane.n_pad, dim, knn_plane.similarity),
+            lambda: build_fused_hybrid_step(
+                mesh, n_pad_t=text_plane.n_pad, Q=Q, L=L, W_text=W_text,
+                nc=MAX_BOOL_CLAUSES, n_pad_k=knn_plane.n_pad, dim=dim,
+                similarity=knn_plane.similarity, W_knn=W_knn,
+                k=W_text + W_knn, fusion=fusion,
+                n_shards=text_plane.n_shards, Q2=Q2,
+                rescore_mode=rescore_mode or "total",
+                block=knn_plane.block),
+            "fused_plane")
+        kvecs_dev, kvn_dev, kex_dev, k_stream = knn_plane._corpus_refs()
+        tdocs_dev, timpacts_dev, _tdense, t_stream = \
+            text_plane._corpus_refs()
+        stream_b = k_stream + t_stream
+        repl = NamedSharding(mesh, P(AXIS_REPLICA, None))
+        repl1 = NamedSharding(mesh, P(AXIS_REPLICA))
+        repl3 = NamedSharding(mesh, P(AXIS_REPLICA, AXIS_SHARD, None))
+        args = [tdocs_dev, timpacts_dev,
+                kvecs_dev, kvn_dev, kex_dev,
+                jax.device_put(starts, repl3),
+                jax.device_put(lengths, repl3),
+                jax.device_put(idfw, repl), jax.device_put(cbits, repl),
+                jax.device_put(req, repl1), jax.device_put(neg, repl1),
+                jax.device_put(shd, repl1), jax.device_put(msm, repl1),
+                jax.device_put(qv, repl), jax.device_put(kboost, repl1),
+                jax.device_put(rc, repl1), jax.device_put(wt, repl1),
+                jax.device_put(wk, repl1)]
+        h2d = starts.nbytes + lengths.nbytes + idfw.nbytes + cbits.nbytes \
+            + qv.nbytes + 24 * B_pad + stream_b
+        if Q2:
+            args += [jax.device_put(st2, repl3), jax.device_put(ln2, repl3),
+                     jax.device_put(iw2, repl), jax.device_put(qw, repl1),
+                     jax.device_put(rw, repl1), jax.device_put(rwin, repl1)]
+            h2d += st2.nbytes + ln2.nbytes + iw2.nbytes + 12 * B_pad
+        phases.enter("plane[launch]")
+        t1 = time.perf_counter()
+        out = _run_step(text_plane._serial_dispatch, step, *args)
+        if stages is not None:
+            phases.enter("plane[sync]")
+            jax.block_until_ready(out)
+        phases.enter("plane[d2h]")
+        t2 = time.perf_counter()
+        text_plane.n_dispatches += 1
+        knn_plane.n_dispatches += 1
+        from ..common import telemetry as _tm
+        _tm.record_mesh_dispatch(mesh.shape[AXIS_SHARD],
+                                 mesh.shape[AXIS_REPLICA])
+        compiled = _tm.last_call_compiled()
+        fvals = np.asarray(out[0])[:B]
+        fids = np.asarray(out[1])[:B]
+        counts = np.asarray(out[2])[:B]
+        tvals = np.asarray(out[3])[:B]
+        tids = np.asarray(out[4])[:B]
+        kvals = np.asarray(out[5])[:B]
+        kids = np.asarray(out[6])[:B]
+        d2h = fvals.nbytes + fids.nbytes + counts.nbytes + tvals.nbytes \
+            + tids.nbytes + kvals.nbytes + kids.nbytes
+        _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+        if stream_b:
+            _tm.record_tier_stream_bytes(stream_b)
+        phases.enter("plane[decode]")
+        UP = max(text_plane.n_pad, knn_plane.n_pad)
 
-    def decode(vrow, grow, npad, kq):
-        rows = []
-        for v, g in zip(vrow, grow):
-            if v == NEG_INF or len(rows) >= kq:
-                break
-            rows.append((float(v), int(g) // npad, int(g) % npad))
-        return rows
+        def decode(vrow, grow, npad, kq):
+            rows = []
+            for v, g in zip(vrow, grow):
+                if v == NEG_INF or len(rows) >= kq:
+                    break
+                rows.append((float(v), int(g) // npad, int(g) % npad))
+            return rows
 
-    rows = [decode(fvals[bi], fids[bi], UP, fqs[bi].get("k") or
-                   (W_text + W_knn)) for bi in range(B)]
-    text_rows = [decode(tvals[bi], tids[bi], text_plane.n_pad,
-                        int(wt[bi])) for bi in range(B)]
-    knn_rows = [decode(kvals[bi], kids[bi], knn_plane.n_pad,
-                       int(wk[bi])) for bi in range(B)]
-    totals = [int(c) for c in counts]
+        rows = [decode(fvals[bi], fids[bi], UP, fqs[bi].get("k") or
+                       (W_text + W_knn)) for bi in range(B)]
+        text_rows = [decode(tvals[bi], tids[bi], text_plane.n_pad,
+                            int(wt[bi])) for bi in range(B)]
+        knn_rows = [decode(kvals[bi], kids[bi], knn_plane.n_pad,
+                           int(wk[bi])) for bi in range(B)]
+        totals = [int(c) for c in counts]
     if stages is not None:
         stages["prep_ms"] = (t1 - t0) * 1e3
         stages["dispatch_ms"] = (t2 - t1) * 1e3

@@ -952,7 +952,9 @@ class RestAPI:
         info = getattr(self._trace_tls, "value", None)
         if not info or not info[0]:
             from ..common import tracing as _tracing
-            tid, _parent = _tracing.parse_incoming(headers)
+            # the HTTP edge's trace (http[in]) when there is one
+            tid = _tracing.current_trace_id() or \
+                _tracing.parse_incoming(headers)[0]
             hmap = {str(k).lower(): v for k, v in (headers or {}).items()}
             info = (tid or _tracing.new_trace_id(),
                     (info[1] if info else None) or hmap.get("x-opaque-id"))
@@ -1008,165 +1010,183 @@ class RestAPI:
                      body: bytes,
                      headers: Optional[dict] = None) \
             -> Tuple[int, str, bytes]:
-        if self.security.enabled and self.enforce_security and \
-                not getattr(self._internal_tls, "active", False):
-            # every route requires credentials when security is on
-            # (reference: SecurityRestFilter wraps the whole dispatcher);
-            # the cluster front enforces at ITS door and disables this
-            # inner check for trusted internal dispatches
-            try:
-                self._principal_tls.value = \
-                    self.security.authenticate(headers)
-                # role-based authorization on every route except the
-                # self-service endpoints any authenticated user may
-                # call (AuthorizationService.authorize +
-                # RestAuthenticateAction / HasPrivileges)
-                if path.rstrip("/") not in (
-                        "/_security/_authenticate",
-                        "/_security/user/_has_privileges"):
-                    self.security.rbac.authorize(
-                        self._principal_tls.value, method, path)
-            except Exception as e:   # noqa: BLE001 — 401/403 ES body
-                return self._error_response(e)
-        if not getattr(self._internal_tls, "active", False):
-            # fresh warning scope per EXTERNAL request only — internal
-            # re-dispatches (SQL/transform/ML seams) keep accumulating
-            # into the outer request's scope
-            from ..xpack.deprecation import begin_request
-            begin_request()
-        params = {k: v[-1] for k, v in
-                  parse_qs(query, keep_blank_values=True).items()}
-        if query:
-            # bare flags like ?v
-            for part in query.split("&"):
-                if part and "=" not in part:
-                    params[part] = "true"
-        # match routes on the ENCODED path, decode per captured segment
-        # (RestUtils.decodeComponent: %2F inside one segment — date-math
-        # index names, slashed ids — must not split routing)
-        path = path.rstrip("/") or "/"
-        while "//" in path:
-            # an empty path segment (index: [] in specs) collapses away
-            path = path.replace("//", "/")
-        matched_path = False
-        for m, rx, names, fn in self._routes:
-            match = rx.match(path)
-            if match is None:
-                continue
-            matched_path = True
-            if m != method and not (method == "HEAD" and m == "GET"):
-                continue
-            kwargs = {k: (unquote(v) if v is not None else v)
-                      for k, v in zip(names, match.groups())}
-            # every request runs as a registered task for its lifetime
-            # (reference: TaskManager.java:76 registers every action) and
-            # inside a traced root span: the trace id is minted here — or
-            # adopted from an incoming traceparent/trace.id header — and
-            # follows the request through coordinator → shard fan-out →
-            # microbatch dispatch (common/tracing.py)
-            from ..common import tracing as _tracing
-            hmap2 = {str(k).lower(): v for k, v in (headers or {}).items()}
-            opaque = params.get("__x_opaque_id") or \
-                hmap2.get("x-opaque-id")
-            action = _action_name(method, path)
-            desc = f"{method} {path}"
-            if opaque:
-                desc += f" [x-opaque-id={opaque}]"
-            _op_token = _tracing.set_opaque_id(opaque)
-            # the root span carries the tenant (X-Opaque-Id) so the
-            # GET /_trace listing's ?tenant= filter works off the store
-            root_attrs = {"action": action}
-            if opaque:
-                root_attrs["tenant"] = opaque
-            try:
-                with _tracing.span(f"rest[{action}]", node=self.node_id,
-                                   headers=headers, root=True,
-                                   attrs=root_attrs) as sp:
-                    task_headers = {"trace.id": sp.trace_id}
-                    if opaque:
-                        task_headers["X-Opaque-Id"] = opaque
-                    self._trace_tls.value = (sp.trace_id, opaque)
-                    # QoS edge: classify + admission-check data-path
-                    # actions INSIDE the span (the 429 carries the
-                    # trace id; the journal event inherits the ambient
-                    # trace) but BEFORE task registration — a shed
-                    # request must cost O(1)
-                    _pri_token = None
-                    if action.startswith("indices:data/"):
+        from ..common import tracing as _tracing
+        # rest[parse]: what a request pays before its action's handler
+        # (security, query string, the scan of the route table)
+        with _tracing.span("rest[parse]"):
+            if self.security.enabled and self.enforce_security and \
+                    not getattr(self._internal_tls, "active", False):
+                # every route requires credentials when security is on
+                # (reference: SecurityRestFilter wraps the whole dispatcher);
+                # the cluster front enforces at ITS door and disables this
+                # inner check for trusted internal dispatches
+                try:
+                    self._principal_tls.value = \
+                        self.security.authenticate(headers)
+                    # role-based authorization on every route except the
+                    # self-service endpoints any authenticated user may
+                    # call (AuthorizationService.authorize +
+                    # RestAuthenticateAction / HasPrivileges)
+                    if path.rstrip("/") not in (
+                            "/_security/_authenticate",
+                            "/_security/user/_has_privileges"):
+                        self.security.rbac.authorize(
+                            self._principal_tls.value, method, path)
+                except Exception as e:   # noqa: BLE001 — 401/403 ES body
+                    return self._error_response(e)
+            if not getattr(self._internal_tls, "active", False):
+                # fresh warning scope per EXTERNAL request only — internal
+                # re-dispatches (SQL/transform/ML seams) keep accumulating
+                # into the outer request's scope
+                from ..xpack.deprecation import begin_request
+                begin_request()
+            params = {k: v[-1] for k, v in
+                      parse_qs(query, keep_blank_values=True).items()}
+            if query:
+                # bare flags like ?v
+                for part in query.split("&"):
+                    if part and "=" not in part:
+                        params[part] = "true"
+            # match routes on the ENCODED path, decode per captured segment
+            # (RestUtils.decodeComponent: %2F inside one segment — date-math
+            # index names, slashed ids — must not split routing)
+            path = path.rstrip("/") or "/"
+            while "//" in path:
+                # an empty path segment (index: [] in specs) collapses away
+                path = path.replace("//", "/")
+            fn = kwargs = None
+            matched_path = False
+            for m, rx, names, route_fn in self._routes:
+                match = rx.match(path)
+                if match is None:
+                    continue
+                matched_path = True
+                if m != method and not (method == "HEAD" and m == "GET"):
+                    continue
+                fn = route_fn
+                kwargs = {k: (unquote(v) if v is not None else v)
+                          for k, v in zip(names, match.groups())}
+                break
+        if fn is None:
+            if matched_path:
+                status, payload = 405, {
+                    "error": f"Incorrect HTTP method for uri [{path}] and "
+                             f"method [{method}]", "status": 405}
+            else:
+                status, payload = 400, {
+                    "error": f"no handler found for uri [{path}] and method "
+                             f"[{method}]", "status": 400}
+            return status, JSON_CT, json.dumps(payload).encode()
+        # every request runs as a registered task for its lifetime
+        # (reference: TaskManager.java:76 registers every action) and
+        # inside a traced root span: the trace id is minted here — or
+        # adopted from an incoming traceparent/trace.id header — and
+        # follows the request through coordinator → shard fan-out →
+        # microbatch dispatch (common/tracing.py)
+        hmap2 = {str(k).lower(): v for k, v in (headers or {}).items()}
+        opaque = params.get("__x_opaque_id") or \
+            hmap2.get("x-opaque-id")
+        action = _action_name(method, path)
+        desc = f"{method} {path}"
+        if opaque:
+            desc += f" [x-opaque-id={opaque}]"
+        _op_token = _tracing.set_opaque_id(opaque)
+        # the root span carries the tenant (X-Opaque-Id) so the
+        # GET /_trace listing's ?tenant= filter works off the store
+        root_attrs = {"action": action}
+        if opaque:
+            root_attrs["tenant"] = opaque
+        try:
+            with _tracing.span(f"rest[{action}]", node=self.node_id,
+                               headers=headers, root=True,
+                               attrs=root_attrs) as sp:
+                task_headers = {"trace.id": sp.trace_id}
+                if opaque:
+                    task_headers["X-Opaque-Id"] = opaque
+                self._trace_tls.value = (sp.trace_id, opaque)
+                # QoS edge: classify + admission-check data-path
+                # actions INSIDE the span (the 429 carries the
+                # trace id; the journal event inherits the ambient
+                # trace) but BEFORE task registration — a shed
+                # request must cost O(1)
+                _pri_token = None
+                if action.startswith("indices:data/"):
+                    from ..common import qos as _qos
+                    if _qos.qos_enabled():
+                        override = hmap2.get("x-es-priority")
+                        qbody = None
+                        if not override and \
+                                action.startswith("indices:data/read"):
+                            qbody = self._qos_body(body)
+                        pri = _qos.classify(action=action,
+                                            body=qbody,
+                                            override=override)
+                        decision = _qos.controller().admit(
+                            tenant=opaque, priority=pri,
+                            action=action)
+                        if not decision.allowed:
+                            sp.attrs["error"] = "QosRejectedError"
+                            self._note_shed(qbody, opaque,
+                                            sp.trace_id)
+                            what = ("request throttled: tenant "
+                                    "token bucket in debt"
+                                    if decision.kind == "throttle"
+                                    else "request shed: cluster "
+                                    "overloaded")
+                            return self._error_response(
+                                _qos.QosRejectedError(
+                                    what, decision, tenant=opaque))
+                        _pri_token = _qos.bind_priority(pri)
+                task = self.task_manager.register(
+                    action,
+                    description=desc + f" [trace.id={sp.trace_id}]",
+                    headers=task_headers)
+                self._req_task.task = task
+                # resource attribution: the task's ledger rides the
+                # request context (shard search / plane dispatch
+                # charge it at stage boundaries), and the request
+                # thread's CPU window opens here
+                from ..node.task_manager import (bind_resources,
+                                                 unbind_resources)
+                _res_token = bind_resources(task.resources)
+                # flight-recorder ambient context: journal events on
+                # this request's path stamp node + task id
+                from ..common import flightrec as _flightrec
+                _fr_token = _flightrec.bind_ambient(
+                    node=self.node_id, task=f"{task.node}:{task.id}")
+                # continuous-profiler attribution: this thread
+                # samples into the "rest" pool under this tenant
+                # for the request's lifetime (the shape holder is
+                # published by flightrec.bind_shape on the search
+                # path) — nest-safe for internal re-dispatches
+                from ..common import contprof as _contprof
+                _cp_token = _contprof.bind_request_thread(opaque)
+                task.resources.cpu_mark()
+                try:
+                    result = fn(params, body, **kwargs)
+                except Exception as e:  # noqa: BLE001 — ES-shaped
+                    sp.attrs["error"] = type(e).__name__
+                    return self._error_response(e)
+                finally:
+                    if _pri_token is not None:
                         from ..common import qos as _qos
-                        if _qos.qos_enabled():
-                            override = hmap2.get("x-es-priority")
-                            qbody = None
-                            if not override and \
-                                    action.startswith("indices:data/read"):
-                                qbody = self._qos_body(body)
-                            pri = _qos.classify(action=action,
-                                                body=qbody,
-                                                override=override)
-                            decision = _qos.controller().admit(
-                                tenant=opaque, priority=pri,
-                                action=action)
-                            if not decision.allowed:
-                                sp.attrs["error"] = "QosRejectedError"
-                                self._note_shed(qbody, opaque,
-                                                sp.trace_id)
-                                what = ("request throttled: tenant "
-                                        "token bucket in debt"
-                                        if decision.kind == "throttle"
-                                        else "request shed: cluster "
-                                        "overloaded")
-                                return self._error_response(
-                                    _qos.QosRejectedError(
-                                        what, decision, tenant=opaque))
-                            _pri_token = _qos.bind_priority(pri)
-                    task = self.task_manager.register(
-                        action,
-                        description=desc + f" [trace.id={sp.trace_id}]",
-                        headers=task_headers)
-                    self._req_task.task = task
-                    # resource attribution: the task's ledger rides the
-                    # request context (shard search / plane dispatch
-                    # charge it at stage boundaries), and the request
-                    # thread's CPU window opens here
-                    from ..node.task_manager import (bind_resources,
-                                                     unbind_resources)
-                    _res_token = bind_resources(task.resources)
-                    # flight-recorder ambient context: journal events on
-                    # this request's path stamp node + task id
-                    from ..common import flightrec as _flightrec
-                    _fr_token = _flightrec.bind_ambient(
-                        node=self.node_id, task=f"{task.node}:{task.id}")
-                    # continuous-profiler attribution: this thread
-                    # samples into the "rest" pool under this tenant
-                    # for the request's lifetime (the shape holder is
-                    # published by flightrec.bind_shape on the search
-                    # path) — nest-safe for internal re-dispatches
-                    from ..common import contprof as _contprof
-                    _cp_token = _contprof.bind_request_thread(opaque)
-                    task.resources.cpu_mark()
-                    try:
-                        result = fn(params, body, **kwargs)
-                    except Exception as e:  # noqa: BLE001 — ES-shaped
-                        sp.attrs["error"] = type(e).__name__
-                        return self._error_response(e)
-                    finally:
-                        if _pri_token is not None:
-                            from ..common import qos as _qos
-                            _qos.unbind_priority(_pri_token)
-                        task.resources.cpu_release()
-                        _contprof.unbind_request_thread(_cp_token)
-                        _flightrec.reset_ambient(_fr_token)
-                        unbind_resources(_res_token)
-                        self._req_task.task = None
-                        if task.running and \
-                                not getattr(task, "async_detached", False):
-                            self.task_manager.unregister(task)
-                        # internal re-dispatches (monitoring fetch, SQL
-                        # seams) overwrite the echo stash — the OUTER
-                        # request's pair must win
-                        self._trace_tls.value = (sp.trace_id, opaque)
-            finally:
-                _tracing._OPAQUE.reset(_op_token)
+                        _qos.unbind_priority(_pri_token)
+                    task.resources.cpu_release()
+                    _contprof.unbind_request_thread(_cp_token)
+                    _flightrec.reset_ambient(_fr_token)
+                    unbind_resources(_res_token)
+                    self._req_task.task = None
+                    if task.running and \
+                            not getattr(task, "async_detached", False):
+                        self.task_manager.unregister(task)
+                    # internal re-dispatches (monitoring fetch, SQL
+                    # seams) overwrite the echo stash — the OUTER
+                    # request's pair must win
+                    self._trace_tls.value = (sp.trace_id, opaque)
+        finally:
+            _tracing._OPAQUE.reset(_op_token)
+        # rest[render]: the handler's result to the bytes of the body
+        with _tracing.span("rest[render]"):
             if isinstance(result, tuple) and len(result) == 3:
                 # (status, content_type, str|bytes) — non-JSON bodies
                 # (SQL txt/csv/tsv, hot_threads text) pick their own type
@@ -1192,15 +1212,6 @@ class RestAPI:
             if payload is None:
                 return status, JSON_CT, b"null"
             return status, JSON_CT, payload
-        if matched_path:
-            status, payload = 405, {"error": f"Incorrect HTTP method for uri "
-                                             f"[{path}] and method [{method}]",
-                                    "status": 405}
-        else:
-            status, payload = 400, {
-                "error": f"no handler found for uri [{path}] and method "
-                         f"[{method}]", "status": 400}
-        return status, JSON_CT, json.dumps(payload).encode()
 
     # ------------------------------------------------------------------
     # root / cluster

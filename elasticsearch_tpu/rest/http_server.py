@@ -14,6 +14,8 @@ import asyncio
 import json
 from typing import Callable, Optional, Tuple
 
+from ..common import tracing as _tracing
+
 MAX_BODY = 100 * 1024 * 1024  # reference default http.max_content_length
 
 
@@ -75,6 +77,13 @@ class HttpServer:
                 if request is None:
                     break
                 method, target, headers, body = request
+                # the request's trace starts here: http[in] runs from
+                # the last byte read to the hand-off to the handler's
+                # thread (tracing.handoff in the handler), or to the
+                # handler's return where it runs inline
+                edge = _tracing.open_span(
+                    "http[in]", headers=headers, root=True,
+                    attrs={"bytes_in": len(body)})
                 path, _, query = target.partition("?")
                 # bind the deprecation-warning container in THIS task's
                 # context before dispatch so a handler running on an
@@ -99,6 +108,12 @@ class HttpServer:
                         json.dumps({"error": {
                             "type": "exception",
                             "reason": str(e)}, "status": 500}).encode()
+                finally:
+                    edge.close()
+                out_span = _tracing.open_span(
+                    "http[out]", trace_id=edge.trace_id,
+                    parent_span_id=edge.span_id,
+                    attrs={"status": status, "bytes_out": len(payload)})
                 keep_alive = headers.get("connection", "").lower() != "close"
                 # RFC-7234 299 deprecation warnings accumulated by the
                 # handler (HeaderWarning analog — xpack/deprecation.py)
@@ -124,6 +139,7 @@ class HttpServer:
                         f"{'keep-alive' if keep_alive else 'close'}\r\n\r\n")
                 writer.write(head.encode() + (b"" if method == "HEAD"
                                               else payload))
+                out_span.close()
                 await writer.drain()
                 if not keep_alive:
                     break

@@ -16,8 +16,10 @@ in time. This module is that surface:
   dispatcher thread id, bucket key/params, batch composition (request
   count, dedup lane count, k bucket, view size, mesh axes), h2d/d2h
   bytes, compile-cache verdict, kernel family, and the roofline audit
-  (``common/roofline.py``). Flightrec ``slow_dispatch`` events carry
-  the record's ``seq`` so the two journals cross-link.
+  (``common/roofline.py``). The record's ``seq`` is the dispatch's
+  number everywhere: flightrec ``slow_dispatch`` events, the
+  dispatcher's ``batch[...]`` spans and the ``plane_dispatch`` span of
+  every request it carried bear it (``common/tracing.py``).
 
 - :func:`chrome_trace` — renders records as Chrome trace-event JSON
   (the ``{"traceEvents": [...]}`` format perfetto/chrome://tracing
@@ -47,7 +49,8 @@ from typing import Dict, List, Optional
 
 from ..common.settings import CLUSTER_SETTINGS, Setting
 
-__all__ = ["DispatchProfileRing", "RING", "record", "chrome_trace"]
+__all__ = ["DispatchProfileRing", "RING", "next_seq", "record",
+           "chrome_trace"]
 
 SETTING_RING_SIZE = CLUSTER_SETTINGS.register(
     Setting.int_setting("dispatch_profile.ring.size", 2048,
@@ -77,9 +80,12 @@ class DispatchProfileRing:
     def record(self, **fields) -> dict:
         """Append one dispatch record. O(1); never raises (profiling
         must not fail the dispatch it profiles). Returns the record
-        (empty dict on failure)."""
+        (empty dict on failure). ``seq`` is the dispatch's number from
+        :func:`next_seq`, taken when the dispatch started so that its
+        ``batch[...]`` spans and its requests' ``plane_dispatch`` spans
+        carry it too; drawn here when the caller has none."""
         try:
-            rec = {"seq": next(_SEQ)}
+            rec = {"seq": fields.pop("seq", None) or next(_SEQ)}
             rec.update(fields)
             with self._lock:
                 if len(self._ring) >= self.cap:
@@ -112,6 +118,13 @@ class DispatchProfileRing:
 #: PROCESS-scoped ring (the flightrec.DEFAULT singleton pattern —
 #: in-process multi-node clusters share it; the cluster fan-in dedupes)
 RING = DispatchProfileRing()
+
+
+def next_seq() -> int:
+    """The number of a dispatch that is about to start: its timeline
+    record, its ``batch[...]`` spans and the ``plane_dispatch`` spans of
+    the requests it carries all bear it."""
+    return next(_SEQ)
 
 
 def record(**fields) -> dict:

@@ -64,6 +64,8 @@ import numpy as np
 
 from ..common import qos as _qos
 from ..common import racedep
+from ..common import tracing as _tracing
+from . import dispatch_profile as _dp
 
 #: upper bound on queries per dispatch — past this the dispatch itself is
 #: long enough that splitting reduces tail latency
@@ -110,7 +112,6 @@ class _Slot:
         #: and the node stamp keeps the cluster fan-in's per-node
         #: dedup exact (in-process nodes share the ring)
         from ..common import flightrec as _fr
-        from ..common import tracing as _tracing
         self.trace_id = _tracing.current_trace_id()
         self.node = _fr.ambient_node()
         #: the request's query shape id (dispatch-profile records join
@@ -401,151 +402,166 @@ class PlaneMicroBatcher:
         return batch
 
     def _run_batch(self, batch: List[_Slot]) -> None:
-        t_pick = time.perf_counter()
-        # dispatch at the bucket's rounded-up k so the compile shape is
-        # stable within a bucket (slots trim to their own k on fan-out);
-        # a coalesced cross-bucket batch runs at the max-k shape
-        k = self._k_bucket(max(s.k for s in batch))
-        # in-flight dedup: identical queries that queued concurrently
-        # (the same hot body from many clients) share ONE dispatch slot —
-        # each client still gets its own result copy on fan-out
-        slot_of: Dict = {}
-        lane: List[int] = []
-        for s in batch:
-            qk = self._query_key(s.terms)
-            idx = slot_of.setdefault(qk, len(slot_of))
-            lane.append(idx)
-        n_deduped = len(batch) - len(slot_of)
-        uniq: List = [None] * len(slot_of)
-        for s, idx in zip(batch, lane):
-            if uniq[idx] is None:
-                uniq[idx] = s.terms
-        # pad the batch to a power of two: every distinct traced B shape is
-        # a fresh XLA compile — ragged arrival sizes would otherwise
-        # compile dozens of programs (padding slots score as no-op
-        # queries). Then pad on to a REPLICA-axis multiple: the mesh
-        # partitions the batch dim over replica groups (the pad at
-        # dist_search.search would add it anyway), and filling the
-        # per-replica sub-batches here keeps the batcher's co-batched
-        # block equal to the traced block — warm-lattice shapes ARE the
-        # serving shapes at every mesh.
-        b_pad = 1 << max(0, (len(uniq) - 1).bit_length())
-        rm = self.mesh_replica_devices
-        if rm > 1:
-            b_pad = -(-b_pad // rm) * rm
-        queries = uniq + [self._pad_slot()
-                          for _ in range(b_pad - len(uniq))]
-        plane_stages: Dict[str, float] = {}
-        t_call = time.perf_counter()
-        err: Optional[BaseException] = None
-        try:
-            out = self._dispatch(
-                queries, k, plane_stages,
-                view=batch[0].view_segments, params=batch[0].params)
-            vals, hits, totals = out[:3]
-            # fused agg stages: a plane that served analytics stages
-            # returns a 4th per-slot list of aggregations dicts
-            aggs_list = out[3] if len(out) > 3 else None
-        except BaseException as e:          # noqa: BLE001 — fan the error
-            err = e                         # out to every query in the batch
-        t_done = time.perf_counter()
-        if err is not None:
+        # the dispatch's number: its timeline record, the three
+        # batch[...] spans below (the stage boundaries t_pick / t_call /
+        # t_done / t_end, on the profiler's clock while a session is
+        # active) and the requests' plane_dispatch spans all carry it
+        seq = _dp.next_seq()
+        with _tracing.Phases() as phases:
+            phases.enter("batch[prep]", seq=seq, requests=len(batch))
+            t_pick = time.perf_counter()
+            # dispatch at the bucket's rounded-up k so the compile shape is
+            # stable within a bucket (slots trim to their own k on fan-out);
+            # a coalesced cross-bucket batch runs at the max-k shape
+            k = self._k_bucket(max(s.k for s in batch))
+            # in-flight dedup: identical queries that queued concurrently
+            # (the same hot body from many clients) share ONE dispatch slot —
+            # each client still gets its own result copy on fan-out
+            slot_of: Dict = {}
+            lane: List[int] = []
             for s in batch:
-                s.error = err
-        else:
+                qk = self._query_key(s.terms)
+                idx = slot_of.setdefault(qk, len(slot_of))
+                lane.append(idx)
+            n_deduped = len(batch) - len(slot_of)
+            uniq: List = [None] * len(slot_of)
             for s, idx in zip(batch, lane):
-                s.vals = vals[idx][:s.k]
-                s.hits = hits[idx][:s.k]
-                s.total = totals[idx]
-                if aggs_list is not None:
-                    s.aggs = aggs_list[idx]
-        # stage attribution: queue wait is per-slot; prep / dispatch /
-        # fetch are shared by the whole batch (one dispatch). The plane
-        # refines its own call into prep/dispatch/fetch when it can;
-        # otherwise the whole call counts as dispatch.
-        prep_ms = (t_call - t_pick) * 1e3 + plane_stages.get("prep_ms", 0.0)
-        dispatch_ms = plane_stages.get(
-            "dispatch_ms", (t_done - t_call) * 1e3)
-        fetch_base_ms = plane_stages.get("fetch_ms", 0.0)
-        batch_info = {"batch_size": len(batch), "k_bucket": k,
-                      "compile_cache": plane_stages.get("compile_cache",
-                                                        "hit"),
-                      # the dispatch's mesh topology, so profile:true
-                      # responses name the device fan-out next to the
-                      # per-device docs share below
-                      "mesh": {"shard_devices": self.mesh_shard_devices,
-                               "replica_devices":
-                                   self.mesh_replica_devices}}
-        # task resource attribution (node/task_manager.TaskResources):
-        # the dispatch's transfer bytes split across the batch's slots
-        # (so per-task sums reconcile with es_device_transfer_bytes_total)
-        # while docs scanned is per QUERY — every query's score covers
-        # the full base corpus plus the delta tier
-        share = 1.0 / max(len(batch), 1)
-        h2d = plane_stages.get("h2d_bytes")
-        d2h = plane_stages.get("d2h_bytes")
-        if h2d or d2h:
-            batch_info["h2d_bytes"] = int((h2d or 0) * share)
-            batch_info["d2h_bytes"] = int((d2h or 0) * share)
-        base_docs = getattr(self.plane, "base_docs", None)
-        if base_docs is None:
-            base_docs = getattr(self.plane, "n_docs_total", 0)
-        # a cluster-pruned (IVF) dispatch scans only the probed rows —
-        # the plane reports them; full scans cover the whole base corpus
-        scanned = plane_stages.get("docs_scanned")
-        batch_info["docs_scanned"] = int(
-            (base_docs if scanned is None else scanned)
-            + plane_stages.get("delta_docs", 0))
-        # per-DEVICE share of the scan: the shard axis partitions the
-        # corpus, so each chip streams ~1/s_dev of the scanned rows (the
-        # delta tier is host-side and excluded) — task attribution and
-        # plane_serving report both views
-        sdev = max(self.mesh_shard_devices, 1)
-        base_scan = int(base_docs if scanned is None else scanned)
-        batch_info["docs_scanned_per_device"] = -(-base_scan // sdev)
-        tier = plane_stages.get("tier")
-        if tier is not None:
-            # streamed-tier dispatch (warm plane): surface the storage
-            # tier + per-dispatch host→device stream bytes next to the
-            # transfer counters, so profile:true and the stats rollup
-            # show WHY this dispatch's byte model moved to the host link
-            batch_info["tier"] = tier
-            batch_info["stream_bytes"] = int(
-                plane_stages.get("stream_bytes", 0))
-        delta_ms = plane_stages.get("delta_ms")
-        if delta_ms is not None:
-            # this dispatch merged the base plane with a live delta tier:
-            # surface the scan cost + delta size in the Profile API's
-            # serving section and the batcher's stats rollup
-            batch_info["delta_ms"] = round(delta_ms, 3)
-            batch_info["delta_docs"] = int(
-                plane_stages.get("delta_docs", 0))
-        with self._cond:
-            racedep.note_write("microbatch.stats", self)
-            fetch_ms = fetch_base_ms + \
-                (time.perf_counter() - t_done) * 1e3
-            for s in batch:
-                s.info = batch_info
-                s.stage_ms = {
-                    "queue": (t_pick - s.t_enq) * 1e3, "prep": prep_ms,
-                    "dispatch": dispatch_ms, "fetch": fetch_ms}
-                if "agg_ms" in plane_stages:
-                    # fused analytics stages ran inside this dispatch:
-                    # break their share out next to the pipeline stages
-                    # (profile:true serving section)
-                    s.stage_ms["agg"] = plane_stages["agg_ms"]
-                for name in STAGES:
-                    self.stage_totals_ms[name] += s.stage_ms[name]
-                    self.stage_samples[name].append(s.stage_ms[name])
-                s.done = True
-            self.n_dispatches += 1
-            self.n_queries += len(batch)
-            self.n_deduped += n_deduped
+                if uniq[idx] is None:
+                    uniq[idx] = s.terms
+            # pad the batch to a power of two: every distinct traced B shape is
+            # a fresh XLA compile — ragged arrival sizes would otherwise
+            # compile dozens of programs (padding slots score as no-op
+            # queries). Then pad on to a REPLICA-axis multiple: the mesh
+            # partitions the batch dim over replica groups (the pad at
+            # dist_search.search would add it anyway), and filling the
+            # per-replica sub-batches here keeps the batcher's co-batched
+            # block equal to the traced block — warm-lattice shapes ARE the
+            # serving shapes at every mesh.
+            b_pad = 1 << max(0, (len(uniq) - 1).bit_length())
+            rm = self.mesh_replica_devices
+            if rm > 1:
+                b_pad = -(-b_pad // rm) * rm
+            queries = uniq + [self._pad_slot()
+                              for _ in range(b_pad - len(uniq))]
+            plane_stages: Dict[str, float] = {}
+            exec_span = phases.enter("batch[execute]", seq=seq,
+                                     requests=len(batch), b_pad=b_pad)
+            t_call = time.perf_counter()
+            err: Optional[BaseException] = None
+            try:
+                out = self._dispatch(
+                    queries, k, plane_stages,
+                    view=batch[0].view_segments, params=batch[0].params)
+                vals, hits, totals = out[:3]
+                # fused agg stages: a plane that served analytics stages
+                # returns a 4th per-slot list of aggregations dicts
+                aggs_list = out[3] if len(out) > 3 else None
+            except BaseException as e:      # noqa: BLE001 — fan the error
+                err = e                     # out to every query in the batch
+            if exec_span is not None:
+                exec_span.attrs["kernel"] = self._kernel_family(
+                    batch[0].params, plane_stages)
+            phases.enter("batch[fetch]", seq=seq, requests=len(batch))
+            t_done = time.perf_counter()
+            if err is not None:
+                for s in batch:
+                    s.error = err
+            else:
+                for s, idx in zip(batch, lane):
+                    s.vals = vals[idx][:s.k]
+                    s.hits = hits[idx][:s.k]
+                    s.total = totals[idx]
+                    if aggs_list is not None:
+                        s.aggs = aggs_list[idx]
+            # stage attribution: queue wait is per-slot; prep / dispatch /
+            # fetch are shared by the whole batch (one dispatch). The plane
+            # refines its own call into prep/dispatch/fetch when it can;
+            # otherwise the whole call counts as dispatch.
+            prep_ms = (t_call - t_pick) * 1e3 \
+                + plane_stages.get("prep_ms", 0.0)
+            dispatch_ms = plane_stages.get(
+                "dispatch_ms", (t_done - t_call) * 1e3)
+            fetch_base_ms = plane_stages.get("fetch_ms", 0.0)
+            batch_info = {"dispatch_seq": seq, "batch_size": len(batch),
+                          "k_bucket": k,
+                          "compile_cache": plane_stages.get("compile_cache",
+                                                            "hit"),
+                          # the dispatch's mesh topology, so profile:true
+                          # responses name the device fan-out next to the
+                          # per-device docs share below
+                          "mesh": {"shard_devices": self.mesh_shard_devices,
+                                   "replica_devices":
+                                       self.mesh_replica_devices}}
+            # task resource attribution (node/task_manager.TaskResources):
+            # the dispatch's transfer bytes split across the batch's slots
+            # (so per-task sums reconcile with es_device_transfer_bytes_total)
+            # while docs scanned is per QUERY — every query's score covers
+            # the full base corpus plus the delta tier
+            share = 1.0 / max(len(batch), 1)
+            h2d = plane_stages.get("h2d_bytes")
+            d2h = plane_stages.get("d2h_bytes")
+            if h2d or d2h:
+                batch_info["h2d_bytes"] = int((h2d or 0) * share)
+                batch_info["d2h_bytes"] = int((d2h or 0) * share)
+            base_docs = getattr(self.plane, "base_docs", None)
+            if base_docs is None:
+                base_docs = getattr(self.plane, "n_docs_total", 0)
+            # a cluster-pruned (IVF) dispatch scans only the probed rows —
+            # the plane reports them; full scans cover the whole base corpus
+            scanned = plane_stages.get("docs_scanned")
+            batch_info["docs_scanned"] = int(
+                (base_docs if scanned is None else scanned)
+                + plane_stages.get("delta_docs", 0))
+            # per-DEVICE share of the scan: the shard axis partitions the
+            # corpus, so each chip streams ~1/s_dev of the scanned rows (the
+            # delta tier is host-side and excluded) — task attribution and
+            # plane_serving report both views
+            sdev = max(self.mesh_shard_devices, 1)
+            base_scan = int(base_docs if scanned is None else scanned)
+            batch_info["docs_scanned_per_device"] = -(-base_scan // sdev)
+            tier = plane_stages.get("tier")
+            if tier is not None:
+                # streamed-tier dispatch (warm plane): surface the storage
+                # tier + per-dispatch host→device stream bytes next to the
+                # transfer counters, so profile:true and the stats rollup
+                # show WHY this dispatch's byte model moved to the host link
+                batch_info["tier"] = tier
+                batch_info["stream_bytes"] = int(
+                    plane_stages.get("stream_bytes", 0))
+            delta_ms = plane_stages.get("delta_ms")
             if delta_ms is not None:
-                self.n_delta_queries += len(batch)
-                self.delta_ms += delta_ms
-            self.max_seen_batch = max(self.max_seen_batch, len(batch))
-            self._cond.notify_all()
+                # this dispatch merged the base plane with a live delta tier:
+                # surface the scan cost + delta size in the Profile API's
+                # serving section and the batcher's stats rollup
+                batch_info["delta_ms"] = round(delta_ms, 3)
+                batch_info["delta_docs"] = int(
+                    plane_stages.get("delta_docs", 0))
+            with self._cond:
+                racedep.note_write("microbatch.stats", self)
+                fetch_ms = fetch_base_ms + \
+                    (time.perf_counter() - t_done) * 1e3
+                for s in batch:
+                    s.info = batch_info
+                    s.stage_ms = {
+                        "queue": (t_pick - s.t_enq) * 1e3, "prep": prep_ms,
+                        "dispatch": dispatch_ms, "fetch": fetch_ms}
+                    if "agg_ms" in plane_stages:
+                        # fused analytics stages ran inside this dispatch:
+                        # break their share out next to the pipeline stages
+                        # (profile:true serving section)
+                        s.stage_ms["agg"] = plane_stages["agg_ms"]
+                    for name in STAGES:
+                        self.stage_totals_ms[name] += s.stage_ms[name]
+                        self.stage_samples[name].append(s.stage_ms[name])
+                    s.done = True
+                self.n_dispatches += 1
+                self.n_queries += len(batch)
+                self.n_deduped += n_deduped
+                if delta_ms is not None:
+                    self.n_delta_queries += len(batch)
+                    self.delta_ms += delta_ms
+                self.max_seen_batch = max(self.max_seen_batch, len(batch))
+                self._cond.notify_all()
         t_end = time.perf_counter()
         # dispatch-timeline record + roofline audit, then the
         # flight-recorder slow-dispatch journal — ALL outside the
@@ -553,7 +569,7 @@ class PlaneMicroBatcher:
         # under a serving lock). The slow event carries the profile
         # record's seq so the two journals cross-link.
         rec = self._profile_dispatch(
-            batch, n_uniq=len(slot_of), k=k, b_pad=b_pad,
+            batch, seq=seq, n_uniq=len(slot_of), k=k, b_pad=b_pad,
             t_pick=t_pick, t_call=t_call, t_done=t_done, t_end=t_end,
             plane_stages=plane_stages, batch_info=batch_info, err=err)
         from ..common import flightrec as _fr
@@ -580,7 +596,7 @@ class PlaneMicroBatcher:
             return "bm25_pruned"
         return "bm25_eager"
 
-    def _profile_dispatch(self, batch, *, n_uniq: int, k: int,
+    def _profile_dispatch(self, batch, *, seq: int, n_uniq: int, k: int,
                           b_pad: int, t_pick: float, t_call: float,
                           t_done: float, t_end: float,
                           plane_stages: dict, batch_info: dict,
@@ -591,7 +607,6 @@ class PlaneMicroBatcher:
         under a lock; O(1) and never raises."""
         try:
             from ..common import roofline as _rf
-            from . import dispatch_profile as _dp
             mono_end = time.perf_counter()
             wall_end = time.time()
 
@@ -631,7 +646,7 @@ class PlaneMicroBatcher:
                     audit = {"error": str(e)}
             me = threading.current_thread()
             return _dp.record(
-                ts_ms=round(wall(q_start), 3),
+                seq=seq, ts_ms=round(wall(q_start), 3),
                 mono_ms=round(q_start * 1e3, 3),
                 end_ms=round(wall(t_end), 3),
                 node=next((s.node for s in batch if s.node), None),

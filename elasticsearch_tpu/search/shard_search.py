@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import tracing as _tracing
 from ..common.errors import IllegalArgumentError, ParsingError
 from ..index.mapping import (DateFieldType, DenseVectorFieldType,
                              KeywordFieldType, MapperService, NumberFieldType,
@@ -58,6 +59,18 @@ def _attribute_dispatch(stages: Optional[dict],
             docs_scanned=int(info.get("docs_scanned", 0)),
             delta_docs_scanned=int(info.get("delta_docs", 0)),
             dispatches=1)
+
+
+def _dispatch_attrs(span, stages: dict, info: dict) -> None:
+    """A dispatch span's attributes once the dispatch has come back: the
+    request's stage timings and the batcher's stamp (batch size,
+    ``compile_cache``, ``dispatch_seq``: the number of the dispatch that
+    carried it, as its timeline record and ``batch[...]`` spans bear
+    it)."""
+    if span is not None:
+        span.attrs.update({s: round(ms, 3) for s, ms in stages.items()
+                           if isinstance(ms, (int, float))})
+        span.attrs.update(info)
 
 
 def _attribute_segment_scan(segments) -> None:
@@ -260,13 +273,15 @@ class ShardSearcher:
                 from .microbatch import batched_knn_search
                 knn_stages: Dict[str, float] = {}
                 knn_info: Dict[str, object] = {}
-                raw, phits = batched_knn_search(plane, qv,
-                                                k=num_candidates,
-                                                view=self.segments,
-                                                stages=knn_stages,
-                                                info=knn_info,
-                                                nprobe=nprobe,
-                                                rerank=rerank)
+                with _tracing.span("plane_dispatch") as dsp:
+                    raw, phits = batched_knn_search(plane, qv,
+                                                    k=num_candidates,
+                                                    view=self.segments,
+                                                    stages=knn_stages,
+                                                    info=knn_info,
+                                                    nprobe=nprobe,
+                                                    rerank=rerank)
+                    _dispatch_attrs(dsp, knn_stages, knn_info)
                 _attribute_dispatch(knn_stages, knn_info)
                 if serving_out is not None:
                     serving_out.append({
@@ -382,12 +397,23 @@ class ShardSearcher:
     # main entry
     # ------------------------------------------------------------------
 
-    def search(self, body: Optional[dict] = None, *, size: int = 10,
-               from_: int = 0, min_score: Optional[float] = None,
-               track_total_hits=True,
-               collect_agg_inputs: bool = False,
-               knn_override: Optional[List[List[Tuple[float, int, int]]]]
-               = None) -> ShardSearchResult:
+    def search(self, body: Optional[dict] = None, **kw) -> ShardSearchResult:
+        """One shard-level search (:meth:`_search` has the arguments), as
+        consecutive spans under the ambient one: ``shard[plan]``, then
+        the dispatch span of the route taken (``plane_dispatch`` /
+        ``fused_dispatch``) or ``shard[query_phase]``, ``shard[knn]``,
+        ``shard[rank]``, ``shard[fetch]``."""
+        with _tracing.Phases() as phases:
+            return self._search(phases, body, **kw)
+
+    def _search(self, phases, body: Optional[dict] = None, *,
+                size: int = 10,
+                from_: int = 0, min_score: Optional[float] = None,
+                track_total_hits=True,
+                collect_agg_inputs: bool = False,
+                knn_override: Optional[List[List[Tuple[float, int, int]]]]
+                = None) -> ShardSearchResult:
+        plan_span = phases.enter("shard[plan]")
         body = body or {}
         size = int(body.get("size", size))
         from_ = int(body.get("from", from_))
@@ -515,13 +541,18 @@ class ShardSearcher:
                     from .microbatch import batched_fused_search
                     fstages: Dict[str, float] = {}
                     finfo: Dict[str, object] = {}
-                    try:
-                        fused_result = batched_fused_search(
-                            runner, qp.make_item(fused_plan),
-                            view=self.segments, stages=fstages,
-                            info=finfo, prune=fprune)
-                    except qp.FusedFallback:
-                        fused_result = None
+                    if plan_span is not None:
+                        plan_span.attrs["route"] = "fused"
+                    phases.close()
+                    with _tracing.span("fused_dispatch") as dsp:
+                        try:
+                            fused_result = batched_fused_search(
+                                runner, qp.make_item(fused_plan),
+                                view=self.segments, stages=fstages,
+                                info=finfo, prune=fprune)
+                        except qp.FusedFallback:
+                            fused_result = None
+                        _dispatch_attrs(dsp, fstages, finfo)
                 from ..common import telemetry as _tm
                 _tm.record_planner(
                     "fused" if fused_result is not None
@@ -551,6 +582,10 @@ class ShardSearcher:
         serving_stages: Optional[Dict[str, float]] = None
         serving_info: Optional[Dict[str, object]] = None
         plane_total_gte = False
+        if plan_span is not None:
+            plan_span.attrs["route"] = (
+                "fused" if fused_result is not None
+                else "plane" if plane_route is not None else "segments")
         if fused_result is not None:
             # the fused dispatch already ran the whole retrieval
             # pipeline (bool scoring, knn, fusion, rescore): its rows
@@ -572,15 +607,6 @@ class ShardSearcher:
             knn_spec = None
             rescore_spec = None
             rank_spec = None
-            from ..common import tracing as _tracing
-            _tracing.record_point(
-                "fused_dispatch",
-                took_ms=sum(v for v in serving_stages.values()
-                            if isinstance(v, (int, float))),
-                attrs={**{s: round(ms, 3)
-                          for s, ms in serving_stages.items()
-                          if isinstance(ms, (int, float))},
-                       **serving_info})
             _attribute_dispatch(serving_stages, serving_info)
         elif plane_route is not None:
             plane, bag_terms = plane_route
@@ -608,26 +634,25 @@ class ShardSearcher:
             # view=self.segments: hit coordinates must decode against
             # THIS searcher's snapshot even if a refresh mutates the
             # generation's delta while the request sits in the queue
-            pvals0, phits0, ptotal0 = batched_search(
-                plane, bag_terms, k=max(window, 1), stages=serving_stages,
-                info=serving_info, view=self.segments, prune=prune_eff)
+            phases.close()
+            with _tracing.span("plane_dispatch") as dsp:
+                pvals0, phits0, ptotal0 = batched_search(
+                    plane, bag_terms, k=max(window, 1),
+                    stages=serving_stages, info=serving_info,
+                    view=self.segments, prune=prune_eff)
+                _dispatch_attrs(dsp, serving_stages, serving_info)
             from ..parallel.dist_search import (total_is_lower_bound,
                                                 total_value)
             plane_total_gte = total_is_lower_bound(ptotal0)
             total = total_value(ptotal0)
             candidates = [(float(v), si, d)
                           for v, (si, d) in zip(pvals0, phits0)]
-            # trace: the micro-batch dispatch as one leaf span under the
-            # ambient shard span (stage timings arrive after the fact)
-            from ..common import tracing as _tracing
-            _tracing.record_point(
-                "plane_dispatch",
-                took_ms=sum(serving_stages.values()),
-                attrs={**{s: round(ms, 3)
-                          for s, ms in serving_stages.items()},
-                       **serving_info})
             _attribute_dispatch(serving_stages, serving_info)
         else:
+            # the per-segment eager scorers; a body with no query (knn
+            # only) still scores match_all here
+            phases.enter("shard[query_phase]", segments=len(self.segments),
+                         has_query=bool(query_spec))
             for seg_idx, seg in enumerate(self.segments):
                 scores, mask = query.execute(self.ctx, seg)
                 mask = mask & seg.live_dev
@@ -696,10 +721,12 @@ class ShardSearcher:
             knn_rankings = knn_override
         elif knn_spec:
             specs = knn_spec if isinstance(knn_spec, list) else [knn_spec]
+            phases.enter("shard[knn]", clauses=len(specs))
             for spec in specs:
                 knn_rankings.append(self._knn_candidates(
                     spec, serving_out=knn_serving if profile_on else None))
 
+        phases.enter("shard[rank]")
         max_score: Optional[float] = None
         if knn_rankings:
             # ONE copy of the fusion arithmetic, shared with the fused
@@ -786,6 +813,7 @@ class ShardSearcher:
             total_relation = "gte"
 
         # --- fetch phase ---------------------------------------------------
+        phases.enter("shard[fetch]", hits=len(page))
         source_spec = body.get("_source", True)
         stored = body.get("stored_fields")
         if stored is not None and "_source" not in body and \

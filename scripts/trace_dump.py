@@ -31,11 +31,21 @@ journal events as instant ``i`` marks.
 
 Output, one line per span, indented by tree depth:
 
-    rest[indices:data/read/search]              12.41ms  node=n0
-      coordinator[search]                       11.80ms  indices=logs
-        shards[logs]                            11.02ms
-          plane_dispatch                         9.13ms  compile_cache=hit
-          * failover_wave                        @+3.20ms  failed=n2
+    http[in]                                     0.09ms  bytes_in=57
+      rest[parse]                                0.12ms
+      rest[indices:data/read/search]            12.41ms  node=n0
+        coordinator[search]                     11.80ms  indices=logs
+          shards[logs]                          11.02ms
+            shard[plan]                          0.21ms  route=plane
+            plane_dispatch                       9.13ms  compile_cache=hit  dispatch_seq=88
+            * failover_wave                      @+3.20ms  failed=n2
+            shard[rank]                          0.02ms
+            shard[fetch]                         1.40ms  hits=10
+      rest[render]                               0.30ms
+      http[out]                                  0.05ms  status=200  bytes_out=4211
+
+(``http[in]`` ends where the request leaves the event loop for a handler
+thread; its children start after it has ended.)
 """
 from __future__ import annotations
 
@@ -189,7 +199,7 @@ def main() -> int:
     ap.add_argument("--tenant", default=None,
                     help="with --list/--last: keep only one tenant's "
                          "traces (server-side GET /_trace?tenant=, the "
-                         "X-Opaque-Id stamped on the root span)")
+                         "X-Opaque-Id stamped on the rest[...] span)")
     ap.add_argument("--json", action="store_true",
                     help="raw JSON instead of the tree rendering")
     ap.add_argument("--events", action="store_true",

@@ -334,8 +334,8 @@ def test_single_node_trace_spans_rest_to_shard(api_with_index):
     assert coord["name"] == "coordinator[search]"
     assert coord["children"][0]["name"] == "shards[tr]"
     # plane dispatch carries stage + compile-cache attribution
-    pd = coord["children"][0]["children"][0]
-    assert pd["name"] == "plane_dispatch"
+    pd = next(c for c in coord["children"][0]["children"]
+              if c["name"] == "plane_dispatch")
     assert "compile_cache" in pd["attrs"]
     # unknown traces 404
     st3, _c, _p3 = api.handle("GET", "/_trace/deadbeef", "", b"")
