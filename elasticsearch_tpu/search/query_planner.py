@@ -279,7 +279,9 @@ def lower_body(body: dict, mapper: MapperService) -> Optional[FusedPlan]:
     rank_spec = body.get("rank")
     rescore_spec = body.get("rescore")
     if query_spec is None:
-        return None               # knn-only: the knn route serves it
+        # knn-only: nothing to lower. ShardSearcher serves it from the
+        # kNN rankings alone (shard[plan] route=knn, no query phase)
+        return None
     if agg_plan is not None and knn_spec is not None:
         # top-level knn widens the match set the aggs run over
         # (hybrid hits participate in aggregations) — the agg stages

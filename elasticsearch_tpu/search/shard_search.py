@@ -10,7 +10,10 @@ candidate lists are merged on the host (score desc, then segment/doc id asc —
 Lucene's tie-break order). Field sorting builds normalized sort-key columns
 and lexsorts matched docs; ``knn`` runs the brute-force einsum per segment
 and merges with the query's candidates (hybrid score sum, or reciprocal
-rank fusion under ``rank.rrf``)."""
+rank fusion under ``rank.rrf``). A body with ``knn`` and no ``query`` runs
+no query phase, as in the reference: its hits and ``hits.total`` are the
+kNN rankings'; the phase still runs where ``aggs`` or a field sort read
+its masks."""
 
 from __future__ import annotations
 
@@ -401,7 +404,8 @@ class ShardSearcher:
         """One shard-level search (:meth:`_search` has the arguments), as
         consecutive spans under the ambient one: ``shard[plan]``, then
         the dispatch span of the route taken (``plane_dispatch`` /
-        ``fused_dispatch``) or ``shard[query_phase]``, ``shard[knn]``,
+        ``fused_dispatch``) or ``shard[query_phase]`` (not for a knn-only
+        body: ``shard[plan]``'s ``route`` is ``knn``), ``shard[knn]``,
         ``shard[rank]``, ``shard[fetch]``."""
         with _tracing.Phases() as phases:
             return self._search(phases, body, **kw)
@@ -573,6 +577,20 @@ class ShardSearcher:
                 "shape": shape_id,
             }
 
+        # --- knn route: no query phase -----------------------------------
+        # With no `query`, and kNN rankings to come (the body's `knn`, or
+        # the coordinator's knn_override), the rank section below takes
+        # its candidates and its total from those rankings alone and
+        # drops the query phase's. What else reads the phase: aggs
+        # (agg_pending: masks and scores, for the reduce here or as the
+        # collect_agg_inputs payload) and a field sort (host_masks /
+        # host_scores). With neither, nothing reads it and it does not
+        # run (the reference runs no match_all for such a body either).
+        knn_only = (not query_spec
+                    and bool(knn_spec if knn_override is None
+                             else knn_override)
+                    and aggs is None and not use_field_sort)
+
         # --- query phase (device) -----------------------------------------
         pending = []
         agg_pending = []
@@ -585,7 +603,8 @@ class ShardSearcher:
         if plan_span is not None:
             plan_span.attrs["route"] = (
                 "fused" if fused_result is not None
-                else "plane" if plane_route is not None else "segments")
+                else "plane" if plane_route is not None
+                else "knn" if knn_only else "segments")
         if fused_result is not None:
             # the fused dispatch already ran the whole retrieval
             # pipeline (bool scoring, knn, fusion, rescore): its rows
@@ -648,9 +667,12 @@ class ShardSearcher:
             candidates = [(float(v), si, d)
                           for v, (si, d) in zip(pvals0, phits0)]
             _attribute_dispatch(serving_stages, serving_info)
+        elif knn_only:
+            total = 0
+            candidates: List[Tuple[float, int, int]] = []
         else:
-            # the per-segment eager scorers; a body with no query (knn
-            # only) still scores match_all here
+            # the per-segment eager scorers; a body with no query scores
+            # match_all here (knn + aggs, knn + field sort, empty body)
             phases.enter("shard[query_phase]", segments=len(self.segments),
                          has_query=bool(query_spec))
             for seg_idx, seg in enumerate(self.segments):
@@ -699,7 +721,7 @@ class ShardSearcher:
                         host_scores[seg_idx] = np.asarray(scores)
 
             total = 0
-            candidates: List[Tuple[float, int, int]] = []
+            candidates = []
             for seg_idx, count_dev, vals_dev, idx_dev in pending:
                 if count_dev is not None:
                     total += int(count_dev)

@@ -35,6 +35,10 @@ DIM = 8
 KNN_BODY = {"knn": {"field": "v", "query_vector": [0.5] * DIM, "k": 3,
                     "num_candidates": 3, "nprobe": 0}, "size": 3}
 TEXT_BODY = {"query": {"match": {"body": "quick"}}}
+#: knn beside a query the planner does not lower: the per-segment query
+#: phase scores the phrase, the kNN plane serves the clause
+HYBRID_BODY = dict(KNN_BODY,
+                   query={"match_phrase": {"body": "quick brown"}})
 
 
 @contextlib.contextmanager
@@ -108,18 +112,25 @@ def _end(span):
 
 #: another body of the same route (an equal one is answered by the shard
 #: request cache, and never reaches the shard)
-WARM = {"knn": KNN_BODY, "text": {"query": {"match": {"body": "lazy"}}}}
+WARM = {"knn": KNN_BODY, "text": {"query": {"match": {"body": "lazy"}}},
+        "hybrid": dict(KNN_BODY,
+                       query={"match_phrase": {"body": "lazy dog"}})}
+#: route -> (body, the shard's phases, plane_dispatch's parent,
+#: shard[plan]'s route attribute)
 ROUTES = {
-    "knn": (KNN_BODY, ["shard[plan]", "shard[query_phase]", "shard[knn]",
-                       "shard[rank]", "shard[fetch]"], "shard[knn]"),
+    "knn": (KNN_BODY, ["shard[plan]", "shard[knn]", "shard[rank]",
+                       "shard[fetch]"], "shard[knn]", "knn"),
+    "hybrid": (HYBRID_BODY, ["shard[plan]", "shard[query_phase]",
+                             "shard[knn]", "shard[rank]", "shard[fetch]"],
+               "shard[knn]", "segments"),
     "text": (TEXT_BODY, ["shard[plan]", "plane_dispatch", "shard[rank]",
-                         "shard[fetch]"], "shards[sp]"),
+                         "shard[fetch]"], "shards[sp]", "plane"),
 }
 
 
 @pytest.mark.parametrize("route", sorted(ROUTES))
 def test_search_leaves_one_span_tree(route):
-    body, shard_children, dispatch_parent = ROUTES[route]
+    body, shard_children, dispatch_parent, plan_route = ROUTES[route]
     with served_node() as post:
         post("/sp/_search", WARM[route])       # pack the plane
         headers, resp = post("/sp/_search", body)
@@ -153,11 +164,12 @@ def test_search_leaves_one_span_tree(route):
     assert one["http[in]"]["attrs"]["bytes_in"] == \
         len(json.dumps(body).encode())
     assert one["http[out]"]["attrs"]["status"] == 200
-    assert one["shard[plan]"]["attrs"]["route"] == \
-        ("segments" if route == "knn" else "plane")
-    if route == "knn":
+    assert one["shard[plan]"]["attrs"]["route"] == plan_route
+    # a knn-only body runs no query phase; beside a query it does
+    assert ("shard[query_phase]" in one) == (route == "hybrid")
+    if route == "hybrid":
         assert one["shard[query_phase]"]["attrs"] == \
-            {"segments": 1, "has_query": False}
+            {"segments": 1, "has_query": True}
     # the dispatch span bears the dispatch's number, and the timeline
     # has that record
     pd = one["plane_dispatch"]["attrs"]
