@@ -19,6 +19,16 @@ import time
 from .node import Http
 
 
+def percentile(sorted_vals: list, q: float) -> float:
+    """Linear-interpolated percentile of an ascending list."""
+    if len(sorted_vals) == 1:
+        return sorted_vals[0]
+    pos = q * (len(sorted_vals) - 1)
+    lo = int(pos)
+    hi = min(lo + 1, len(sorted_vals) - 1)
+    return sorted_vals[lo] + (sorted_vals[hi] - sorted_vals[lo]) * (pos - lo)
+
+
 class Request:
     __slots__ = ("client", "qrec", "sent", "received", "status", "raw",
                  "error")

@@ -77,14 +77,11 @@ class Cell:
         cells = metric.get("workloads")
         return cells is None or self.name in cells
 
-    def end_to_end(self) -> list:
-        return [m for m in self.manifest["end_to_end"] if self._reports(m)]
-
-    def per_layer(self) -> list:
-        """The cell's per-layer metrics, each with its descriptor file
+    def _described(self, kind: str) -> list:
+        """The cell's metrics of one kind, each with its descriptor file
         (``benchmark/metrics/<name>.json``: reader and parameters)."""
         out = []
-        for m in self.manifest["per_layer"]:
+        for m in self.manifest[kind]:
             if not self._reports(m):
                 continue
             desc = load_json(os.path.join(self.metrics_dir,
@@ -92,3 +89,9 @@ class Cell:
             out.append(dict(m, **{"reader": desc["reader"],
                                   "params": desc.get("params", {})}))
         return out
+
+    def end_to_end(self) -> list:
+        return self._described("end_to_end")
+
+    def per_layer(self) -> list:
+        return self._described("per_layer")

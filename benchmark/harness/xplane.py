@@ -8,8 +8,9 @@ trace reader:
    node child is gone, so it never touches the chip):
    ``python benchmark/harness/xplane.py <trace.xplane.pb> <out.json>``.
 2. :func:`summarize` is plain Python over those events: the union of busy
-   intervals, time per XLA module (the jitted program: the program has no
-   ``named_scope`` yet), self time per op, and the idle gaps.
+   intervals, time per XLA module (the jitted program) and self time per
+   op. The program's spans and scopes, and the idle gaps by what the
+   dispatchers were doing, are ``xplane_spans.py``'s.
 
 On a TPU the device plane (``/device:TPU:<n>``) carries a line of module
 events (``XLA Modules``) and a line of op events (``XLA Ops``); op events
@@ -95,19 +96,15 @@ def summarize_plane(plane: dict) -> dict:
     for name, s, d in mod_events:
         # one jitted program compiled at several shapes is one module here:
         # drop the program id the runtime appends ("jit_step(123)")
-        m = modules.setdefault(re.sub(r"\(\d+\)$", "", name),
-                               [0, 0.0, []])
+        m = modules.setdefault(re.sub(r"\(\d+\)$", "", name), [0, 0.0])
         m[0] += 1
         m[1] += d / 1e9
-        m[2].append([s, s + d])
     ops = sorted(_self_times(op_events).items(), key=lambda kv: -kv[1])
     return {"plane": plane["name"],
             "busy_s": sum(b - a for a, b in merged) / 1e9,
             "first_ns": merged[0][0] if merged else None,
             "last_ns": merged[-1][1] if merged else None,
-            "busy_intervals_ns": merged,
-            "modules": {k: {"count": v[0], "seconds": v[1],
-                            "intervals_ns": sorted(v[2])}
+            "modules": {k: {"count": v[0], "seconds": v[1]}
                         for k, v in modules.items()},
             "ops": ops[:10],
             "lines": {ln["name"]: len(ln["events"])
@@ -115,7 +112,7 @@ def summarize_plane(plane: dict) -> dict:
 
 
 def summarize(planes: list) -> dict:
-    """Per device plane: busy seconds, modules, top ops, busy intervals."""
+    """Per device plane: busy seconds, modules, top ops."""
     return {"devices": [summarize_plane(p) for p in planes
                         if any(ln["events"] for ln in p["lines"])]}
 
@@ -144,64 +141,6 @@ def step_ms(trace):
     if top is None or top[1] <= 0:
         return None
     return top[2] / top[1] * 1e3
-
-
-def clock_offset_ns(step_intervals_ns: list, host_execute_ns: list):
-    """The host's wall clock minus the device's, from the step program's
-    executions and the batcher's execute stages, which end together (the
-    dispatcher blocks until the result is ready): the median difference
-    of their ends, matched in order. None when the counts differ, so that
-    no order can be trusted."""
-    if not step_intervals_ns or \
-            len(step_intervals_ns) != len(host_execute_ns):
-        return None
-    dev_ends = sorted(b for _a, b in step_intervals_ns)
-    host_ends = sorted(b for _a, b in host_execute_ns)
-    diffs = sorted(h - d for h, d in zip(host_ends, dev_ends))
-    return diffs[len(diffs) // 2]
-
-
-def idle_gaps(busy_intervals_ns: list, start_ns: float, end_ns: float):
-    """The gaps between busy intervals inside [start, end]: [[a, b], ...]."""
-    gaps, cur = [], start_ns
-    for a, b in busy_intervals_ns:
-        if b <= start_ns:
-            continue
-        if a >= end_ns:
-            break
-        if a > cur:
-            gaps.append([cur, min(a, end_ns)])
-        cur = max(cur, b)
-    if cur < end_ns:
-        gaps.append([cur, end_ns])
-    return gaps
-
-
-def attribute_gaps(gaps_ns: list, host_spans_ns: list) -> list:
-    """Idle seconds by what the host was doing: each gap is split over the
-    host spans ([name, start_ns, end_ns], earlier names win) that overlap
-    it, the rest goes to ``outside_dispatch``. Returns [[name, seconds]]
-    sorted, at most 10."""
-    total: dict = {}
-    spans = sorted(host_spans_ns, key=lambda s: s[1])
-    for a, b in gaps_ns:
-        cur = a
-        for name, s, e in spans:
-            if e <= cur or s >= b:
-                continue
-            s, e = max(s, cur), min(e, b)
-            if s > cur:
-                total["outside_dispatch"] = \
-                    total.get("outside_dispatch", 0.0) + (s - cur) / 1e9
-            total[name] = total.get(name, 0.0) + (e - s) / 1e9
-            cur = e
-            if cur >= b:
-                break
-        if cur < b:
-            total["outside_dispatch"] = \
-                total.get("outside_dispatch", 0.0) + (b - cur) / 1e9
-    return [[k, v] for k, v in sorted(total.items(),
-                                      key=lambda kv: -kv[1])][:10]
 
 
 def main(argv) -> int:

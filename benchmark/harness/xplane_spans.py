@@ -296,7 +296,8 @@ def reduce(raw: dict) -> dict:
     - ``launches``: {span name: programs launched on request threads
       while that span was the innermost open one}, with ``n_requests``;
     - ``modules``: {module: {"count", "seconds"}}; ``op_self_s``:
-      {module: {op_name: own seconds}};
+      {module: {op_name: own seconds}}; ``step_intervals``: the union of
+      the first device's ``jit_*`` executions, [[start, end]];
     - ``idle_gaps``: [[what the dispatchers were doing, seconds]], gaps
       over 1 ms only, and ``idle_s`` / ``window_s`` of the first device.
     """
@@ -464,6 +465,8 @@ def reduce(raw: dict) -> dict:
         "launches": {"by_span": launched, "n_requests": sum(
             1 for r in requests.values() if "http[in]" in r["spans"])},
         "modules": modules, "op_self_s": op_self,
+        "step_intervals": _union([iv for n, ivs in step_execs.items()
+                                  if n.startswith("jit_") for iv in ivs]),
         "idle_gaps": sorted(([k, v] for k, v in idle.items()),
                             key=lambda kv: -kv[1]),
         "idle_s": idle_s,
@@ -503,15 +506,18 @@ def edge_ms(summary: dict, frm: dict, to: dict):
 
 
 def execute_host_ms(summary: dict):
-    """(mean ms, dispatches counted) of ``batch[execute]`` less the device
-    time of the step executions inside it."""
+    """(mean ms, dispatches counted) of ``batch[execute]`` less the time
+    inside it in which a jitted program ran on the device: the dispatch's
+    own step, and the other in-flight dispatch's, which it waits out in its
+    ``plane[sync]`` (two overlap since PR 26). What is left is the host's
+    time that no step hides."""
     vals = []
     for d in summary["dispatches"].values():
         ex = d["spans"].get("batch[execute]")
         if ex is None or not d["steps"]:
             continue
-        vals.append(((ex[1] - ex[0])
-                     - sum(e - s for s, e in d["steps"])) / 1e6)
+        vals.append(((ex[1] - ex[0]) - _covered(
+            ex[0], ex[1], summary["step_intervals"])) / 1e6)
     return (statistics.fmean(vals), len(vals)) if vals else (None, 0)
 
 

@@ -1,7 +1,9 @@
 """Reader ``execute_host_ms``: per dispatch, the dispatcher's
-``batch[execute]`` span less the device time of the step executions inside
-it (``jit_<kernel>`` modules on the device plane of the same trace):
-launch, transfers, result decode, and the step's wait for the device."""
+``batch[execute]`` span less the time inside it in which any ``jit_*``
+program ran on the device (the device plane of the same trace), the
+dispatch's own step or the other in-flight dispatch's: launch, transfers
+and result decode that no step hides, and the waits with the device
+idle."""
 
 from harness import xplane_spans
 

@@ -35,13 +35,28 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from harness import counters, loadgen, store, xplane   # noqa: E402
+from harness import (counters, loadgen, store, xplane,  # noqa: E402
+                     xplane_spans)
 from harness.manifest import (CHECKOUT, Cell, ManifestError,  # noqa: E402
                               load_manifest, load_module)
 from harness.node import Node, NodeError               # noqa: E402
 
 CACHE_DIR = os.path.join(HERE, ".cache")
-WAVE_TRIES = 2
+WAVE_TRIES = 4
+# a wave's timing: (seconds between its blockers, seconds from the last one
+# to its group). A generator's blocker holds its dispatcher while a program
+# loads (0.5 s or more): two of them, well apart. A plain request of the mix
+# holds one for a lone step (11 ms on the kNN cell). A group of up to TRAIN
+# requests arrives within one such step: it is sent behind a train of TRAIN
+# plain requests, so close together that the device has a backlog and both
+# dispatchers are held while it arrives. A larger group takes longer to
+# arrive than any plain request lasts: behind two of them it splits into
+# one, two and the rest, which is enough to meet its padded batch (PERF.md
+# section 6, PR 28, has the counts)
+SLOW_WAVE = (0.01, 0.02)
+PLAIN_WAVE = (0.004, 0.004)
+TRAIN_WAVE = (0.004, 0.0)
+TRAIN = 4
 
 
 def say(msg: str) -> None:
@@ -62,26 +77,6 @@ class _WarmQueries:
         return self.queries.warmup(32)
 
 
-def _percentile(sorted_vals: list, q: float) -> float:
-    """Linear-interpolated percentile of an ascending list."""
-    if len(sorted_vals) == 1:
-        return sorted_vals[0]
-    pos = q * (len(sorted_vals) - 1)
-    lo = int(pos)
-    hi = min(lo + 1, len(sorted_vals) - 1)
-    return sorted_vals[lo] + (sorted_vals[hi] - sorted_vals[lo]) * (pos - lo)
-
-
-def _end_to_end(sent: list, answered: list) -> tuple:
-    """(metrics, ascending latencies ms, span s) of a window: every answered
-    request over the time from the first send to the last answer."""
-    lat = sorted((r.received - r.sent) * 1e3 for r in answered)
-    span = max(r.received for r in answered) - min(r.sent for r in sent)
-    return ({"search_qps": len(answered) / span,
-             "search_p50_ms": _percentile(lat, 0.50),
-             "search_p95_ms": _percentile(lat, 0.95)}, lat, span)
-
-
 def _answer(raw: bytes):
     """What a ``_search`` response held, or None when it is not a whole
     answer (shard failures and time-outs count as failed requests)."""
@@ -97,19 +92,22 @@ def _answer(raw: bytes):
         return None
 
 
-def _wave(node: Node, path: str, blockers: list, group: list) -> None:
-    """The blockers as single requests a few milliseconds apart (one for
-    each of the batcher's dispatchers), then ``group`` at once while those
-    execute: the group queues up and leaves as one batch, where a blocker
-    outlasts the group's arrival."""
+def _wave(node: Node, path: str, blockers: list, group: list,
+          timing: tuple = (0.0, 0.0)) -> None:
+    """The blockers as single requests ``timing[0]`` seconds apart (each
+    taken by a dispatcher before the next arrives), then after
+    ``timing[1]`` more ``group`` at once while those execute: the group
+    queues up and leaves as one batch, where the blockers hold both
+    dispatchers for longer than the group takes to arrive."""
+    gap, lead = timing
     threads = []
     for r in blockers:
         t = threading.Thread(target=loadgen.send_group,
                              args=(node.port, path, [r]), daemon=True)
         t.start()
         threads.append(t)
-        time.sleep(0.01)
-    time.sleep(0.02)
+        time.sleep(gap)
+    time.sleep(lead)
     out = loadgen.send_group(node.port, path, group)
     for t in threads:
         t.join(900)
@@ -123,11 +121,16 @@ def _wave(node: Node, path: str, blockers: list, group: list) -> None:
 def warm_up(node: Node, cell: Cell, path: str, queries, clients: int):
     """Meet every shape the cell's traffic can ask for: for each padded
     batch a closed loop of ``clients`` can produce, largest first, the
-    groups its query generator names, each sent as one batch behind the
-    generator's blockers and sent once more if the node's timeline shows
-    no dispatch at that size; then the cell's own traffic until no new
-    program compiles."""
+    groups its query generator names, each sent as one batch behind
+    blockers and sent again while the node's timeline shows no dispatch
+    at that size since the warm-up began: two of the generator's blockers
+    (slow ones, for the groups that take longest to arrive) while it has
+    any, then plain requests of the mix; then the cell's own traffic until
+    no new program compiles and every one of those padded batches has
+    been dispatched (PR 28 met a padded batch 4 for the first time inside
+    a window)."""
     w = cell.traffic.get("warmup", {})
+    t_warm_ms = time.time() * 1e3
     buckets, b = [], 1
     while b < clients:
         buckets.append(b)
@@ -136,12 +139,16 @@ def warm_up(node: Node, cell: Cell, path: str, queries, clients: int):
     for b in reversed(buckets):
         met, sent = 0, 0
         for _try in range(WAVE_TRIES):
-            t_ms = time.time() * 1e3
             for group in queries.warmup_groups(min(b, clients)):
-                _wave(node, path, queries.blockers(2), group)
+                blockers, timing = queries.blockers(2), SLOW_WAVE
+                if not blockers and len(group) <= TRAIN:
+                    blockers, timing = queries.warmup(TRAIN), TRAIN_WAVE
+                elif not blockers:
+                    blockers, timing = queries.warmup(2), PLAIN_WAVE
+                _wave(node, path, blockers, group, timing)
                 sent += 1
-            met += [d["b_pad"] for d in
-                    counters.dispatches_since(node, t_ms)].count(b)
+            met = [d["b_pad"] for d in counters.dispatches_since(
+                node, t_warm_ms)].count(b)
             if met:
                 break
         say(f"warm-up: padded batch {b}: {sent} group(s) sent, "
@@ -159,7 +166,11 @@ def warm_up(node: Node, cell: Cell, path: str, queries, clients: int):
             f"in {chunk:.0f} s + drain, {int(d['compiles'])} compile(s), "
             f"{d['compile_ms'] / 1e3:.1f} s compiling")
         quiet = quiet + 1 if d["compiles"] == 0 else 0
-        if quiet >= quiet_needed:
+        unmet = sorted(set(buckets) - {
+            x["b_pad"] for x in counters.dispatches_since(node, t_warm_ms)})
+        if unmet:
+            say(f"warm-up: no dispatch yet at padded batch {unmet}")
+        elif quiet >= quiet_needed:
             break
 
 
@@ -184,9 +195,9 @@ def _dispatch_groups(requests: list, dispatches: list) -> list:
     return [g for g in groups if g]
 
 
-def _read_trace(trace_dir: str, marks: dict, dispatches: list):
+def _read_trace(ctx: dict, trace_dir: str, marks: dict):
     """(trace summary, device busy/window seconds, breakdown) from the
-    profiler's files, read by a helper process that holds no chip."""
+    profiler's files, read by helper processes that hold no chip."""
     pb = xplane.find_xplane(trace_dir)
     if pb is None:
         say("trace: the profiler left no xplane file")
@@ -215,31 +226,15 @@ def _read_trace(trace_dir: str, marks: dict, dispatches: list):
     if top:
         say(f"trace: kernel metrics matched XLA module {top[0]!r}: "
             f"{top[1]:.0f} executions, {top[2]:.4f} s on the device")
-    # idle gaps, by what the batcher's stage clock says the host was doing.
-    # The trace's clock starts with the trace, the stages are on the wall
-    # clock: line them up on the step program's executions
+    # idle gaps by the dispatcher span open in them: the program's spans
+    # are in the same file, on the device's clock
     d0 = devs[0]
-    execs = [[a * 1e6, b * 1e6] for d in dispatches
-             for name, (a, b) in d["stages"].items() if name == "execute"]
-    step = d0["modules"].get(top[0]) if top else None
-    offset = xplane.clock_offset_ns(step["intervals_ns"] if step else [],
-                                    execs)
-    gaps = xplane.idle_gaps(d0["busy_intervals_ns"], d0["first_ns"],
-                            d0["last_ns"])
-    if offset is not None:
-        say(f"trace: clocks lined up on {len(execs)} executions of the "
-            f"step program: wall = device + {offset:.0f} ns")
-        spans = [[name, a * 1e6 - offset, b * 1e6 - offset]
-                 for d in dispatches
-                 for name, (a, b) in d["stages"].items() if name != "queue"]
-        idle = xplane.attribute_gaps(gaps, spans)
+    spans = xplane_spans.load(ctx)
+    if spans is not None:
+        idle = spans["idle_gaps"][:10]
     else:
-        say(f"trace: {len(execs)} dispatches on the host clock, "
-            f"{step['count'] if step else 0} executions of the step "
-            f"program in the trace: the clocks cannot be lined up, idle "
-            f"gaps unattributed")
-        gaps.sort(key=lambda g: g[0] - g[1])
-        idle = [["unattributed", (b - a) / 1e9] for a, b in gaps[:10]]
+        idle = [["unattributed",
+                 (d0["last_ns"] - d0["first_ns"]) / 1e9 - d0["busy_s"]]]
     breakdown = {"device_ops": [[n, s] for n, s in d0["ops"]],
                  "idle_gaps": idle}
     return trace, {"busy_s": busy_s, "window_s": window_s}, breakdown
@@ -378,13 +373,25 @@ def run(args, *, rehearsal: bool = False, node_launcher: str | None = None,
                          f"({len(reqs)} sent); first: "
                          f"{reqs[0].status if reqs else None} "
                          f"{reqs[0].raw[:300] if reqs else b''!r}")
-    e2e, lat, span = _end_to_end(reqs, ok)
-    e2e["setup_s"] = setup_s
+    # what every metric's reader sees: the answered requests over the time
+    # from the first send to the last answer, their latencies ascending
+    lat = sorted((r.received - r.sent) * 1e3 for r in ok)
+    span = max(r.received for r in ok) - min(r.sent for r in reqs)
+    ctx = {"cell": cell, "config": cfg, "traffic": traffic,
+           "counters": delta, "latencies_ms": lat, "requests": ok,
+           "span_s": span, "setup_s": setup_s, "dispatches": dispatches,
+           "device": device, "data": data, "say": say}
     gaps = res["gaps"]
     say(f"window: {len(reqs)} requests sent, {len(ok)} answered, "
         f"{failed} failed, over {span:.3f} s; generator overhead (reply "
         f"to next send) mean {statistics.fmean(gaps) * 1e6 if gaps else 0:.0f}"
         f" us, max {max(gaps) * 1e3 if gaps else 0:.2f} ms")
+    p50 = loadgen.percentile(lat, 0.5)
+    say("window: latency ms " + ", ".join(
+        f"p{round(q * 100)} {loadgen.percentile(lat, q):.1f}"
+        for q in (0.5, 0.9, 0.95, 0.99)) + f", max {lat[-1]:.1f}; "
+        f"{sum(v > 2 * p50 for v in lat)} of {len(lat)} over twice the "
+        f"median")
     routes: dict = {}
     for d in dispatches:
         key = f"{d['kernel']}/B{d['b_pad']}/{d['compile_cache']}"
@@ -395,10 +402,11 @@ def run(args, *, rehearsal: bool = False, node_launcher: str | None = None,
                if d["compile_cache"] not in ("hit", "miss"))
     say(f"window: counters {json.dumps(delta, sort_keys=True)}")
 
-    trace = breakdown = None
+    breakdown = None
     if args.trace:
-        trace, dev_times, breakdown = _read_trace(trace_dir, marks,
-                                                  dispatches)
+        ctx["dispatch_groups"] = _dispatch_groups(ok, dispatches)
+        ctx["trace"], dev_times, breakdown = _read_trace(ctx, trace_dir,
+                                                         marks)
         device.update(dev_times)
     # -- correctness: a seeded sample of the window's answers, the slowest
     # request among them, against the plain reference
@@ -437,20 +445,13 @@ def run(args, *, rehearsal: bool = False, node_launcher: str | None = None,
         say(f"control ({time.perf_counter() - t0:.1f} s): "
             f"{json.dumps(control)}")
 
-    if args.trace:
-        ctx = {"cell": cell, "config": cfg, "traffic": traffic,
-               "counters": delta, "latencies_ms": lat, "requests": ok,
-               "dispatches": dispatches,
-               "dispatch_groups": _dispatch_groups(ok, dispatches),
-               "trace": trace, "device": device, "data": data, "say": say}
-        metrics = {}
-        for m in cell.per_layer():
-            v = load_module("readers", m["reader"]).read(ctx, m["params"])
-            if v is not None:
-                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-    else:
-        metrics = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
-                   for m in cell.end_to_end()}
+    # every metric is read by the reader its descriptor names: the
+    # untraced line carries the end-to-end ones, the traced line the layers'
+    metrics = {}
+    for m in cell.per_layer() if args.trace else cell.end_to_end():
+        v = load_module("readers", m["reader"]).read(ctx, m["params"])
+        if v is not None:
+            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     result = {"correct": bool(correct), "attempted": len(reqs),
               "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:

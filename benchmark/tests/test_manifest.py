@@ -51,7 +51,7 @@ def test_every_named_file_exists_and_loads():
         load_module("references", cell.config["reference"]["name"]).Reference
         layer_metrics = cell.per_layer()
         assert layer_metrics, w["name"]
-        for p in layer_metrics:
+        for p in layer_metrics + cell.end_to_end():
             load_module("readers", p["reader"]).read
         assert {e["name"] for e in cell.end_to_end()} >= {"setup_s",
                                                           "search_qps"}
@@ -65,7 +65,7 @@ def test_every_named_file_exists_and_loads():
 
 def test_every_metric_file_is_in_the_manifest():
     m = load_manifest()
-    named = {p["name"] for p in m["per_layer"]}
+    named = {p["name"] for p in m["per_layer"] + m["end_to_end"]}
     on_disk = {f[:-5] for f in os.listdir(os.path.join(BENCH, "metrics"))}
     assert on_disk == named
 

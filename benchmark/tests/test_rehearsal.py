@@ -28,7 +28,7 @@ def test_end_to_end_run(rehearsal_manifest, workload):
     assert r["correct"] is True, r["compared"]
     assert r["failed"] == 0 and r["attempted"] > 20
     assert set(r["metrics"]) == {"search_qps", "search_p50_ms",
-                                 "search_p95_ms", "setup_s"}
+                                 "search_p99_ms", "setup_s"}
     assert all(m["value"] > 0 for m in r["metrics"].values())
     assert r["device"]["platform"] == "cpu"      # a rehearsal, no chip
 
@@ -40,8 +40,8 @@ def test_traced_run_reports_per_layer_metrics(rehearsal_manifest):
     # counters read from outside; no device plane on the CPU platform, so
     # the trace readers find nothing and report nothing (never a 0)
     assert {"batcher.mean_batch", "batcher.queue_ms", "planes.prep_ms",
-            "planes.dispatch_ms", "planes.compiles_in_window",
-            "rest.outside_batcher_ms"} <= set(r["metrics"])
+            "planes.dispatch_ms", "planes.compiles_in_window"} \
+        <= set(r["metrics"])
     assert "kernels.knn_exact_roofline" not in r["metrics"]
     assert "device.idle_share" not in r["metrics"]
 

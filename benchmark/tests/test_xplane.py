@@ -41,16 +41,11 @@ def test_recorded_trace(recorded):
     assert sum(t for _n, t in xplane._self_times(ops).items()) == \
         pytest.approx(dev["busy_s"], rel=1e-6)
     assert dev["busy_s"] <= (dev["last_ns"] - dev["first_ns"]) / 1e9
-    gaps = xplane.idle_gaps(dev["busy_intervals_ns"], dev["first_ns"],
-                            dev["last_ns"])
-    idle = sum(b - a for a, b in gaps) / 1e9
-    assert idle + dev["busy_s"] == pytest.approx(
-        (dev["last_ns"] - dev["first_ns"]) / 1e9, rel=1e-9)
     top_ops = [n.split(" ")[0] for n, _t in dev["ops"][:3]]
     assert "%sort.12" in top_ops and "%reshape.18" in top_ops
 
 
-def test_union_self_time_gaps_and_attribution():
+def test_union_and_self_time():
     plane = {"name": "/device:TPU:0", "lines": [
         {"name": "XLA Modules", "events": [["jit_step(7)", 0, 100],
                                            ["jit_step(9)", 200, 100],
@@ -59,25 +54,14 @@ def test_union_self_time_gaps_and_attribution():
                                        ["b", 50, 40], ["c", 150, 10],
                                        ["a", 200, 100]]}]}
     dev = xplane.summarize_plane(plane)
-    assert dev["busy_intervals_ns"] == [[0, 100], [150, 160], [200, 300]]
+    # busy 0..100, 150..160, 200..300
     assert dev["busy_s"] == pytest.approx(210e-9)
+    assert (dev["first_ns"], dev["last_ns"]) == (0, 300)
     assert dev["modules"]["jit_step"] == {
-        "count": 2, "seconds": pytest.approx(200e-9),
-        "intervals_ns": [[0, 100], [200, 300]]}
+        "count": 2, "seconds": pytest.approx(200e-9)}
     own = dict(dev["ops"])
     assert own["while"] == pytest.approx(30e-9)      # 100 - 30 - 40
     assert own["a"] == pytest.approx(130e-9)
-    gaps = xplane.idle_gaps(dev["busy_intervals_ns"], 0, 320)
-    assert gaps == [[100, 150], [160, 200], [300, 320]]
-    idle = dict(xplane.attribute_gaps(
-        gaps, [["prep", 90, 140], ["fetch", 170, 400]]))
-    assert idle == {"prep": pytest.approx(40e-9),
-                    "fetch": pytest.approx(50e-9),
-                    "outside_dispatch": pytest.approx(20e-9)}
-    # clocks: the host's execute stages end with the step's executions
-    assert xplane.clock_offset_ns([[0, 100], [200, 300]],
-                                  [[1000, 1105], [1190, 1303]]) == 1005
-    assert xplane.clock_offset_ns([[0, 100]], []) is None
 
 
 def test_read_planes_reads_a_profiler_file(tmp_path):

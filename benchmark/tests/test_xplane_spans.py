@@ -91,6 +91,27 @@ def test_execute_host_takes_both_step_executions_of_a_dispatch(summary):
         (pytest.approx((4000 - 1400 - 1600) * U), 1)
 
 
+def test_execute_host_leaves_out_the_other_dispatchs_step():
+    """Two dispatches in flight (PR 26): A's ``batch[execute]`` 100..400
+    holds its own step 120..300 and waits out 100 units of B's (300..500);
+    B's 150..600 holds its own and, from 150 to 300, A's. Own steps alone
+    would leave A 120 and B 250 units; no step at all runs in A's for 20
+    (100..120) and in B's for 100 (500..600)."""
+    def disp(seq, a, b):
+        st = {"seq": seq, "kernel": "knn_exact", "requests": 8, "b_pad": 8}
+        return {"spans": [["batch[execute]", a * 1e4, (b - a) * 1e4, st]],
+                "launches": []}
+    raw = {"host": [disp(1, 100, 400), disp(2, 150, 600)],
+           "devices": [{"name": "/device:TPU:0", "modules": [
+               ["jit_knn_exact(3)", 120 * 1e4, 180 * 1e4],
+               ["jit_knn_exact(3)", 300 * 1e4, 200 * 1e4]], "ops": []}]}
+    s = json.loads(json.dumps(xs.reduce(raw)))
+    assert [[a / 1e4, b / 1e4] for a, b in s["step_intervals"]] == \
+        [[120, 500]]
+    assert [len(d["steps"]) for d in s["dispatches"].values()] == [1, 1]
+    assert xs.execute_host_ms(s) == (pytest.approx((20 + 100) / 2 * U), 2)
+
+
 def test_self_time_is_duration_less_what_children_cover(summary):
     st = summary["span_stats"]
     # request spans, by parent link: shard[knn] 5300 long; its child
@@ -172,7 +193,10 @@ def test_recorded_dispatch_from_the_chip():
     assert (d["kernel"], d["requests"], d["b_pad"]) == ("knn_exact", 2, 2)
     # one execution of the step inside batch[execute]: 36.98 of 65.26 ms
     assert len(d["steps"]) == 1
-    assert xs.execute_host_ms(s) == (pytest.approx(28.278276), 1)
+    # 28.278276 ms of it with the step not running, 17.858504 with no
+    # program at all on the device (the request threads' eager programs
+    # of that tree ran for the other 10.4)
+    assert xs.execute_host_ms(s) == (pytest.approx(17.858504), 1)
     assert s["modules"]["jit_knn_exact"]["count"] == 1
     p = _metric("kernels.knn_scores_ms")["params"]
     # 13.513688 under knn_exact/scores, 8.691714 the loop's own block
@@ -466,7 +490,7 @@ def test_traced_rehearsal_reports_the_span_metrics(rehearsal_manifest):
     for name in ("planes.execute_host_ms", "kernels.knn_scores_ms",
                  "kernels.knn_topk_ms"):
         assert name not in got
-    assert "rest.outside_batcher_ms" in got          # the old ones stay
+    assert "batcher.mean_batch" in got               # the old ones stay
     # at least 95 % of the answered requests joined edge to edge
     line = next(s for s in said if " joined from http[in]" in s)
     share = float(line.split("(")[1].split(" %")[0])
