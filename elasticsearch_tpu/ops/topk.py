@@ -2,11 +2,14 @@
 
 Replaces Lucene's ``TopScoreDocCollector`` heap
 (reference: ``search/query/TopDocsCollectorContext.java:215``) with
-``jax.lax.top_k`` over the dense per-segment score array. For large segments a
-two-stage blockwise top-k cuts the sort cost: per-block top-k on the VPU, then
-a final top-k over the small candidate set. Tie-break matches Lucene's
-ascending-doc-id order because ``lax.top_k`` selects the lowest index among
-equal values and block candidates are laid out in doc-id order.
+``jax.lax.top_k`` over the dense per-segment score array. On the TPU
+``lax.top_k`` is a full sort of its row, so wide rows go through an exact
+*selection* first (:func:`batched_blockwise_topk`): the maxima of
+contiguous groups of columns choose the k groups that can hold a winner,
+and only those groups are sorted. Tie-break matches Lucene's
+ascending-doc-id order because ``lax.top_k`` selects the lowest position
+among equal values and both the groups and the candidates gathered from
+them stay in doc-id order.
 """
 
 from __future__ import annotations
@@ -18,60 +21,73 @@ from . import in_named_scope
 
 NEG_INF = float("-inf")
 
-_BLOCK = 16384          # scores per block in the two-stage path
-_BLOCKWISE_MIN = 1 << 17  # use the two-stage path above this many docs
+#: rows narrower than this are sorted whole: on the chip (PERF.md §6, PR 31)
+#: the selection saves most of a 16,384-wide sort and loses on a 4,096-wide
+_SELECT_MIN = 1 << 14
 
 
 def _topk_kernel(n: int, k: int):
-    use_blocks = n >= _BLOCKWISE_MIN and n % _BLOCK == 0 and k <= _BLOCK
-
     @in_named_scope("topk")
     def topk_kernel(scores, mask):
         """scores float32[n]; mask bool[n] (False → excluded). Returns
         (values float32[k], indices int32[k]); excluded slots carry -inf."""
         masked = jnp.where(mask, scores, NEG_INF)
-        if use_blocks:
-            # one algorithm, one implementation: the batched helper's
-            # tie-break argument (block-major candidates + top_k's
-            # lowest-index preference) covers the 1-D case as its B=1
-            # slice
-            vals, idx = batched_blockwise_topk(masked[None], k,
-                                               block=_BLOCK)
-            return vals[0], idx[0]
-        vals, idx = jax.lax.top_k(masked, k)
-        return vals, idx.astype(jnp.int32)
+        # one algorithm, one implementation: the 1-D case is the batched
+        # selection's B=1 slice
+        vals, idx = batched_blockwise_topk(masked[None], k)
+        return vals[0], idx[0]
 
     return jax.jit(topk_kernel)
 
 
-@in_named_scope("batched_blockwise_topk")
-def batched_blockwise_topk(scores, k: int, block: int = _BLOCK):
-    """Exact top-k over the last axis of ``scores`` [B, n] via the
-    two-stage blockwise path: per-block ``top_k`` then a final ``top_k``
-    over the B × (n/block)·k candidate set.  ``lax.top_k`` cost grows
-    with the sorted width, so two narrow selections beat one over n
-    (the same trade ops/topk.py's 1-D kernel makes; this is the batched
-    form the kNN einsum and the dense-tier scan need).
+def _group_width(n: int, k: int) -> int:
+    """Columns per group for a row of ``n`` scores of which ``k`` are
+    kept, or 0 where the row is sorted whole. The smallest power of two
+    at or above sqrt(n / k), which makes the two sorts (n/g maxima, k·g
+    candidates) about equally wide; it has to divide the row, and the
+    candidates have to be at most a quarter of it. A function of the
+    shape alone, so it is decided while tracing."""
+    if n < _SELECT_MIN:
+        return 0
+    g = 1
+    while g * g * k < n:
+        g *= 2
+    return g if n % g == 0 and k * g <= n // 4 else 0
 
-    Exact: any global top-k element is inside its own block's top-k
-    (k ≤ block).  Tie-break stays ascending-index: candidates are laid
-    out block-major, within a block ``top_k`` puts the lowest index
-    first among equals, and the final ``top_k`` picks the lowest
-    candidate position among equals — which is the earlier block.
-    Falls back to plain ``top_k`` when the shape doesn't block."""
-    n = scores.shape[-1]
-    if n % block or n < 2 * block or k > block:
+
+@in_named_scope("batched_blockwise_topk")
+def batched_blockwise_topk(scores, k: int):
+    """Exact top-k over the last axis of ``scores`` [B, n], without
+    sorting the row: (1) the maximum of each contiguous group of g
+    columns (:func:`_group_width`), (2) ``top_k`` over the n/g maxima
+    picks k groups, taken in ascending order, (3) those groups are
+    gathered, [B, k·g] candidates in column order, (4) ``top_k`` over the
+    candidates.
+
+    Exact, ties and -inf padding included. Order elements by (value
+    descending, column ascending). If x of the true top-k sat in a group
+    that was not picked, each of the k picked groups holds a maximum that
+    is larger than x, or equal to x in a group of lower index (groups are
+    contiguous and ``top_k`` prefers the lowest position): k elements
+    come before x, a contradiction. The candidates are in column order,
+    so the last ``top_k``'s lowest-position preference is the lowest
+    column: values AND indices equal ``lax.top_k(scores, k)``."""
+    B, n = scores.shape
+    g = _group_width(n, k)
+    if not g:
         vals, idx = jax.lax.top_k(scores, min(k, n))
         return vals, idx.astype(jnp.int32)
-    nb = n // block
-    blocks = scores.reshape(scores.shape[0], nb, block)
-    bv, bi = jax.lax.top_k(blocks, k)                # [B, nb, k]
-    base = (jnp.arange(nb, dtype=jnp.int32) * block)[None, :, None]
-    cand_idx = (bi.astype(jnp.int32) + base).reshape(
-        scores.shape[0], nb * k)
-    cand_vals = bv.reshape(scores.shape[0], nb * k)
-    vals, sel = jax.lax.top_k(cand_vals, k)
-    idx = jnp.take_along_axis(cand_idx, sel, axis=1)
+    groups = scores.reshape(B, n // g, g)
+    with jax.named_scope("group_max"):
+        gmax = jnp.max(groups, axis=-1)
+    with jax.named_scope("select"):
+        _, gid = jax.lax.top_k(gmax, k)
+        gid = jnp.sort(gid, axis=-1)
+    with jax.named_scope("gather"):
+        cand = jnp.take_along_axis(groups, gid[:, :, None], axis=1)
+    with jax.named_scope("final"):
+        vals, sel = jax.lax.top_k(cand.reshape(B, k * g), k)
+        idx = jnp.take_along_axis(gid, sel // g, axis=1) * g + sel % g
     return vals, idx
 
 

@@ -223,21 +223,23 @@ def test_cardinality_exact_and_hll_regimes(searcher, monkeypatch):
 
 
 def test_batched_blockwise_topk_exact():
-    """blockwise two-stage top-k is bit-identical to plain lax.top_k,
-    including boundary shapes and ascending-index tie-break."""
+    """the group-maxima selection is bit-identical to plain lax.top_k,
+    including boundary shapes and ascending-index tie-break
+    (tests/test_topk_selection.py holds the property test)."""
     import jax.numpy as jnp
     from jax import lax
     from elasticsearch_tpu.ops.topk import batched_blockwise_topk
 
     rng = np.random.RandomState(3)
-    for B, n, k, block in ((2, 4096, 100, 512), (1, 1024, 10, 512),
-                           (3, 512, 600, 512),   # k > block: fallback
-                           (2, 1000, 5, 512),    # n % block: fallback
-                           (1, 512, 5, 512)):    # n < 2*block: fallback
+    for B, n, k in ((2, 65536, 100), (1, 16384, 10),
+                    (2, 4096, 100),    # k groups pass a quarter: sorted whole
+                    (3, 512, 600),     # k > n
+                    (2, 1000, 5),      # no group divides n
+                    (1, 512, 5)):
         scores = jnp.asarray(
             rng.randint(0, 50, (B, n)).astype(np.float32))
         want_v, want_i = lax.top_k(scores, min(k, n))
-        got_v, got_i = batched_blockwise_topk(scores, k, block=block)
+        got_v, got_i = batched_blockwise_topk(scores, k)
         np.testing.assert_array_equal(np.asarray(want_v),
                                       np.asarray(got_v))
         # heavy ties (values 0..49 over 4096 slots): index agreement

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticsearch_tpu.parallel import (DistributedKnnPlane, build_knn_step,
                                         make_search_mesh, prepare_knn_corpus)
+from elasticsearch_tpu.parallel.dist_search import KNN_BLOCK
 from elasticsearch_tpu.parallel.mesh import AXIS_REPLICA, AXIS_SHARD
 
 SIMS = ("dot_product", "cosine", "l2_norm")
@@ -188,6 +189,33 @@ def test_knn_step_trace_has_no_corpus_normalization(similarity):
                 offenders.append((eqn.primitive.name, aval.shape))
     assert not offenders, (
         f"corpus-side normalization leaked into the knn trace: {offenders}")
+
+
+def test_knn_step_scan_sorts_at_most_a_quarter_block_per_query():
+    """Ratchet for the top-k selection (``ops/topk.py``): at the served
+    shape (GloVe: n_pad 2^21, dim 100, k 100, B 32; shapes only, nothing
+    is allocated) the ``top_k`` equations inside the scan body together
+    take at most block / 4 elements per query. ``top_k`` is a full sort
+    of its operand on the TPU; a step that sorts every score of a block
+    (4 x 16,384 + 400 + 200 per query before the selection) fails."""
+    n_pad, dim, k, B = 1 << 21, 100, 100, 32
+    mesh = make_search_mesh(n_shards=1, n_replicas=1)
+    step = build_knn_step(mesh, n_pad=n_pad, dim=dim, k=k, n_shards=1,
+                          similarity="cosine")
+    sds = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(step)(
+        sds((1, n_pad, dim), np.float32), sds((1, n_pad), np.float32),
+        sds((1, n_pad), bool), sds((B, dim), np.float32))
+    eqns = []
+    _collect_eqns(closed, eqns)
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1, "the step is one scan over the corpus blocks"
+    body = []
+    _collect_eqns(scans[0].params["jaxpr"], body)
+    sorted_per_query = [int(np.prod(e.invars[0].aval.shape)) // B
+                        for e in body if e.primitive.name == "top_k"]
+    assert sorted_per_query, "no top_k found in the scan body"
+    assert sum(sorted_per_query) <= KNN_BLOCK // 4, sorted_per_query
 
 
 # ---------------------------------------------------------------------------
