@@ -256,12 +256,11 @@ class HealthService:
                 doc["diagnosis"].append(_diagnosis(
                     "plane_serving:sync_rebuilds",
                     "Refreshes are invalidating serving planes faster "
-                    "than the background repack absorbs them, or "
-                    "delta-tier serving is disabled (ES_TPU_PLANE_DELTA"
-                    "=0).",
-                    "Re-enable delta serving, raise "
-                    "ES_TPU_PLANE_DELTA_FRACTION, or lower the refresh "
-                    "rate; watch es_plane_rebuild_total{mode=\"sync\"}.",
+                    "than the background repack absorbs them (merges "
+                    "and deletes restructure the base; appends past "
+                    "1/8 of it trigger a repack).",
+                    "Lower the refresh rate or batch the writes; watch "
+                    "es_plane_rebuild_total{mode=\"sync\"}.",
                     {"indices": sorted(per_index)}))
             if ann_drift > 0:
                 doc["impacts"].append(_impact(

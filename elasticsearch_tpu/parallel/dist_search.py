@@ -1879,6 +1879,15 @@ def _plane_cached_step(self, key: Tuple, builder, site: str):
     return fn
 
 
+def _padded_batches(mesh: Mesh, max_b: int) -> List[int]:
+    """The padded batches the micro-batcher dispatches: powers of two up
+    to ``max_b``, each rounded up to a multiple of the mesh's replica
+    axis (which partitions the batch)."""
+    n_repl = mesh.shape[AXIS_REPLICA]
+    return sorted({-(-(1 << i) // n_repl) * n_repl
+                   for i in range(max(max_b, 1).bit_length())})
+
+
 class DistributedSearchPlane:
     """Packs per-shard postings into mesh-sharded device arrays and runs
     batched distributed searches.
@@ -1894,6 +1903,9 @@ class DistributedSearchPlane:
     DENSE_BLOCK = 1 << 19
     #: dense-tier row budget per shard (memory cap: T × n_pad × 2B each)
     MAX_DENSE_TERMS = 256
+    #: largest padded batch whose bag dispatch still picks a short L rung
+    #: (see :meth:`serving_shape`, which has the figures)
+    SHORT_RUNG_MAX_B = 2
 
     def __init__(self, mesh: Mesh, shards: Sequence[dict], field: str,
                  *, k1: float = DEFAULT_K1, b: float = DEFAULT_B,
@@ -2383,7 +2395,8 @@ class DistributedSearchPlane:
         """Longest sparse-tier posting run any of these queries touches
         — the minimal safe L.  Cheap (dict probes + offset diffs only;
         none of _lookup's array assembly), for callers sizing a shared
-        compile shape across a workload."""
+        compile shape across a workload; the bag route walks it only
+        for a padded batch of at most :attr:`SHORT_RUNG_MAX_B`."""
         out = 1
         for terms in queries:
             for t in set(terms):
@@ -2401,41 +2414,35 @@ class DistributedSearchPlane:
 
     def ladder_rungs(self) -> List[int]:
         """The fixed 4-step geometric L ladder (L_cap, L_cap/8, L_cap/64,
-        L_cap/512 floored at 1024) — the serving compile-shape lattice's
-        L axis (:meth:`ladder_L` picks from these; warmup pre-compiles
-        them)."""
+        L_cap/512 floored at 1024): the L axis of the bool and fused
+        routes, and of the bag route's smallest padded batches
+        (:meth:`serving_shape`); :meth:`ladder_L` picks from these."""
         return sorted({max(1024, self.L_cap >> s) for s in (9, 6, 3, 0)})
 
     def ladder_L(self, needed: int) -> int:
-        """Smallest ladder rung ≥ needed.  Serving uses this instead of
-        raw pow2 buckets: at most 4 sparse-merge compile shapes per
-        (B, Q, k) family instead of ~log2(L_cap), while ordinary
+        """Smallest ladder rung ≥ needed: at most 4 sparse-merge compile
+        shapes per (B, Q, k) family instead of ~log2(L_cap), while
         short-run batches still skip the worst-case merge cost."""
         for r in self.ladder_rungs():
             if needed <= r:
                 return r
         return self.L_cap
 
-    def _dense_inputs(self, idfw, dense_rid, dense_hit):
-        """Slot-space dense-tier inputs for one batch: pick the used-row
-        gather width U (pow2-bucketed for compile-cache stability), build
-        ``u_ids`` i32[S, U] (the batch's used rows per shard), the
-        slot-indexed per-candidate (rid, w) pairs, and the slot-space
-        weight matrix W f32[B, S, U]. When the batch uses most of the
-        dense tier, U collapses to T_pad and u_ids is a dummy (the step
-        streams the full block array, no gather)."""
+    def _dense_rows(self, dense_rid, dense_hit) -> List[np.ndarray]:
+        """The distinct dense-tier rows a batch touches, per shard."""
+        return [np.unique(dense_rid[:, si, :][dense_hit[:, si, :]])
+                for si in range(self.n_shards)]
+
+    def _dense_inputs(self, idfw, dense_rid, dense_hit, u_lists, U: int):
+        """Slot-space dense-tier inputs for one batch at the caller's
+        gather width ``U``: ``u_ids`` i32[S, U]
+        (the batch's used rows per shard, ``u_lists``; unused slots carry
+        zero weight everywhere), the slot-indexed per-candidate (rid, w)
+        pairs, and the slot-space weight matrix W f32[B, S, U]. At
+        ``U == T_pad`` u_ids is a dummy (the step streams the full block
+        array, no gather)."""
         B, S = dense_hit.shape[0], self.n_shards
         T = self.T_pad
-        u_lists = [np.unique(dense_rid[:, si, :][dense_hit[:, si, :]])
-                   for si in range(S)]
-        max_used = max((r.size for r in u_lists), default=0)
-        U = min(T, max(16, round_up_pow2(max(max_used, 1))))
-        # the one-third bar dates from a gather that wrote a working set
-        # and re-read it (~3x the U rows through HBM); blocks are now
-        # narrowed as they stream, so the bar is conservative — left where
-        # it is until a chip run measures the row-gathered stream
-        if 3 * U > T:
-            U = T
         if U < T:
             u_ids = np.zeros((S, U), np.int32)
             rid_out = np.zeros_like(dense_rid)
@@ -2446,7 +2453,6 @@ class DistributedSearchPlane:
                     rid_out[bi_ix, si, qi_ix] = np.searchsorted(
                         rows, dense_rid[bi_ix, si, qi_ix]).astype(np.int32)
         else:
-            U = T
             u_ids = np.zeros((S, 1), np.int32)
             rid_out = dense_rid
         dense_w = np.where(dense_hit, idfw[:, None, :], 0.0) \
@@ -2456,12 +2462,98 @@ class DistributedSearchPlane:
         if bi_ix.size:
             np.add.at(W, (bi_ix, si_ix, rid_out[bi_ix, si_ix, qi_ix]),
                       idfw[bi_ix, qi_ix])
-        return U, u_ids, rid_out, dense_w, W
+        return u_ids, rid_out, dense_w, W
+
+    # -- the serving list: which programs a served bag batch runs ----------
 
     #: serving Q floor: dispatches through :meth:`serve` never trace a Q
     #: below this, collapsing the Q shape axis (1..8-unique-term queries
     #: all share one compile) at negligible host-assembly cost
     SERVING_Q_MIN = 8
+
+    def serving_q(self, queries: Sequence[Sequence[str]]) -> int:
+        """Q of a served bag batch: :attr:`SERVING_Q_MIN`, or the next
+        power of two at or above the most distinct terms any query
+        holds — the one axis of the serving list a batch's bags can
+        still open (a query of more than 8 distinct terms)."""
+        return max(self.SERVING_Q_MIN, round_up_pow2(max(
+            max((len(set(q)) for q in queries), default=1), 1)))
+
+    def serving_shape(self, b_pad: int, k_bucket: int,
+                      Q: Optional[int] = None,
+                      run_len: Optional[int] = None) -> Tuple:
+        """THE decision "which program does a served bag batch run": the
+        ``_get_step`` key ``(Q, L, k, tiered, with_count, U)`` of a batch
+        padded to ``b_pad`` (the jitted step retraces on the batch
+        dimension, so a program is the pair). A function of its
+        arguments and of constants fixed at pack time (``L_cap``,
+        ``T_pad``, the mesh), not of the batch's bags. Figures: one
+        v5e chip, 2^21 docs of a Zipf corpus at MS MARCO's parameters
+        (T_pad 256, L_cap 32768), ``jit_bm25_tiered``'s device ms a step
+        (PERF.md §6, PR 32).
+
+        - **L** is ``L_cap``: from five requests a batch on, 98 % of
+          batches hold a long sparse run, and the ladder's rungs opened
+          three programs a (B, k) for the rest. Only a padded batch of
+          at most :attr:`SHORT_RUNG_MAX_B` keeps the ladder, by
+          ``run_len`` (its longest sparse run): at B_pad 1 a step reads
+          10.5 / 22.8 / 152.5 ms at L 1024 / 4096 / 32768, and 46 % of
+          single requests (20 % of pairs) fit a short rung.
+        - **U** is ``T_pad``: the whole dense tier streams, no gather.
+          The width never showed in the step: at B_pad 8, 1238.4 /
+          1239.1 / 1239.9 / 1241.9 ms gathered at U 16 / 32 / 64 / 128
+          and 1233.1 streamed (B_pad 1: 152.5-156.7 against 148.3; 16
+          and 32 alike), while a width read from the bags sat on a
+          boundary between two programs at every batch size.
+        - **Q** is :attr:`SERVING_Q_MIN` unless given (:meth:`serving_q`).
+        - ``with_count`` is True: the batcher always asks for totals.
+
+        :meth:`serve` runs only what this returns, and the batcher's
+        warm-up compiles :meth:`serving_shapes`, which calls it."""
+        Q = self.SERVING_Q_MIN if Q is None else Q
+        L = self.L_cap
+        if run_len is not None and b_pad <= self.SHORT_RUNG_MAX_B:
+            L = min(self.ladder_L(run_len), self.L_cap)
+        if self.T_pad:
+            return Q, L, k_bucket, True, True, self.T_pad
+        return Q, L, k_bucket, False, True, None
+
+    def serving_shapes(self, k_buckets: Sequence[int],
+                       max_b: int) -> List[Tuple]:
+        """Every program the bag route serves at Q =
+        :attr:`SERVING_Q_MIN`, as ``(b_pad, key)``: the micro-batcher's
+        padded batches up to ``max_b`` x ``k_buckets``. The same list
+        from a plane rebuilt by ``from_packed(export_packed())``."""
+        out: List[Tuple] = []
+        for b_pad in _padded_batches(self.mesh, max_b):
+            runs = self.ladder_rungs() \
+                if b_pad <= self.SHORT_RUNG_MAX_B else [None]
+            for kb in sorted(set(k_buckets)):
+                for run_len in runs:
+                    shape = (b_pad, self.serving_shape(b_pad, kb,
+                                                       run_len=run_len))
+                    if shape not in out:
+                        out.append(shape)
+        return out
+
+    def warm_shape(self, shape: Tuple) -> None:
+        """Compile (or load from the cache) and run one member of
+        :meth:`serving_shapes` on inert queries."""
+        b_pad, (Q, L, k, tiered, with_count, U) = shape
+        self.search([[]] * b_pad, k=k, Q=Q, L=L, U=U, tiered=tiered,
+                    with_totals=with_count)
+
+    def _serve_listed(self, queries: Sequence[Sequence[str]], k: int,
+                      **kw):
+        """The jitted bag dispatch at its :meth:`serving_shape`."""
+        n_repl = self.mesh.shape[AXIS_REPLICA]
+        b_pad = -(-len(queries) // n_repl) * n_repl
+        run_len = self.max_run_len(queries) \
+            if b_pad <= self.SHORT_RUNG_MAX_B else None
+        Q, L, _k, tiered, _wc, U = self.serving_shape(
+            b_pad, k, self.serving_q(queries), run_len)
+        return self.search(queries, k=k, Q=Q, L=L, U=U, tiered=tiered,
+                           **kw)
 
     def serve(self, queries: Sequence[Sequence[str]], k: int = 10,
               *, with_totals: bool = False,
@@ -2471,11 +2563,12 @@ class DistributedSearchPlane:
         """Serving entry (the micro-batcher's dispatch hook): the
         CPU-native eager scorer when this plane was built on a CPU
         backend — term-at-a-time over precomputed impacts compiles
-        nothing and beats XLA:CPU — else the jitted step at the stable
-        serving shapes: ladder-rung L, Q floored to SERVING_Q_MIN, so
-        live traffic only ever hits the pre-warmed (B, Q, L, k)
-        lattice. ``extra_docs``/``extra_df`` fold a delta tier's corpus
-        mass into the idf weights (see :meth:`_lookup`).
+        nothing and beats XLA:CPU — else the jitted step at the shape
+        :meth:`serving_shape` states for the padded batch, a member of
+        the list the batcher's warm-up compiles
+        (:meth:`serving_shapes`). ``extra_docs``/``extra_df`` fold a
+        delta tier's corpus mass into the idf weights (see
+        :meth:`_lookup`).
 
         ``prune``: block-max pruned scan (rank-safe — results are
         bit-identical to the eager scan; under an early exit the totals
@@ -2495,33 +2588,28 @@ class DistributedSearchPlane:
         # stays available — it touches no device memory.
         warm_stream = self.storage_tier != "hot" and self._host_csr is None
         if self.blockmax is not None and prune is not False \
-                and not warm_stream:
-            needed_q = max(self.SERVING_Q_MIN, round_up_pow2(max(
-                max((len(set(q)) for q in queries), default=1), 1)))
-            if k * needed_q <= LEX_THETA_WINDOW:
-                if self._host_csr is not None:
-                    return self.search_pruned_eager(
-                        queries, k=k, with_totals=with_totals,
-                        stages=stages, extra_docs=extra_docs,
-                        extra_df=extra_df)
-                return self.search_pruned(
-                    queries, k=k, with_totals=with_totals, stages=stages,
-                    extra_docs=extra_docs, extra_df=extra_df)
+                and not warm_stream \
+                and k * self.serving_q(queries) <= LEX_THETA_WINDOW:
+            if self._host_csr is not None:
+                return self.search_pruned_eager(
+                    queries, k=k, with_totals=with_totals,
+                    stages=stages, extra_docs=extra_docs,
+                    extra_df=extra_df)
+            return self.search_pruned(
+                queries, k=k, with_totals=with_totals, stages=stages,
+                extra_docs=extra_docs, extra_df=extra_df)
         if self._host_csr is not None:
             return self.search_eager(queries, k=k,
                                      with_totals=with_totals, stages=stages,
                                      extra_docs=extra_docs,
                                      extra_df=extra_df)
-        L = self.ladder_L(self.max_run_len(queries))
-        needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
-        Q = max(self.SERVING_Q_MIN, round_up_pow2(needed_q))
-        return self.search(queries, k=k, Q=Q, L=L,
-                           tiered=self.T_pad > 0 or None,
-                           with_totals=with_totals, stages=stages,
-                           extra_docs=extra_docs, extra_df=extra_df)
+        return self._serve_listed(queries, k, with_totals=with_totals,
+                                  stages=stages, extra_docs=extra_docs,
+                                  extra_df=extra_df)
 
     def search(self, queries: Sequence[Sequence[str]], k: int = 10,
                *, Q: Optional[int] = None, L: Optional[int] = None,
+               U: Optional[int] = None,
                tiered: Optional[bool] = None, with_totals: bool = False,
                stages: Optional[dict] = None, extra_docs: int = 0,
                extra_df: Optional[Dict[str, int]] = None):
@@ -2529,6 +2617,12 @@ class DistributedSearchPlane:
         (scores f32[B, k], hits list[list[(shard, local_doc)]]) — plus
         exact per-query match counts (list[int], the device-side
         TotalHitCountCollector) when ``with_totals``.
+
+        ``Q`` / ``L`` / ``U`` (slots a query, merge tile, dense-tier
+        width): None sizes each to the smallest shape this batch's bags
+        need — what the kernel tests and the plain references call, and
+        the reference :meth:`serve`'s stated shapes are compared with;
+        a given value too small for the batch raises.
 
         ``tiered``: None (default) picks the tiered kernel iff the batch
         touches a dense-tier term; True forces the tiered kernel whenever a
@@ -2578,8 +2672,18 @@ class DistributedSearchPlane:
                     "tiered=False but the batch hits dense-tier terms")
             docs_dev, impacts_dev, dense_dev, stream_b = self._corpus_refs()
             if use_tiered:
-                U, u_ids, rid_slots, dense_w, W = self._dense_inputs(
-                    idfw, dense_rid, dense_hit)
+                u_lists = self._dense_rows(dense_rid, dense_hit)
+                max_used = max((r.size for r in u_lists), default=0)
+                if U is None:
+                    U = min(self.T_pad,
+                            max(16, round_up_pow2(max(max_used, 1))))
+                elif U < min(max_used, self.T_pad):
+                    raise ValueError(
+                        f"U={U} would drop dense rows from a batch that "
+                        f"touches {max_used}; pass U=None to size "
+                        f"automatically")
+                u_ids, rid_slots, dense_w, W = self._dense_inputs(
+                    idfw, dense_rid, dense_hit, u_lists, U)
                 step = self._get_step(Q, L, k, tiered=True,
                                       with_count=with_totals, U=U)
                 shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
@@ -3174,13 +3278,8 @@ class DistributedSearchPlane:
             # warm plane: the block-max device tier was dropped on
             # demotion and the corpus streams per dispatch anyway —
             # serve through the (rank-identical) streamed eager scan
-            return self.search(
-                queries, k=k,
-                Q=max(self.SERVING_Q_MIN, round_up_pow2(max(
-                    max((len(set(q)) for q in queries), default=1), 1))),
-                L=self.ladder_L(self.max_run_len(queries)),
-                tiered=self.T_pad > 0 or None,
-                with_totals=with_totals, stages=stages,
+            return self._serve_listed(
+                queries, k, with_totals=with_totals, stages=stages,
                 extra_docs=extra_docs, extra_df=extra_df)
         with _tracing.Phases() as phases:
             phases.enter("plane[h2d]")
@@ -3191,23 +3290,18 @@ class DistributedSearchPlane:
             n_repl = self.mesh.shape[AXIS_REPLICA]
             B_pad = -(-B // n_repl) * n_repl
             queries = list(queries) + [[] for _ in range(B_pad - B)]
-            needed_q = max(max((len(set(q)) for q in queries), default=1), 1)
-            Q = max(self.SERVING_Q_MIN, round_up_pow2(needed_q))
+            Q = self.serving_q(queries)
             (starts, lengths, idfw, _rid, dense_hit, _ml,
              any_dense) = self._lookup(queries, Q, extra_docs=extra_docs,
                                        extra_df=extra_df)
             if any_dense:
                 # Zipf-head terms live in the dense streaming-matmul tier —
-                # already the device's fast path for exactly those postings.
-                # Dispatch at the pre-warmed serving shapes (ladder L, Q
-                # floor): a raw pow2 L here would compile off-lattice
-                # mid-traffic
-                return self.search(queries[:B], k=k, tiered=True, Q=Q,
-                                   L=self.ladder_L(
-                                       self.max_run_len(queries[:B])),
-                                   with_totals=with_totals,
-                                   stages=stages, extra_docs=extra_docs,
-                                   extra_df=extra_df)
+                # already the device's fast path for exactly those postings:
+                # the bag route's own dispatch, at its stated shape
+                return self._serve_listed(
+                    queries[:B], k, with_totals=with_totals,
+                    stages=stages, extra_docs=extra_docs,
+                    extra_df=extra_df)
             S = self.n_shards
             NB = tier.n_blocks
             P_need = 1
@@ -3303,14 +3397,12 @@ class DistributedSearchPlane:
                 bad_q = [queries[i] for i in bad]
                 # pad to a power of two like the micro-batcher does: a raw
                 # count of unsafe queries would compile one eager program per
-                # distinct count, off the (B-pow2 x k x L-rung) lattice
+                # distinct count, off the serving list
                 bad_q += [[] for _ in range(
                     round_up_pow2(len(bad_q), 1) - len(bad_q))]
-                ev = self.search(bad_q, k=k, Q=Q,
-                                 L=self.ladder_L(self.max_run_len(bad_q)),
-                                 tiered=self.T_pad > 0 or None,
-                                 with_totals=True, extra_docs=extra_docs,
-                                 extra_df=extra_df)
+                ev = self._serve_listed(bad_q, k, with_totals=True,
+                                        extra_docs=extra_docs,
+                                        extra_df=extra_df)
                 for j, i in enumerate(bad):
                     src = np.asarray(ev[0][j], np.float32)[:k]
                     vals_out[i] = NEG_INF
@@ -3972,6 +4064,27 @@ class DistributedKnnPlane:
         return self.search(query_vectors, k=k, stages=stages)
 
     cached_step = _plane_cached_step
+
+    def serving_shapes(self, k_buckets: Sequence[int],
+                       max_b: int) -> List[Tuple]:
+        """The exact scan's programs, ``(b_pad, key)`` with ``key`` the
+        ``_get_step`` key ``(k,)``: the micro-batcher's padded batches
+        up to ``max_b`` x ``k_buckets``. The IVF step's shapes follow
+        the probe (its union width) and are not stated."""
+        return [(b_pad, (kb,))
+                for b_pad in _padded_batches(self.mesh, max_b)
+                for kb in sorted(set(k_buckets))]
+
+    def warm_shape(self, shape: Tuple) -> None:
+        """Compile (or load from the cache) and run one member of
+        :meth:`serving_shapes` on zero vectors; with an IVF tier, its
+        default dispatch at the same batch too (the program a zero
+        probe asks for, which live traffic may or may not share)."""
+        b_pad, (k,) = shape
+        q = np.zeros((b_pad, max(self.dim, 1)), np.float32)
+        self.search(q, k=k)
+        if self.ivf is not None:
+            self.serve(q, k=k)
 
     def _get_step(self, k: int):
         return self.cached_step(

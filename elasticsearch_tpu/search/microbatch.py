@@ -40,10 +40,13 @@ Observability: every request is stamped with per-stage timings — queue
 wait, host prep, device dispatch, result fetch — aggregated per batcher
 (totals for nodes stats, bounded sample rings for bench percentiles), so
 a serving regression is attributable to a stage instead of one opaque
-p99. :meth:`PlaneMicroBatcher.warmup` pre-compiles the serving shape
-lattice (B-pow2 × k-bucket × L-rung) off the serving path at plane-build
-time — a first-hit XLA compile landing mid-traffic is the classic
-multi-second p99 signature.
+p99. :meth:`PlaneMicroBatcher.warmup` pre-compiles, off the serving path
+at plane-build time, the list of programs the plane states it serves
+(``plane.serving_shapes``: for the text plane's bag route one program a
+(padded batch, k-bucket), because ``serve()`` picks its shape from that
+same list and never from the batch's bags; for the kNN plane its exact
+scans; none for the fused runner) — a first-hit XLA compile landing
+mid-traffic is the classic multi-second p99 signature.
 
 One batcher per serving GENERATION (``plane_route`` hands the batcher a
 generation object — packed base plane + append-only delta tier — whose
@@ -677,8 +680,9 @@ class PlaneMicroBatcher:
 
     def warmup(self, ks: Sequence[int] = (10,),
                max_b: Optional[int] = None, sync: bool = False):
-        """Pre-compile the serving shape lattice (B-pow2 × k-bucket ×
-        L-rung) so no first-hit XLA compile lands mid-traffic. Runs in a
+        """Pre-compile the plane's serving list (``plane.serving_shapes``
+        over padded batches up to ``max_b`` and the k-buckets of ``ks``)
+        so no first-hit XLA compile lands mid-traffic. Runs in a
         background thread by default (plane build must not block on
         minutes of compiles); ``sync=True`` blocks (tests). Host-serving
         planes (CPU backend → eager/BLAS paths) compile nothing and
@@ -753,24 +757,13 @@ class PlaneMicroBatcher:
         return getattr(self.plane, "_host_csr", None) is not None
 
     def _warm_lattice(self, ks, max_b):
-        """Thunks, one per (B, k-bucket, L-rung) serving shape."""
+        """Thunks, one per program the plane states it serves
+        (``plane.serving_shapes``): the plane owns the list and runs a
+        named member of it on inert queries (``plane.warm_shape``)."""
         plane = self.plane
-        rungs = plane.ladder_rungs() if hasattr(plane, "ladder_rungs") \
-            else [None]
         kbs = sorted({self._k_bucket(k) for k in ks})
-        # serving dispatches run at the plane's Q floor (serve() collapses
-        # the Q shape axis there) — warm that exact shape
-        qkw = {"Q": plane.SERVING_Q_MIN} \
-            if getattr(plane, "SERVING_Q_MIN", 0) else {}
-        b = 1
-        while b <= min(max_b, self.max_batch):
-            for kb in kbs:
-                for L in rungs:
-                    yield lambda B=b, kb=kb, L=L: plane.search(
-                        [self._pad_slot()] * B, k=kb, L=L,
-                        tiered=getattr(plane, "T_pad", 0) > 0 or None,
-                        with_totals=True, **qkw)
-            b <<= 1
+        for shape in plane.serving_shapes(kbs, min(max_b, self.max_batch)):
+            yield lambda shape=shape: plane.warm_shape(shape)
 
     # -- stats --------------------------------------------------------------
 
@@ -862,17 +855,12 @@ class PlaneMicroBatcher:
         serve = getattr(self.plane, "serve", None)
         if serve is not None:
             # the plane's serving entry picks the backend path (eager
-            # CSR scorer on CPU, ladder-shaped jitted step on TPU) and
-            # refines the stage timings
+            # CSR scorer on CPU, the jitted step at the plane's stated
+            # serving shape on TPU) and refines the stage timings
             return serve(queries, k=k, with_totals=True, stages=stages,
                          **kw)
-        # legacy/raw planes: size L through the ladder here
-        L = None
-        if hasattr(self.plane, "max_run_len"):
-            L = self.plane.ladder_L(self.plane.max_run_len(queries))
-        tiered = getattr(self.plane, "T_pad", 0) > 0 or None
-        return self.plane.search(queries, k=k, L=L, tiered=tiered,
-                                 with_totals=True)
+        # a plane with no serving entry (test doubles)
+        return self.plane.search(queries, k=k, with_totals=True)
 
 
 class KnnPlaneMicroBatcher(PlaneMicroBatcher):
@@ -905,25 +893,6 @@ class KnnPlaneMicroBatcher(PlaneMicroBatcher):
 
     def _serves_host(self) -> bool:
         return getattr(self.plane, "_host_pack", None) is not None
-
-    def _warm_lattice(self, ks, max_b):
-        plane = self.plane
-        kbs = sorted({self._k_bucket(k) for k in ks})
-        has_ivf = getattr(plane, "ivf", None) is not None
-        b = 1
-        while b <= min(max_b, self.max_batch):
-            for kb in kbs:
-                yield lambda B=b, kb=kb: plane.search(
-                    np.zeros((B, max(plane.dim, 1)), np.float32), k=kb)
-                if has_ivf:
-                    # the IVF serving default is its own compile family
-                    # ((nprobe, rerank, union-width) shapes); warm the
-                    # default knobs so the first pruned dispatch of each
-                    # B×k shape doesn't compile mid-traffic
-                    yield lambda B=b, kb=kb: plane.serve(
-                        np.zeros((B, max(plane.dim, 1)), np.float32),
-                        k=kb)
-            b <<= 1
 
     def _dispatch(self, queries, k: int,
                   stages: Optional[dict] = None, view=None, params=None):
@@ -981,12 +950,6 @@ class FusedPlaneMicroBatcher(PlaneMicroBatcher):
 
     def _serves_host(self) -> bool:
         return self.plane.serves_host()
-
-    def _warm_lattice(self, ks, max_b):
-        # fused shapes warm on first dispatch per shape; the lattice is
-        # bounded by (B-pow2 × plan shape) and the bench asserts zero
-        # steady-state compiles after that first window
-        return iter(())
 
     def _dispatch(self, queries, k: int, stages: Optional[dict] = None,
                   view=None, params=None):

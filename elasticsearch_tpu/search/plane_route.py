@@ -576,8 +576,7 @@ class ServingPlaneCache:
 
     #: delta-tier doc fraction (of the base generation's docs) above
     #: which a background repack folds the delta into a new base
-    REPACK_DELTA_FRACTION = float(os.environ.get(
-        "ES_TPU_PLANE_DELTA_FRACTION", "0.125"))
+    REPACK_DELTA_FRACTION = 0.125
 
     #: corpus size above which a kNN base pack also builds the IVF tier
     #: (k-means + cluster-contiguous int8 quantized rows — cluster-pruned
@@ -627,13 +626,12 @@ class ServingPlaneCache:
         #: the block-max tier on tiny corpora by lowering it)
         self.lex_prune_min_docs = self.LEX_PRUNE_MIN_DOCS
         #: delta-tier serving on/off (off = the old rebuild-every-refresh
-        #: behavior; the live-indexing bench uses this as its baseline)
-        self.delta_enabled = os.environ.get(
-            "ES_TPU_PLANE_DELTA", "1").lower() not in ("0", "false")
+        #: behavior; the live-indexing bench sets it as its baseline)
+        self.delta_enabled = True
         #: "background" (production) or "sync" (deterministic tests /
-        #: callers that need the swap visible before the call returns)
-        self.repack_mode = os.environ.get(
-            "ES_TPU_PLANE_REPACK_MODE", "background")
+        #: callers that need the swap visible before the call returns
+        #: set it on the instance)
+        self.repack_mode = "background"
         self._gen_lock = threading.RLock()
         #: guards the lazy mesh singleton — its OWN leaf lock, not
         #: _gen_lock: the cold build (jax import + device enumeration,

@@ -419,6 +419,12 @@ class FusedPlanRunner:
     def serves_host(self) -> bool:
         return self._text_base()._host_csr is not None
 
+    def serving_shapes(self, k_buckets, max_b) -> list:
+        """Nothing to warm ahead of traffic: a fused program's shape
+        follows the plan (windows, clause slots, rescore), so each
+        compiles on its first dispatch."""
+        return []
+
     def _bases_aligned(self) -> bool:
         """Device fused step unifies candidates by SHARD INDEX — valid
         only when both generations packed the same base segment list."""

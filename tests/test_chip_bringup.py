@@ -175,14 +175,13 @@ def test_warmup_failure_is_counted_and_journaled():
 
     class RefusedPlane:
         """A device plane (no host twin) whose every shape fails."""
-        SERVING_Q_MIN = 8
-        T_pad = 0
         _host_csr = None
 
-        def ladder_rungs(self):
-            return [1024]
+        def serving_shapes(self, k_buckets, max_b):
+            return [(1 << i, (8, 1024, kb, False, True, None))
+                    for i in range(max_b.bit_length()) for kb in k_buckets]
 
-        def search(self, *a, **kw):
+        def warm_shape(self, shape):
             raise RuntimeError("RESOURCE_EXHAUSTED: out of memory in hbm")
 
     before = len(_events("warmup_failed"))

@@ -365,14 +365,17 @@ def test_cache_exports_per_device_hbm_gauge(monkeypatch):
 
 
 def test_zero_steady_state_compiles_on_2d_mesh(monkeypatch, text_shards):
-    """The warm lattice covers the serving shapes at a 2x4 mesh too: a
-    post-warmup burst across batch sizes compiles nothing."""
+    """The plane's serving list covers what it serves at a 2x4 mesh too
+    (padded batches are multiples of the replica axis): a post-warmup
+    burst across batch sizes compiles nothing."""
     monkeypatch.setenv("ES_TPU_PLANE_HOST_SERVE", "0")
     plane = ds.DistributedSearchPlane(_mesh(2, 4), text_shards, "body")
     assert plane._host_csr is None
     b = PlaneMicroBatcher(plane)
     b.warmup(ks=(10,), max_b=4, sync=True)
-    assert b.warmed_shapes > 0
+    listed = plane.serving_shapes([16], 4)
+    assert [s[0] for s in listed] == [2, 4]      # B 1 pads to 2 replicas
+    assert b.warmed_shapes == len(listed)
     def _compiles():
         doc = tm.DEFAULT.metrics_doc().get("es_xla_compiles_total")
         return sum(int(s["value"]) for s in (doc or {}).get("series", []))

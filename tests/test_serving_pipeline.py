@@ -24,6 +24,16 @@ class FakePlane:
         self.dispatch_s = dispatch_s
         self.lock = threading.Lock()
 
+    def serving_shapes(self, k_buckets, max_b):
+        """The programs this plane states it serves: one a (padded
+        batch, k-bucket)."""
+        return [(1 << i, (kb,)) for i in range(max_b.bit_length())
+                for kb in k_buckets]
+
+    def warm_shape(self, shape):
+        b_pad, (kb,) = shape
+        self.search([[]] * b_pad, k=kb, with_totals=True)
+
     def search(self, queries, k=10, L=None, tiered=None, with_totals=False):
         real = [q for q in queries if len(q)]     # drop pow2 padding slots
         with self.lock:
@@ -219,8 +229,8 @@ def test_warmup_compiles_the_lattice_off_the_serving_path():
     plane = FakePlane()
     b = PlaneMicroBatcher(plane, max_batch=8)
     b.warmup(ks=(10,), sync=True)
-    # B ∈ {1,2,4,8} × one k bucket × one (None) L rung
-    assert b.warmed_shapes == 4
+    # the plane's own list: B ∈ {1,2,4,8} × one k bucket
+    assert b.warmed_shapes == 4 == len(plane.serving_shapes([16], 8))
     assert all(bt == [] for bt in plane.batches)    # pad-only dispatches
     assert b.n_dispatches == 0                      # not serving traffic
     # a host-serving plane (CPU backend) has nothing to pre-compile

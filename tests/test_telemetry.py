@@ -191,8 +191,8 @@ def _tiny_plane():
 
 def test_compile_churn_ratchet_zero_compiles_after_warmup():
     """Regression guard for the PR-2 fix: after ``warmup(sync=True)``
-    pre-compiles the serving shape lattice, a steady-state burst across
-    the bucket lattice (mixed B arrival patterns, mixed term counts,
+    pre-compiles the plane's serving list (``plane.serving_shapes``), a
+    steady-state burst (mixed B arrival patterns, mixed term counts,
     k inside the warmed bucket) must register ZERO new compiles."""
     from elasticsearch_tpu.search.microbatch import PlaneMicroBatcher
     plane = _tiny_plane()
@@ -203,8 +203,10 @@ def test_compile_churn_ratchet_zero_compiles_after_warmup():
     before_warm = telemetry.compile_count()
     b.warmup(ks=(10,), sync=True)
     after_warm = telemetry.compile_count()
-    assert b.warmed_shapes >= 3                  # B ∈ {1,2,4} at least
-    assert after_warm > before_warm, "warmup should compile the lattice"
+    # the plane's own list, one program a padded batch: B ∈ {1,2,4}
+    assert b.warmed_shapes == len(plane.serving_shapes([16], 4)) == 3
+    assert after_warm - before_warm == 3, \
+        "warmup should compile the list, one program a member"
 
     errs = []
 
