@@ -90,6 +90,12 @@ def main(argv=None) -> int:
           f"[{devices[0].device_kind}] x{len(devices)}, compile cache "
           f"{cache_dir}", flush=True)
     os.makedirs(args.data, exist_ok=True)
+    # this process is a node, whichever stack it builds below: what it
+    # installs to serve from (recovered shards, packed planes, loaded
+    # programs) leaves the cyclic collector's reach as it is installed,
+    # and GET /_nodes/stats counts the passes (its jvm section, gc collectors)
+    from ..common import heap
+    heap.arm()
     # one request thread per slot of a full micro-batch
     from ..search.microbatch import MAX_BATCH
     pool = ThreadPoolExecutor(max_workers=MAX_BATCH,

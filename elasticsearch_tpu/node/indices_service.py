@@ -19,6 +19,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..common import heap as _heap
 from ..common.errors import (ElasticsearchError, IllegalArgumentError,
                              IndexClosedError, IndexNotFoundError,
                              ResourceAlreadyExistsError)
@@ -162,6 +163,11 @@ class IndexService:
         # local engines (which hold data only for locally-assigned shards).
         # None on the single-node path — zero behavior change.
         self.cluster_hooks = None
+        # what recovery brought back (segments, the version map) lives as
+        # long as the index: in a node's process it leaves the cyclic
+        # collector's reach here (a new, empty index installs nothing)
+        if any(sh.segments for sh in self.shards):
+            _heap.settle()
 
     def _on_shard_refresh(self) -> None:
         """Engine refresh listener → plane-generation reconcile. Text

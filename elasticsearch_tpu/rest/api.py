@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 from urllib.parse import parse_qs, unquote
 
+from ..common import heap as _heap
 from ..common.errors import (ActionRequestValidationError,
                              DocumentMissingError, ElasticsearchError,
                              ResourceNotFoundError,
@@ -2061,7 +2062,7 @@ class RestAPI:
                             "non_heap_committed_in_bytes": 0,
                             "pools": {}},
                     "threads": {"count": 1, "peak_count": 1},
-                    "gc": {"collectors": {}},
+                    "gc": {"collectors": _heap.collectors_doc()},
                     "buffer_pools": {
                         "direct": {"count": 0, "used_in_bytes": 0,
                                    "total_capacity_in_bytes": 0},

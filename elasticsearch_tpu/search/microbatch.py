@@ -65,6 +65,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..common import heap as _heap
 from ..common import qos as _qos
 from ..common import racedep
 from ..common import tracing as _tracing
@@ -731,6 +732,9 @@ class PlaneMicroBatcher:
             # retirement, so compile_churn windows stay honest across
             # generation swaps (see telemetry.record_warmed_shapes)
             _tm.record_warmed_shapes(n)
+            if n:
+                # the loaded programs live as long as the plane does
+                _heap.settle()
 
         if sync:
             _run()

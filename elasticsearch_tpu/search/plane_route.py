@@ -65,6 +65,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..common import heap as _heap
 from ..common import racedep
 from ..index.mapping import MapperService, TextFieldType
 from ..index.segment import Segment
@@ -737,6 +738,12 @@ class ServingPlaneCache:
         # event — emitted outside every cache lock (ESTP-L02)
         from ..common import flightrec as _fr
         _fr.record("plane_rebuild", kind=kind, trigger=trigger, mode=mode)
+        # the generation just installed serves until a repack retires it,
+        # and the one it replaced is garbage now: in a node's process one
+        # full pass frees the old and takes the new out of the cyclic
+        # collector's reach, on the installing thread (the repack thread
+        # for a background repack)
+        _heap.settle()
 
     def _record_delta_serve(self, kind: str, n: int) -> None:
         from ..common import telemetry as _tm
