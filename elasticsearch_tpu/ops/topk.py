@@ -10,6 +10,12 @@ and only those groups are sorted. Tie-break matches Lucene's
 ascending-doc-id order because ``lax.top_k`` selects the lowest position
 among equal values and both the groups and the candidates gathered from
 them stay in doc-id order.
+
+Callers of the selection: the exact kNN scan's blocks
+(``parallel/dist_search._knn_shard_scan``, under ``build_knn_step`` and
+the fused hybrid step), the IVF step's re-rank window over its whole probed
+union at once (``build_ivf_knn_step``, PR 36), the dense text tier
+(``ops/tiered_bm25``) and the 1-D kernel below.
 """
 
 from __future__ import annotations

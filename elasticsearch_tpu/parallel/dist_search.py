@@ -46,7 +46,7 @@ from ..ops.sorted_merge import (bm25_topk_merge_body, make_impacts,
                                 vmap_queries)
 from ..ops.tiered_bm25 import (build_dense_rows, split_tiers,
                                tiered_bm25_topk)
-from ..ops.topk import batched_blockwise_topk
+from ..ops.topk import _group_width, batched_blockwise_topk
 from ..utils.shapes import round_up_multiple, round_up_pow2
 from .mesh import AXIS_REPLICA, AXIS_SHARD
 
@@ -923,19 +923,22 @@ class IvfKnnTier:
 
 def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                        n_shards: int, similarity: str, nprobe: int,
-                       r_cand: int, p_blocks: int, blk: int,
-                       quant: str = "int8"):
+                       r_cand: int, blk: int, quant: str = "int8"):
     """Jitted IVF dispatch: gather the probed-union blocks of the
-    quantized tier, stream them through a ``lax.scan`` running top-k of
-    width ``r_cand`` (the rerank window), re-score the survivors exactly
-    from the f32 tier, then the usual ICI all_gather/top_k reduce.
+    quantized tier, score all ``P·blk`` gathered rows in one
+    einsum, select the ``r_cand`` best (the rerank window) in one exact
+    pass over the flat row (``ops/topk.batched_blockwise_topk``: its
+    group path where ``_group_width`` says so, else one sort;
+    ``es_ann_scan_selection_total`` counts which), re-score the
+    survivors exactly from the f32 tier, then the usual ICI
+    all_gather/top_k reduce.
 
     Global shapes: codes [S, NB+1, blk, dim] int8/bf16; scale/off/rowid/
     rcl [S, NB+1, blk]; vecs f32[S, n_pad, dim] + vnorm2 f32[S, n_pad]
     (the EXACT tier, original row order); queries f32[B, dim]; probed
-    i32[B, nprobe] (global cluster ids); u_blocks i32[S, p_blocks]
+    i32[B, nprobe] (global cluster ids); u_blocks i32[S, P]
     (per-shard union, sentinel NB padding). Bytes moved from HBM per
-    dispatch are ~p_blocks·blk·(dim·qbytes + 12) + r_cand·dim·4 per
+    dispatch are ~P·blk·(dim·qbytes + 12) + r_cand·dim·4 per
     shard — the pruning win the knn_ivf_recall bench measures."""
     s_dev = mesh.shape[AXIS_SHARD]
     if n_shards % s_dev:
@@ -968,62 +971,34 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                 g_rowid = jnp.take(rowid_s, u_s, axis=0)
                 g_rcl = jnp.take(rcl_s, u_s, axis=0)
 
-            def score_block(c_b, sc_b, of_b, rid_b, rc_b):
+            with jax.named_scope("scan"):
+                # one pass over the union: every gathered row scored in
+                # one einsum, then one exact selection of the re-rank
+                # window over the flat row, ties to the lowest gathered
+                # position
+                rid = g_rowid.reshape(-1)                  # [P·blk]
                 dots = jnp.einsum(
-                    "bd,nd->bn", qq, c_b.astype(jnp.float32),
+                    "bd,nd->bn", qq,
+                    g_codes.reshape(-1, g_codes.shape[-1]).astype(
+                        jnp.float32),
                     preferred_element_type=jnp.float32)
-                s = sc_b[None, :] * dots \
-                    + of_b[None, :] * qsum[:, None]
+                s = g_scale.reshape(-1)[None, :] * dots \
+                    + g_off.reshape(-1)[None, :] * qsum[:, None]
                 if l2:
-                    vn_b = jnp.take(vn_s, jnp.clip(rid_b, 0, n_pad - 1))
-                    s = 2.0 * s - vn_b[None, :] - qn[:, None]
+                    vn = jnp.take(vn_s, jnp.clip(rid, 0, n_pad - 1))
+                    s = 2.0 * s - vn[None, :] - qn[:, None]
                 # per-query membership: the row's cluster must be in
                 # THIS query's probed set (co-batched queries share the
                 # gathered union but not the mask)
                 member = jnp.any(
-                    rc_b[None, :, None] == probed[:, None, :], axis=-1)
-                live = (rid_b < n_pad)[None, :]
-                return jnp.where(member & live, s, NEG_INF)
-
-            rr = min(r_cand, blk)
-
-            def step_blk(carry, xs):
-                acc_v, acc_i = carry
-                p_idx, c_b, sc_b, of_b, rid_b, rc_b = xs
-                bv, bi = batched_blockwise_topk(
-                    score_block(c_b, sc_b, of_b, rid_b, rc_b), rr)
-                gi = bi.astype(jnp.int32) + p_idx * blk
-                cat_v = jnp.concatenate([acc_v, bv], axis=1)
-                cat_i = jnp.concatenate([acc_i, gi], axis=1)
-                nv, sel = lax.top_k(cat_v, min(r_cand, cat_v.shape[1]))
-                ni = jnp.take_along_axis(cat_i, sel, axis=1)
-                return (nv, ni), None
-
-            with jax.named_scope("scan"):
-                v0 = score_block(g_codes[0], g_scale[0], g_off[0],
-                                 g_rowid[0], g_rcl[0])
-                v0, i0 = batched_blockwise_topk(v0, rr)
-                i0 = i0.astype(jnp.int32)
-                if rr < r_cand:
-                    # the scan carry is the FIXED-width rerank window:
-                    # pad the seed so every merge keeps exactly r_cand
-                    # entries
-                    padw = r_cand - rr
-                    v0 = jnp.pad(v0, ((0, 0), (0, padw)),
-                                 constant_values=NEG_INF)
-                    i0 = jnp.pad(i0, ((0, 0), (0, padw)))
-                if p_blocks > 1:
-                    (vals_q, pos_q), _ = lax.scan(
-                        step_blk, (v0, i0),
-                        (jnp.arange(1, p_blocks, dtype=jnp.int32),
-                         g_codes[1:], g_scale[1:], g_off[1:],
-                         g_rowid[1:], g_rcl[1:]))
-                else:
-                    vals_q, pos_q = v0, i0
+                    g_rcl.reshape(-1)[None, :, None]
+                    == probed[:, None, :], axis=-1)
+                live = (rid < n_pad)[None, :]
+                vals_q, pos_q = batched_blockwise_topk(
+                    jnp.where(member & live, s, NEG_INF), r_cand)
             with jax.named_scope("rerank"):
                 # positions in the gathered space → original local rows
-                rid_flat = g_rowid.reshape(-1)
-                cand_rows = jnp.take(rid_flat, pos_q)      # [B, R]
+                cand_rows = jnp.take(rid, pos_q)           # [B, R]
                 # EXACT re-rank from the f32 tier: gather survivor rows,
                 # re-score, and sort candidates by row id FIRST so the
                 # final top_k's lowest-position tie preference restores
@@ -4455,9 +4430,9 @@ class DistributedKnnPlane:
                    rerank: int, stages: Optional[dict] = None,
                    listed: bool = True, live: Optional[int] = None):
         """Device IVF dispatch: host centroid matmul picks the probed
-        clusters (``plane[probe]``), then the jitted step streams ONLY
-        their blocks of the quantized tier through the running-top-k
-        and re-ranks exactly from the f32 tier. At the default
+        clusters (``plane[probe]``), then the jitted step scores ONLY
+        their blocks of the quantized tier, selects the re-rank window
+        in one pass over them and re-ranks exactly from the f32 tier. At the default
         ``(nprobe, rerank)`` the batch runs listed programs only
         (:meth:`serving_shapes`): in chunks of at most
         :attr:`IvfKnnTier.LISTED_MAX_B` queries, each chunk's gather at
@@ -4534,9 +4509,12 @@ class DistributedKnnPlane:
             calls = []
             h2d = q_bytes = x_bytes = n_cand = 0
             meta_b = 12 + (4 if self.similarity == "l2_norm" else 0)
+            paths = {"group": 0, "sort": 0}     # the scan's selection
             for lo, rows, u_blocks, Pw in chunks:
                 key = self._ivf_key(k, nprobe, rerank, Pw)
                 r_cand = key[3]
+                paths["group" if _group_width(Pw * tier.block, r_cand)
+                      else "sort"] += 1
                 calls.append((
                     self._get_ivf_step(key),
                     jax.device_put(q[lo: lo + rows], repl),
@@ -4578,6 +4556,7 @@ class DistributedKnnPlane:
             d2h = vals.nbytes + gdocs.nbytes
             _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
             self._record_ann(B, nprobe, n_cand, q_bytes, x_bytes, stages)
+            _tm.record_ann_scan_selection(**paths)
             phases.enter("plane[decode]")
             hits = self._decode_hits(vals, gdocs)
         if stages is not None:
@@ -4614,14 +4593,14 @@ class DistributedKnnPlane:
         return -(-IvfKnnTier.LISTED_MAX_B // n_repl) * n_repl
 
     def _get_ivf_step(self, key: Tuple):
-        _, k, nprobe, r_cand, Pw = key      # :meth:`_ivf_key`
+        _, k, nprobe, r_cand, _pw = key     # :meth:`_ivf_key`
         return self.cached_step(
             key,
             lambda: build_ivf_knn_step(
                 self.mesh, n_pad=self.n_pad, dim=max(self.dim, 1),
                 k=k, n_shards=self.n_shards,
                 similarity=self.similarity, nprobe=nprobe,
-                r_cand=r_cand, p_blocks=Pw, blk=self.ivf.block,
+                r_cand=r_cand, blk=self.ivf.block,
                 quant=self.ivf.quant),
             "knn_ivf_plane")
 

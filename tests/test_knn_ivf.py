@@ -282,3 +282,104 @@ def test_ann_telemetry_families_register():
     assert stages["ann_exact_bytes"] > 0
     assert stages["docs_scanned"] > 0
     del snap0
+
+
+def _per_block_merge(blk):
+    """The selection of PR 35's IVF step, kept as the reference: a
+    ``lax.scan`` over the gathered blocks of the flat scores, each
+    block's top-``min(r_cand, blk)`` merged into a running window of
+    ``r_cand`` (the carry first, so ``top_k`` keeps the earlier
+    position). Same signature as ``batched_blockwise_topk``."""
+    from jax import lax
+    import jax.numpy as jnp
+    from elasticsearch_tpu.ops.topk import batched_blockwise_topk
+
+    def select(scores, r_cand):
+        B = scores.shape[0]
+        blocks = scores.reshape(B, -1, blk).transpose(1, 0, 2)
+        rr = min(r_cand, blk)
+
+        def step_blk(carry, xs):
+            p_idx, s_b = xs
+            bv, bi = batched_blockwise_topk(s_b, rr)
+            cat_v = jnp.concatenate([carry[0], bv], axis=1)
+            cat_i = jnp.concatenate([carry[1], bi + p_idx * blk], axis=1)
+            nv, sel = lax.top_k(cat_v, min(r_cand, cat_v.shape[1]))
+            return (nv, jnp.take_along_axis(cat_i, sel, axis=1)), None
+
+        v0, i0 = batched_blockwise_topk(blocks[0], rr)
+        if rr < r_cand:
+            v0 = jnp.pad(v0, ((0, 0), (0, r_cand - rr)),
+                         constant_values=float("-inf"))
+            i0 = jnp.pad(i0, ((0, 0), (0, r_cand - rr)))
+        (vals, pos), _ = lax.scan(
+            step_blk, (v0, i0),
+            (jnp.arange(1, blocks.shape[0], dtype=jnp.int32), blocks[1:]))
+        return vals, pos
+
+    return select
+
+@pytest.fixture(scope="module")
+def dup_plane():
+    """20,480 clustered rows of which 2,560 are 64 copies each of 40
+    rows, scattered: their int8 codes are equal, so quantized scores tie
+    across blocks at the edge of the re-rank window."""
+    rng = np.random.RandomState(36)
+    centers = rng.randn(48, 8).astype(np.float32)
+    vecs = (centers[rng.randint(0, 48, 20480)]
+            + 0.3 * rng.randn(20480, 8)).astype(np.float32)
+    vecs[rng.permutation(20480)[:2560]] = np.repeat(vecs[:40], 64, axis=0)
+    planes = {sim: DistributedKnnPlane(_mesh(), [dict(vectors=vecs)],
+                                       similarity=sim,
+                                       ivf=dict(nlist=64, seed=0))
+              for sim in ("cosine", "l2_norm")}
+    return planes, vecs
+
+
+@pytest.mark.parametrize("similarity", ("cosine", "l2_norm"))
+@pytest.mark.parametrize("b,width,k,nprobe", [
+    (1, 16, 16, 8), (1, 64, 16, 8), (2, 16, 16, 8), (2, 64, 16, 8),
+    (8, 16, 16, 8), (8, 64, 16, 8), (32, 16, 16, 8), (32, 64, 16, 8),
+    (8, 64, 128, 1)])
+def test_one_pass_selection_equals_the_per_block_merge(
+        dup_plane, similarity, b, width, k, nprobe):
+    """PR 36: one exact selection over the flat probed union returns what
+    the running per-block merge returned, bitwise, on both of its paths
+    (a width of 16 blocks sorts its 4,096 columns whole, 64 blocks take
+    the group path), and where fewer than ``r_cand`` rows are live
+    (``nprobe`` 1, ``k`` 128) in its finite entries."""
+    from elasticsearch_tpu.ops.topk import _group_width
+    from elasticsearch_tpu.parallel import dist_search as ds
+    planes, vecs = dup_plane
+    plane = planes[similarity]
+    tier, r_cand = plane.ivf, 4 * k
+    blk = tier.block
+    assert bool(_group_width(width * blk, r_cand)) == (width == 64)
+    rng = np.random.RandomState(b * width + k)
+    q = (vecs[rng.randint(0, 40, b)]
+         + 0.05 * rng.randn(b, 8)).astype(np.float32)
+    probed = tier.probe(plane._probe_queries(q)[0], nprobe)
+    # the union's blocks first, in a seeded order, then the rest of the
+    # tier and the sentinel: ties between blocks fall both ways
+    union = np.unique(tier.union_blocks(probed, 1)[0])
+    rest = np.setdiff1d(np.arange(tier.n_blocks + 1), union)
+    u = np.concatenate([rng.permutation(union), rng.permutation(rest)])
+    u = u[:width].astype(np.int32)[None]
+    dev = tier.device_arrays(plane.mesh, plane.n_pad)
+    vecs_dev, vn_dev, _ex = plane._device_arrays()
+    args = (dev["codes"], dev["scale"], dev["off"], dev["rowid"],
+            dev["rcl"], vecs_dev, vn_dev, q, probed, u)
+    kw = dict(n_pad=plane.n_pad, dim=8, k=k, n_shards=1,
+              similarity=similarity, nprobe=nprobe, r_cand=r_cand, blk=blk)
+    got_v, got_i = (np.asarray(a) for a in
+                    ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ds, "batched_blockwise_topk", _per_block_merge(blk))
+        want_v, want_i = (np.asarray(a) for a in
+                          ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
+    fin = np.isfinite(want_v)
+    assert (np.isfinite(got_v) == fin).all()
+    assert got_v[fin].tobytes() == want_v[fin].tobytes()
+    assert (got_i[fin] == want_i[fin]).all()
+    live = int(fin.sum(axis=1).min())
+    assert live == k if nprobe > 1 else 0 < live < k

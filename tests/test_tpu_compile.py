@@ -128,7 +128,7 @@ def _ivf(mesh, n_pad, dim, k, nprobe=8, p_blocks=512, blk=256):
     n_blocks = n_pad // blk
     step = ds.build_ivf_knn_step(
         mesh, n_pad=n_pad, dim=dim, k=k, n_shards=1, similarity="cosine",
-        nprobe=nprobe, r_cand=4 * k, p_blocks=p_blocks, blk=blk)
+        nprobe=nprobe, r_cand=4 * k, blk=blk)
 
     def meta(dt):
         return _sds(mesh, (1, n_blocks + 1, blk), dt, S, None, None)
