@@ -736,11 +736,11 @@ class IvfKnnTier:
 
     def probe(self, qq: np.ndarray, nprobe: int) -> np.ndarray:
         """Top-``nprobe`` cluster ids per query from ONE [B, nlist]
-        centroid matmul (host BLAS — the matrix is tiny and the probed
-        set must be host-visible anyway to size the static gather
-        shapes, the same reason the text plane's U-gather picks rows on
-        the host). ``qq``: queries in the plane's packed convention
-        (unit rows for cosine)."""
+        centroid matmul (host BLAS): the CPU backend's probe, and what
+        sizes the gather of a device dispatch outside the listed
+        programs; the device step probes for itself (:func:`_ivf_probe`).
+        ``qq``: queries in the plane's packed convention (unit rows for
+        cosine)."""
         s = qq @ self.centroids.T
         if self.similarity == "l2_norm":
             c2 = np.sum(self.centroids.astype(np.float64) ** 2,
@@ -756,12 +756,31 @@ class IvfKnnTier:
 
     # -- device tier ---------------------------------------------------------
 
+    def block_spans(self):
+        """Each cluster's blocks and rows a shard, what the step's own
+        union reads (:func:`_ivf_union`): ``(first, end, rows)``
+        i32[S, nlist], ``end`` exclusive, an empty cluster 0 / 0 / 0."""
+        S, blk = len(self.shards), self.block
+        first = np.zeros((S, self.nlist), np.int32)
+        end = np.zeros_like(first)
+        rows = np.zeros_like(first)
+        for s, sh in enumerate(self.shards):
+            offs = np.asarray(sh["offsets"], np.int64)
+            live = offs[1:] > offs[:-1]
+            first[s, live] = offs[:-1][live] // blk
+            end[s, live] = (offs[1:][live] - 1) // blk + 1
+            rows[s] = np.diff(offs)
+        return first, end, rows
+
     def device_arrays(self, mesh: Mesh, n_pad: int):
         """Block-major device tier (built lazily, once): codes
         [S, NB+1, blk, d], scale/off/vn-row metadata [S, NB+1, blk],
         rowid i32 (original local row; n_pad = sentinel), rcl i32
         (cluster id per row; -1 = padding). Block NB is an all-sentinel
-        pad target for the probed-union gather."""
+        pad target for the probed-union gather. Beside them what the
+        step's probe reads: ``centroids`` f32[nlist, d] and ``cnorm2``
+        f32[nlist], replicated, and :meth:`block_spans` as ``c_first`` /
+        ``c_end`` / ``c_rows`` i32[S, nlist]."""
         with self._dev_lock:
             if self._dev is not None:
                 return self._dev
@@ -789,28 +808,39 @@ class IvfKnnTier:
                 rcl[s].reshape(-1)[:n] = flat_cl
             spec4 = NamedSharding(mesh, P(AXIS_SHARD, None, None, None))
             spec3 = NamedSharding(mesh, P(AXIS_SHARD, None, None))
+            spec2 = NamedSharding(mesh, P(AXIS_SHARD, None))
             dev_codes = jax.device_put(
                 codes if self.quant == "int8"
                 else codes.astype(jnp.bfloat16), spec4)
+            cent = np.asarray(self.centroids, np.float32)
+            cnorm2 = np.sum(cent.astype(np.float64) ** 2,
+                            axis=1).astype(np.float32)
+            first, end, crows = self.block_spans()
             self._dev = dict(
                 nb=nb,
                 codes=dev_codes,
                 scale=jax.device_put(scale, spec3),
                 off=jax.device_put(off, spec3),
                 rowid=jax.device_put(rowid, spec3),
-                rcl=jax.device_put(rcl, spec3))
+                rcl=jax.device_put(rcl, spec3),
+                centroids=jax.device_put(cent, NamedSharding(mesh, P())),
+                cnorm2=jax.device_put(cnorm2, NamedSharding(mesh, P())),
+                c_first=jax.device_put(first, spec2),
+                c_end=jax.device_put(end, spec2),
+                c_rows=jax.device_put(crows, spec2))
             return self._dev
 
     def union_blocks(self, probed: np.ndarray, n_shards: int,
                      width: Optional[int] = None):
         """Per-shard union of the blocks the batch's probed clusters
         touch, padded with the sentinel block NB to a shared width: the
-        static gather shape of the device step. ``width`` is the
-        batch's :meth:`listed_width`; None sizes the gather from the
-        union itself (its power of two, as an explicit ``nprobe`` /
-        ``rerank`` still does). Returns ``(u_blocks, Pw, wanted)``,
-        ``wanted`` the union's own blocks summed over the shards;
-        ``u_blocks`` is None where the union is wider than ``width``."""
+        host twin of the step's own union (:func:`_ivf_union`).
+        ``width`` is the batch's :meth:`listed_width`; None sizes the
+        gather from the union itself (its power of two, as an explicit
+        ``nprobe`` / ``rerank`` does). Returns ``(u_blocks, Pw,
+        wanted)``, ``wanted`` the union's own blocks summed over the
+        shards; ``u_blocks`` is None where the union is wider than
+        ``width``."""
         blk = self.block
         nb = self.n_blocks
         uniq = np.unique(probed)
@@ -921,25 +951,78 @@ class IvfKnnTier:
         return w
 
 
+def _ivf_probe(qq, centroids, cnorm2, *, nprobe: int, l2: bool):
+    """The device twin of :meth:`IvfKnnTier.probe`: the top-``nprobe``
+    cluster ids a query, i32[B, nprobe], from one [B, nlist] centroid
+    product at float32 (``HIGHEST``), ``2s - |c|²`` for ``l2_norm``;
+    ties to the lowest cluster id."""
+    s = jnp.einsum("bd,cd->bc", qq, centroids,
+                   preferred_element_type=jnp.float32,
+                   precision=lax.Precision.HIGHEST)
+    if l2:
+        s = 2.0 * s - cnorm2[None, :]
+    return lax.top_k(s, min(nprobe, centroids.shape[0]))[1].astype(
+        jnp.int32)
+
+
+def _ivf_union(probed, c_first, c_end, c_rows, *, width: int,
+               n_blocks: int):
+    """The device twin of :meth:`IvfKnnTier.union_blocks`: per shard the
+    blocks the probed clusters span, ascending and padded with the
+    sentinel block ``n_blocks`` to ``width``, from a difference array of
+    the clusters' block spans (:meth:`IvfKnnTier.block_spans`, i32[S,
+    nlist] a shard). Returns ``(u_blocks i32[S, width], counts i32[4])``,
+    ``counts`` summed over the shards: the union's own blocks, the
+    probed clusters' rows (each once), the shards whose union is wider
+    than ``width`` (``u_blocks`` then holds its first ``width``), and
+    the rows each query's own clusters hold."""
+    hit = jnp.zeros(c_first.shape[-1], jnp.int32).at[
+        probed.reshape(-1)].set(1)
+
+    def one(first, end, rows):
+        edge = jnp.zeros(n_blocks + 1, jnp.int32).at[first].add(hit) \
+            .at[end].add(-hit)
+        inside = jnp.cumsum(edge)[:n_blocks] > 0
+        u = jnp.nonzero(inside, size=width, fill_value=n_blocks)[0]
+        wanted = jnp.sum(inside, dtype=jnp.int32)
+        return u.astype(jnp.int32), jnp.stack(
+            [wanted, jnp.sum(hit * rows), (wanted > width).astype(jnp.int32),
+             jnp.sum(jnp.take(rows, probed))])
+
+    u_blocks, counts = jax.vmap(one)(c_first, c_end, c_rows)
+    return u_blocks, jnp.sum(counts, axis=0)
+
+
 def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                        n_shards: int, similarity: str, nprobe: int,
-                       r_cand: int, blk: int, quant: str = "int8"):
-    """Jitted IVF dispatch: gather the probed-union blocks of the
-    quantized tier, score all ``P·blk`` gathered rows in one
-    einsum, select the ``r_cand`` best (the rerank window) in one exact
-    pass over the flat row (``ops/topk.batched_blockwise_topk``: its
-    group path where ``_group_width`` says so, else one sort;
+                       r_cand: int, blk: int, width: int,
+                       quant: str = "int8"):
+    """Jitted IVF dispatch: probe the centroids for each query's
+    ``nprobe`` clusters and take the union of the blocks they span
+    (``knn_ivf/probe``: :func:`_ivf_probe`, :func:`_ivf_union`), gather
+    those blocks of the quantized tier, score all ``width·blk`` gathered
+    rows in one einsum, select the ``r_cand`` best (the rerank window) in
+    one exact pass over the flat row (``ops/topk.batched_blockwise_topk``:
+    its group path where ``_group_width`` says so, else one sort;
     ``es_ann_scan_selection_total`` counts which), re-score the
     survivors exactly from the f32 tier, then the usual ICI
     all_gather/top_k reduce.
 
     Global shapes: codes [S, NB+1, blk, dim] int8/bf16; scale/off/rowid/
     rcl [S, NB+1, blk]; vecs f32[S, n_pad, dim] + vnorm2 f32[S, n_pad]
-    (the EXACT tier, original row order); queries f32[B, dim]; probed
-    i32[B, nprobe] (global cluster ids); u_blocks i32[S, P]
-    (per-shard union, sentinel NB padding). Bytes moved from HBM per
-    dispatch are ~P·blk·(dim·qbytes + 12) + r_cand·dim·4 per
-    shard — the pruning win the knn_ivf_recall bench measures."""
+    (the EXACT tier, original row order); centroids f32[nlist, dim] +
+    cnorm2 f32[nlist], replicated; c_first / c_end / c_rows
+    i32[S, nlist] (each cluster's block span and rows a shard); queries
+    f32[B, dim]. The union is the batch's, every replica's queries
+    probed; ``width`` (the static gather, blocks a shard) is the
+    program's. Returns ``(vals, gdocs, counts)``, ``counts`` i32[4]
+    replicated (:func:`_ivf_union`): the union's blocks summed over the
+    shards, the probed clusters' rows, the shards whose union was wider
+    than ``width`` (the answer is then not the IVF's: the caller serves
+    the exact scan), and the rows the queries' own clusters hold.
+    Bytes moved from HBM per dispatch are ~width·blk·(dim·qbytes + 12) +
+    r_cand·dim·4 per shard — the pruning win the knn_ivf_recall bench
+    measures."""
     s_dev = mesh.shape[AXIS_SHARD]
     if n_shards % s_dev:
         raise ValueError(f"{n_shards} shards not divisible over {s_dev} devices")
@@ -949,8 +1032,8 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
     l2 = similarity == "l2_norm"
 
     @in_named_scope("knn_ivf")
-    def body(codes, scale, off, rowid, rcl, vecs, vnorm2, q, probed,
-             u_blocks):
+    def body(codes, scale, off, rowid, rcl, vecs, vnorm2, centroids,
+             cnorm2, c_first, c_end, c_rows, q):
         if similarity == "cosine":
             qq = q / jnp.maximum(
                 jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
@@ -958,6 +1041,16 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
             qq = q
         qsum = jnp.sum(qq, axis=-1)                       # [B]
         qn = jnp.sum(q * q, axis=-1)                      # [B]
+
+        with jax.named_scope("probe"):
+            probed = _ivf_probe(qq, centroids, cnorm2, nprobe=nprobe,
+                                l2=l2)                   # [B_loc, nprobe]
+            # the union is the batch's: every replica's queries
+            u_blocks, counts = _ivf_union(
+                lax.all_gather(probed, AXIS_REPLICA, axis=0, tiled=True),
+                c_first, c_end, c_rows, width=width,
+                n_blocks=codes.shape[1] - 1)
+            counts = lax.psum(counts, AXIS_SHARD)
 
         @in_named_scope("score")
         def per_shard(codes_s, scale_s, off_s, rowid_s, rcl_s, vecs_s,
@@ -1027,8 +1120,9 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
         vals, idx = jax.vmap(per_shard, in_axes=(0, 0, 0, 0, 0, 0, 0, 0),
                              out_axes=1)(codes, scale, off, rowid, rcl,
                                          vecs, vnorm2, u_blocks)
-        return _global_topk_reduce(vals, idx, s_loc=s_loc, kk=kk,
-                                   n_pad=n_pad, out_k=out_k)
+        vals, gdocs = _global_topk_reduce(vals, idx, s_loc=s_loc, kk=kk,
+                                          n_pad=n_pad, out_k=out_k)
+        return vals, gdocs, counts
 
     step = shard_map(
         body, mesh=mesh,
@@ -1039,10 +1133,13 @@ def build_ivf_knn_step(mesh: Mesh, *, n_pad: int, dim: int, k: int,
                   P(AXIS_SHARD, None, None),
                   P(AXIS_SHARD, None, None),
                   P(AXIS_SHARD, None),
-                  P(AXIS_REPLICA, None),
-                  P(AXIS_REPLICA, None),
-                  P(AXIS_SHARD, None)),
-        out_specs=(P(AXIS_REPLICA, None), P(AXIS_REPLICA, None)),
+                  P(None, None),
+                  P(None),
+                  P(AXIS_SHARD, None),
+                  P(AXIS_SHARD, None),
+                  P(AXIS_SHARD, None),
+                  P(AXIS_REPLICA, None)),
+        out_specs=(P(AXIS_REPLICA, None), P(AXIS_REPLICA, None), P()),
         check_vma=False)
     return _jit_step(step, "knn_ivf")
 
@@ -3953,13 +4050,18 @@ class DistributedKnnPlane:
         dim = max(self.dim, 1)
         # vecs f32 + vnorm2 f32 + exists bool per padded row
         total = self.n_shards * self.n_pad * (dim * 4 + 4 + 1)
+        replicated = 0
         if self.ivf is not None:
             # block-major quantized tier incl. the sentinel pad block:
-            # codes + scale/off/rowid/rcl rows per slot
+            # codes + scale/off/rowid/rcl rows per slot, and what the
+            # step's probe reads: each cluster's block span and rows a
+            # shard, the centroids and their norms on every device
             nb1 = self.ivf.n_blocks + 1
             total += self.n_shards * nb1 * self.ivf.block * \
                 (dim * self.ivf.quant_bytes_per_dim() + 16)
-        return total // max(s_dev, 1)
+            total += self.n_shards * self.ivf.nlist * 12
+            replicated = self.ivf.nlist * (dim * 4 + 4)
+        return total // max(s_dev, 1) + replicated
 
     # -- storage tiers (hot / warm) ------------------------------------------
 
@@ -4429,25 +4531,28 @@ class DistributedKnnPlane:
     def search_ivf(self, query_vectors, k: int = 10, *, nprobe: int,
                    rerank: int, stages: Optional[dict] = None,
                    listed: bool = True, live: Optional[int] = None):
-        """Device IVF dispatch: host centroid matmul picks the probed
-        clusters (``plane[probe]``), then the jitted step scores ONLY
-        their blocks of the quantized tier, selects the re-rank window
-        in one pass over them and re-ranks exactly from the f32 tier. At the default
-        ``(nprobe, rerank)`` the batch runs listed programs only
+        """Device IVF dispatch: the jitted step probes the centroids for
+        each query's clusters, takes the union of their blocks, scores
+        ONLY those blocks of the quantized tier, selects the re-rank
+        window in one pass over them and re-ranks exactly from the f32
+        tier; the host then reads what the program's probe found
+        (``plane[probe]``, after the fetch). At the default ``(nprobe,
+        rerank)`` the batch runs listed programs only
         (:meth:`serving_shapes`): in chunks of at most
         :attr:`IvfKnnTier.LISTED_MAX_B` queries, each chunk's gather at
         its padded size's listed width (the union is padded with the
         sentinel block, which scores -inf); a chunk whose union is wider
-        than that sends the batch to the exact scan instead, counted as
-        an overflow. Where the caller says how many leading rows are
-        ``live`` (the rest its own padding) the chunks stop there and
-        what is left past the full ones runs the listed program of its
-        padded size (:meth:`_ivf_chunks`; 43 queries in a batch of 64:
-        the programs of 32 and 16); padding rows no chunk ran come back
-        empty. Any
-        other ``(nprobe, rerank)``, or ``listed=False``, runs the batch
-        whole with its gather sized from the union (its power of two).
-        Same return convention as :meth:`search`."""
+        than that sends the batch to the exact scan after the step,
+        counted as an overflow. Where the caller says how many leading
+        rows are ``live`` (the rest its own padding) the chunks stop
+        there and what is left past the full ones runs the listed
+        program of its padded size (:meth:`_ivf_chunks`; 43 queries in a
+        batch of 64: the programs of 32 and 16); padding rows no chunk
+        ran come back empty. Any other ``(nprobe, rerank)``, or
+        ``listed=False``, runs the batch whole with its gather sized from
+        the union (its power of two), for which the host probes too
+        (``es_ann_probe_total{site}``). Same return convention as
+        :meth:`search`."""
         if self.ivf is None:
             raise RuntimeError("plane has no IVF tier")
         if self.storage_tier != "hot":
@@ -4456,7 +4561,7 @@ class DistributedKnnPlane:
             return self.search(query_vectors, k=k, stages=stages)
         from ..common import telemetry as _tm
         with _tracing.Phases() as phases:
-            probe_span = phases.enter("plane[probe]")
+            phases.enter("plane[h2d]")
             t0 = time.perf_counter()
             tier = self.ivf
             q = np.asarray(query_vectors, np.float32)
@@ -4469,75 +4574,55 @@ class DistributedKnnPlane:
             if B_pad != B:
                 q = np.concatenate(
                     [q, np.zeros((B_pad - B, q.shape[1]), np.float32)])
-            qq, _ = self._probe_queries(q)
-            probed = tier.probe(qq, nprobe)
             listed = listed and (nprobe, rerank) == (
                 tier.default_nprobe, IVF_DEFAULT_RERANK)
-            n_live = B_pad if live is None or not listed \
-                else max(min(int(live), B_pad), 1)
-            chunks = []         # (first row, rows, u_blocks, Pw)
-            wanted = lo = 0
-            for rows in (self._ivf_chunks(n_live) if listed else [B_pad]):
-                rows = min(rows, B_pad - lo)
-                u_blocks, Pw, want = tier.union_blocks(
-                    probed[lo: lo + rows], self.n_shards,
-                    tier.listed_width(rows, nprobe) if listed else None)
-                chunks.append((lo, rows, u_blocks, Pw))
-                wanted += want
-                lo += rows
-            overflow = any(c[2] is None for c in chunks)
-            union = dict(
-                blocks_wanted=wanted,
-                blocks_run=0 if overflow
-                else self.n_shards * sum(c[3] for c in chunks),
-                rows=int(tier.cluster_sizes[np.unique(probed)].sum()),
-                overflow=int(overflow))
-            _tm.record_ann_union(**union)
-            if probe_span is not None:
-                probe_span.attrs.update(union)
-            if overflow:
-                phases.close()
-                out = self.search(query_vectors, k=k, stages=stages)
-                if stages is not None:
-                    stages["ann_union"] = union
-                return out
-            phases.enter("plane[h2d]")
+            if listed:
+                n_live = B_pad if live is None \
+                    else max(min(int(live), B_pad), 1)
+                plan = [(rows, None) for rows in self._ivf_chunks(n_live)]
+            else:
+                # the gather sized from the batch's own union: the one
+                # route on which the host probes (the step probes again)
+                probed = tier.probe(self._probe_queries(q)[0], nprobe)
+                plan = [(B_pad,
+                         tier.union_blocks(probed, self.n_shards)[1])]
             dev = tier.device_arrays(self.mesh, self.n_pad)
             vecs_dev, vnorm2_dev, _exists_dev = self._device_arrays()
             repl = NamedSharding(self.mesh, P(AXIS_REPLICA, None))
-            shard2 = NamedSharding(self.mesh, P(AXIS_SHARD, None))
-            calls = []
-            h2d = q_bytes = x_bytes = n_cand = 0
+            calls = []          # (step, queries on the device)
+            lo = q_bytes = x_bytes = n_cand = blocks_run = 0
             meta_b = 12 + (4 if self.similarity == "l2_norm" else 0)
             paths = {"group": 0, "sort": 0}     # the scan's selection
-            for lo, rows, u_blocks, Pw in chunks:
+            for rows, Pw in plan:
+                rows = min(rows, B_pad - lo)
+                if Pw is None:
+                    Pw = tier.listed_width(rows, nprobe)
                 key = self._ivf_key(k, nprobe, rerank, Pw)
                 r_cand = key[3]
                 paths["group" if _group_width(Pw * tier.block, r_cand)
                       else "sort"] += 1
-                calls.append((
-                    self._get_ivf_step(key),
-                    jax.device_put(q[lo: lo + rows], repl),
-                    jax.device_put(probed[lo: lo + rows], repl),
-                    jax.device_put(u_blocks, shard2)))
-                h2d += (rows * q.shape[1] + rows * probed.shape[1]
-                        + u_blocks.size) * 4
+                calls.append((self._get_ivf_step(key),
+                              jax.device_put(q[lo: lo + rows], repl)))
+                blocks_run += self.n_shards * Pw
                 # bytes the pruned scan actually reads from HBM vs the
                 # exact re-rank gather (the ROOFLINE IVF model's terms)
                 q_bytes += self.n_shards * Pw * tier.block * \
                     (self.dim * tier.quant_bytes_per_dim() + meta_b)
                 x_bytes += self.n_shards * rows * r_cand * self.dim * 4
                 n_cand += rows * r_cand * self.n_shards
+                lo += rows
+            h2d = lo * q.shape[1] * 4
             phases.enter("plane[launch]")
             t1 = time.perf_counter()
-            out = []            # one (vals, gdocs) a chunk
+            out = []            # one (vals, gdocs, counts) a chunk
             compiled = False
-            for step, q_dev, probed_dev, u_dev in calls:
+            for step, q_dev in calls:
                 out.append(_run_step(
                     self._serial_dispatch, step,
                     dev["codes"], dev["scale"], dev["off"], dev["rowid"],
-                    dev["rcl"], vecs_dev, vnorm2_dev, q_dev, probed_dev,
-                    u_dev))
+                    dev["rcl"], vecs_dev, vnorm2_dev, dev["centroids"],
+                    dev["cnorm2"], dev["c_first"], dev["c_end"],
+                    dev["c_rows"], q_dev))
                 compiled = compiled or _tm.last_call_compiled()
             if stages is not None:
                 phases.enter("plane[sync]")
@@ -4547,14 +4632,33 @@ class DistributedKnnPlane:
             self.n_dispatches += 1
             _tm.record_mesh_dispatch(self.mesh.shape[AXIS_SHARD],
                                      self.mesh.shape[AXIS_REPLICA])
-            vals = np.concatenate([np.asarray(o[0]) for o in out])[:B]
-            gdocs = np.concatenate([np.asarray(o[1]) for o in out])[:B]
+            out = jax.device_get(out)   # the hits and the counts at once
+            vals = np.concatenate([o[0] for o in out])[:B]
+            gdocs = np.concatenate([o[1] for o in out])[:B]
             if vals.shape[0] < B:       # the caller's padding, not run
                 rest = ((0, B - vals.shape[0]), (0, 0))
                 vals = np.pad(vals, rest, constant_values=NEG_INF)
                 gdocs = np.pad(gdocs, rest)
-            d2h = vals.nbytes + gdocs.nbytes
+            counts = np.sum([o[2] for o in out], axis=0, dtype=np.int64)
+            d2h = vals.nbytes + gdocs.nbytes + sum(o[2].nbytes for o in out)
             _tm.record_transfer(h2d_bytes=h2d, d2h_bytes=d2h)
+            probe_span = phases.enter("plane[probe]")
+            # what the program's probe found (_ivf_union's counts)
+            wanted, rows_u, n_over, scanned = (int(c) for c in counts)
+            union = dict(blocks_wanted=wanted,
+                         blocks_run=0 if n_over else blocks_run,
+                         rows=rows_u, overflow=int(n_over > 0))
+            _tm.record_ann_union(**union)
+            _tm.record_ann_probe(**{"program" if listed else "host":
+                                    len(calls)})
+            if probe_span is not None:
+                probe_span.attrs.update(union)
+            if n_over:
+                phases.close()
+                out = self.search(query_vectors, k=k, stages=stages)
+                if stages is not None:
+                    stages["ann_union"] = union
+                return out
             self._record_ann(B, nprobe, n_cand, q_bytes, x_bytes, stages)
             _tm.record_ann_scan_selection(**paths)
             phases.enter("plane[decode]")
@@ -4566,7 +4670,7 @@ class DistributedKnnPlane:
             stages["compile_cache"] = "miss" if compiled else "hit"
             stages["h2d_bytes"] = h2d
             stages["d2h_bytes"] = d2h
-            stages["docs_scanned"] = self._ivf_probed_docs(probed[:B])
+            stages["docs_scanned"] = scanned // max(lo, 1)
             stages["ann_union"] = union
         return vals, hits
 
@@ -4593,14 +4697,14 @@ class DistributedKnnPlane:
         return -(-IvfKnnTier.LISTED_MAX_B // n_repl) * n_repl
 
     def _get_ivf_step(self, key: Tuple):
-        _, k, nprobe, r_cand, _pw = key     # :meth:`_ivf_key`
+        _, k, nprobe, r_cand, width = key   # :meth:`_ivf_key`
         return self.cached_step(
             key,
             lambda: build_ivf_knn_step(
                 self.mesh, n_pad=self.n_pad, dim=max(self.dim, 1),
                 k=k, n_shards=self.n_shards,
                 similarity=self.similarity, nprobe=nprobe,
-                r_cand=r_cand, blk=self.ivf.block,
+                r_cand=r_cand, blk=self.ivf.block, width=width,
                 quant=self.ivf.quant),
             "knn_ivf_plane")
 

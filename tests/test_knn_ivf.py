@@ -348,6 +348,7 @@ def test_one_pass_selection_equals_the_per_block_merge(
     (a width of 16 blocks sorts its 4,096 columns whole, 64 blocks take
     the group path), and where fewer than ``r_cand`` rows are live
     (``nprobe`` 1, ``k`` 128) in its finite entries."""
+    import jax.numpy as jnp
     from elasticsearch_tpu.ops.topk import _group_width
     from elasticsearch_tpu.parallel import dist_search as ds
     planes, vecs = dup_plane
@@ -368,15 +369,24 @@ def test_one_pass_selection_equals_the_per_block_merge(
     dev = tier.device_arrays(plane.mesh, plane.n_pad)
     vecs_dev, vn_dev, _ex = plane._device_arrays()
     args = (dev["codes"], dev["scale"], dev["off"], dev["rowid"],
-            dev["rcl"], vecs_dev, vn_dev, q, probed, u)
+            dev["rcl"], vecs_dev, vn_dev, dev["centroids"], dev["cnorm2"],
+            dev["c_first"], dev["c_end"], dev["c_rows"], q)
     kw = dict(n_pad=plane.n_pad, dim=8, k=k, n_shards=1,
-              similarity=similarity, nprobe=nprobe, r_cand=r_cand, blk=blk)
-    got_v, got_i = (np.asarray(a) for a in
-                    ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
+              similarity=similarity, nprobe=nprobe, r_cand=r_cand, blk=blk,
+              width=width)
+
+    def seeded_union(*_a, **_kw):
+        # the step's own probe still masks each query's clusters; only
+        # the gathered blocks and their order are the test's
+        return jnp.asarray(u), jnp.zeros(4, jnp.int32)
+
     with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ds, "_ivf_union", seeded_union)
+        got_v, got_i, _ = (np.asarray(a) for a in
+                           ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
         mp.setattr(ds, "batched_blockwise_topk", _per_block_merge(blk))
-        want_v, want_i = (np.asarray(a) for a in
-                          ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
+        want_v, want_i, _ = (np.asarray(a) for a in
+                             ds.build_ivf_knn_step(plane.mesh, **kw)(*args))
     fin = np.isfinite(want_v)
     assert (np.isfinite(got_v) == fin).all()
     assert got_v[fin].tobytes() == want_v[fin].tobytes()

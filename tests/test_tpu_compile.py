@@ -124,15 +124,16 @@ def _knn(mesh, n_shards, n_pad, dim, k):
         _sds(mesh, (B, dim), jnp.float32, R, None))
 
 
-def _ivf(mesh, n_pad, dim, k, nprobe=8, p_blocks=512, blk=256):
+def _ivf(mesh, n_pad, dim, k, nprobe=8, p_blocks=512, blk=256, nlist=512):
     n_blocks = n_pad // blk
     step = ds.build_ivf_knn_step(
         mesh, n_pad=n_pad, dim=dim, k=k, n_shards=1, similarity="cosine",
-        nprobe=nprobe, r_cand=4 * k, blk=blk)
+        nprobe=nprobe, r_cand=4 * k, blk=blk, width=p_blocks)
 
     def meta(dt):
         return _sds(mesh, (1, n_blocks + 1, blk), dt, S, None, None)
 
+    span = _sds(mesh, (1, nlist), jnp.int32, S, None)
     return step, (
         _sds(mesh, (1, n_blocks + 1, blk, dim), jnp.int8,
              S, None, None, None),
@@ -140,9 +141,10 @@ def _ivf(mesh, n_pad, dim, k, nprobe=8, p_blocks=512, blk=256):
         meta(jnp.int32),
         _sds(mesh, (1, n_pad, dim), jnp.float32, S, None, None),
         _sds(mesh, (1, n_pad), jnp.float32, S, None),
-        _sds(mesh, (B, dim), jnp.float32, R, None),
-        _sds(mesh, (B, nprobe), jnp.int32, R, None),
-        _sds(mesh, (1, p_blocks), jnp.int32, S, None))
+        _sds(mesh, (nlist, dim), jnp.float32),
+        _sds(mesh, (nlist,), jnp.float32),
+        span, span, span,
+        _sds(mesh, (B, dim), jnp.float32, R, None))
 
 
 def _fused(mesh, n_pad, p_pad, L, dim):
