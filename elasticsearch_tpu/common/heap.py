@@ -38,6 +38,14 @@ lock go (PR 25). ``GET /_nodes/stats`` reads it as ``jvm.gc.collectors``
 freeze, generation-2 passes come *more* often (the collector's
 long-lived total is small again) while each costs tens of milliseconds:
 the time is the figure, not the count.
+
+**The twin.** While a ``jax.profiler`` session is active the same hook
+opens a ``host[gc]`` annotation at a pass's start (stat ``generation``)
+and closes it at its stop (stat ``collected``), so each pass lies in the
+host plane of the trace beside the device's ops and the request spans
+(``common/tracing.py``): a device idle gap or a request span that
+overlaps one was stopped by the collector. Without a session the hook
+adds one ``is_enabled()`` check a pass.
 """
 
 from __future__ import annotations
@@ -47,6 +55,7 @@ import time
 from typing import Dict
 
 from . import telemetry
+from .tracing import TraceAnnotation
 
 _armed = False
 # by generation; written only by _on_collection, which the collector never
@@ -54,17 +63,26 @@ _armed = False
 _passes = [0, 0, 0]
 _pause_ns = [0, 0, 0]
 _started_ns = 0
+_twin = None                        # the open pass's host[gc] annotation
 _settles = telemetry.Counter()      # settle() runs on any installing thread
 
 
 def _on_collection(phase: str, info: dict) -> None:
-    global _started_ns
+    global _started_ns, _twin
     if phase == "start":
+        if TraceAnnotation.is_enabled():
+            _twin = TraceAnnotation("host[gc]",
+                                    generation=info["generation"])
+            _twin.__enter__()
         _started_ns = time.perf_counter_ns()
     else:
         g = info["generation"]
         _passes[g] += 1
         _pause_ns[g] += time.perf_counter_ns() - _started_ns
+        if _twin is not None:
+            _twin.set_metadata(collected=info["collected"])
+            _twin.__exit__(None, None, None)
+            _twin = None
 
 
 def arm() -> None:

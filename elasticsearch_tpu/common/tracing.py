@@ -27,17 +27,24 @@ session is active (an operator's capture, the benchmark's ``--trace 1``)
 a span also opens a ``jax.profiler.TraceAnnotation`` of the same name,
 so it lands in the host plane of the same ``.xplane.pb`` as the device's
 events, on one clock. A traced span's twin carries ``trace_id`` /
-``span_id`` / ``parent`` and no more (its attributes are in the store
-under that span id; :data:`_LINK_KEYS` names the exception). Threads
-that serve no single request (the micro-batcher's dispatchers) get the
-twin only, with their scalar attributes: those spans carry the dispatch
-``seq`` that the requests' ``plane_dispatch`` spans point at. With no
-session nothing is formatted and nothing is written. (That twin is why
-this module, alone under ``common/``, imports ``jax``: the profiler's
-front end only, no backend is initialised by it.)
+``span_id`` / ``parent`` (its attributes are in the store under that span
+id; :data:`_LINK_KEYS` names the exception). Threads that serve no single
+request (the micro-batcher's dispatchers) get the twin only, with their
+scalar attributes: those spans carry the dispatch ``seq`` that the
+requests' ``plane_dispatch`` spans point at. Every dispatcher twin, and
+every twin of one request in :data:`CPU_SAMPLE` (chosen by its trace id,
+so a request's twins carry it all or none), also carries its thread's CPU
+clock (``time.thread_time_ns``, the clock of the task ledger's
+checkpoints) as it opens, ``cpu0_us``, and as it closes, ``cpu1_us``:
+absolute microseconds of that thread, so the CPU between any two edges of
+one thread is a subtraction, and the rest of their wall time is waiting.
+With no session no clock is read, nothing is formatted and nothing is
+written. (That twin is why this module,
+alone under ``common/``, imports ``jax``: the profiler's front end only,
+no backend is initialised by it.)
 
-Cost per span, with and without a session: PERF.md §6 "PR 25" has the
-measured numbers.
+Cost per span, with and without a session: TELEMETRY.md "Overhead
+budget".
 """
 
 from __future__ import annotations
@@ -273,7 +280,14 @@ DEFAULT_STORE = TraceStore()
 
 
 _SCALARS = (str, int, float, bool)
-#: the attributes a traced span's twin carries besides its ids: what
+#: one request in CPU_SAMPLE has twins that carry the thread's CPU clock.
+#: A read is a system call made with the interpreter lock held: 5.9 µs on
+#: the benchmark's TPU host, where a clock on every twin of every request
+#: (~28 reads a request) cut a traced window's throughput by a fifth
+#: (TELEMETRY.md "Overhead budget")
+CPU_SAMPLE = 8
+#: the attributes a traced span's twin carries besides its ids and, where
+#: sampled, its thread's CPU clock (``cpu0_us`` / ``cpu1_us``): what
 #: links it to a span of another thread (a request's ``plane_dispatch``
 #: to the dispatcher's ``batch[...]`` spans of that ``seq``)
 _LINK_KEYS = ("dispatch_seq",)
@@ -296,7 +310,7 @@ class SpanHandle:
 
     __slots__ = ("name", "trace_id", "span_id", "parent_span_id", "attrs",
                  "node", "store", "manual", "_token", "_t0", "_start_ms",
-                 "_ann", "_ann_keys")
+                 "_ann", "_ann_keys", "_clocked")
 
     def __init__(self, name: str, trace_id: Optional[str],
                  parent_span_id: Optional[str], attrs: Optional[dict],
@@ -312,9 +326,14 @@ class SpanHandle:
         self.manual = manual
         self._ann = self._ann_keys = None
         if profiling:
+            self._clocked = trace_id is None or \
+                hash(trace_id) % CPU_SAMPLE == 0
+            cpu = {"cpu0_us": time.thread_time_ns() // 1000} \
+                if self._clocked else {}
             if trace_id is None:
                 self._ann_keys = tuple(self.attrs)
-                self._ann = TraceAnnotation(name, **_stats(self.attrs))
+                self._ann = TraceAnnotation(name, **cpu,
+                                            **_stats(self.attrs))
             elif parent_span_id:
                 # ids get a letter in front: a stat that looks like a
                 # number is read back as one, and one hex id in a few
@@ -322,11 +341,11 @@ class SpanHandle:
                 self._ann = TraceAnnotation(
                     name, trace_id="t" + trace_id,
                     span_id="s" + self.span_id,
-                    parent="s" + str(parent_span_id))
+                    parent="s" + str(parent_span_id), **cpu)
             else:
                 self._ann = TraceAnnotation(
                     name, trace_id="t" + trace_id,
-                    span_id="s" + self.span_id)
+                    span_id="s" + self.span_id, **cpu)
             self._ann.__enter__()
         self._token = _CTX.set(self) if trace_id is not None else None
         self._start_ms = time.time() * 1e3
@@ -348,6 +367,8 @@ class SpanHandle:
             else:
                 late = {k: self.attrs[k] for k in _LINK_KEYS
                         if k in self.attrs}
+            if self._clocked:
+                late["cpu1_us"] = time.thread_time_ns() // 1000
             if late:
                 ann.set_metadata(**_stats(late))
             ann.__exit__(None, None, None)

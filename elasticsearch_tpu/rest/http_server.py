@@ -6,12 +6,22 @@ Netty4HttpServerTransport.java``) with an in-repo pure-Java NIO alternative
 threads in the request path, which matches the single-writer asyncio design
 of the node. Supports keep-alive, Content-Length bodies, and chunked
 transfer decoding (curl/clients use both).
+
+The loop's tick: while the server runs, one coroutine on the loop checks
+every 100 ms for a ``jax.profiler`` session. While one is active it
+sleeps 10 ms at a time and measures how late it woke, which is what a
+readable socket or a finished handler's future waits before the loop
+runs it, and leaves a ``host[loop]`` annotation (``common/tracing.py``'s
+twins) with ``lag_us``; every tenth also carries the CPU clocks of the
+process's Python threads summed by role (:func:`_role_clocks`).
 """
 
 from __future__ import annotations
 
 import asyncio
 import json
+import threading
+import time
 from typing import Callable, Optional, Tuple
 
 from ..common import tracing as _tracing
@@ -23,6 +33,52 @@ class HttpError(Exception):
     def __init__(self, status: int, reason: str):
         self.status = status
         self.reason = reason
+
+
+#: the loop's tick, seconds between wake-ups with a profiler session and
+#: without; the role clocks ride every ROLE_CLOCK_EVERY-th traced tick
+TICK_S = 0.01
+IDLE_TICK_S = 0.1
+ROLE_CLOCK_EVERY = 10
+#: thread-name prefixes of the roles the tick sums CPU clocks by; any
+#: other thread but the loop's counts as ``cpu_other_us``
+_ROLES = (("es-rest-http", "cpu_pool_us"),
+          ("es-dispatcher-", "cpu_dispatch_us"))
+
+
+def _thread_clock_id(native_id: int) -> int:
+    """The kernel's CPU clock of one thread of this process, by its thread
+    id (Linux's ``MAKE_THREAD_CPUCLOCK(tid, CPUCLOCK_SCHED)``: the value
+    ``time.pthread_getcpuclockid`` returns). By id and not by pthread
+    handle: a foreign thread's ``_DummyThread`` stays listed after the
+    thread has ended, and its handle then points at memory that may be
+    gone, where the kernel refuses a dead id (``OSError``)."""
+    return (~native_id << 3) | 6
+
+
+def _role_clocks(loop_ident: int) -> dict:
+    """Each live Python thread's CPU clock in microseconds, summed by role:
+    the loop's own thread, the request pool, the micro-batcher's
+    dispatchers and every other thread; and ``threads``, how many were
+    read. A thread that has ended is left out."""
+    out = {"cpu_loop_us": 0, "cpu_pool_us": 0, "cpu_dispatch_us": 0,
+           "cpu_other_us": 0, "threads": 0}
+    for t in threading.enumerate():
+        if t.native_id is None:         # not started yet
+            continue
+        try:
+            us = time.clock_gettime_ns(_thread_clock_id(t.native_id)) \
+                // 1000
+        except OSError:                 # ended
+            continue
+        if t.ident == loop_ident:
+            role = "cpu_loop_us"
+        else:
+            role = next((r for p, r in _ROLES if t.name.startswith(p)),
+                        "cpu_other_us")
+        out[role] += us
+        out["threads"] += 1
+    return out
 
 
 _STATUS_TEXT = {200: "OK", 201: "Created", 400: "Bad Request",
@@ -47,11 +103,14 @@ class HttpServer:
         #: (the security layer authenticates from Authorization)
         self.pass_headers = pass_headers
         self._server: Optional[asyncio.AbstractServer] = None
+        self._tick_task: Optional[asyncio.Task] = None
 
     async def start(self) -> None:
         self._server = await asyncio.start_server(
             self._serve_connection, self.host, self.port,
             ssl=self.ssl_ctx)
+        self._tick_task = asyncio.get_running_loop().create_task(
+            self._tick())
         owner = getattr(self.handler, "__self__", None)
         if owner is not None and hasattr(owner, "http_publish_address"):
             # advertise the REAL bound socket (host may be 0.0.0.0 and
@@ -62,9 +121,34 @@ class HttpServer:
             owner.http_publish_address = f"{host}:{port}"
 
     async def stop(self) -> None:
+        if self._tick_task is not None:
+            self._tick_task.cancel()
+            try:
+                await self._tick_task
+            except asyncio.CancelledError:
+                pass
+            self._tick_task = None
         if self._server is not None:
             self._server.close()
             await self._server.wait_closed()
+
+    async def _tick(self) -> None:
+        """The loop's tick (module docstring): runs until :meth:`stop`."""
+        loop = asyncio.get_running_loop()
+        me = threading.get_ident()
+        n = 0
+        while True:
+            if not _tracing.TraceAnnotation.is_enabled():
+                await asyncio.sleep(IDLE_TICK_S)
+                continue
+            due = loop.time() + TICK_S
+            await asyncio.sleep(TICK_S)
+            lag_us = int((loop.time() - due) * 1e6)
+            with _tracing.TraceAnnotation("host[loop]",
+                                          lag_us=lag_us) as ann:
+                if n % ROLE_CLOCK_EVERY == 0:
+                    ann.set_metadata(**_role_clocks(me))
+            n += 1
 
     async def _serve_connection(self, reader: asyncio.StreamReader,
                                 writer: asyncio.StreamWriter) -> None:

@@ -334,8 +334,10 @@ def test_prometheus_endpoint_exemplar_opt_in(api_with_index):
     st2, ct2, p2 = api.handle("GET", "/_prometheus/metrics",
                               "exemplars=true", b"")
     assert st2 == 200 and ct2.startswith("application/openmetrics-text")
+    # this index's line: the registry is the process's, and indices of
+    # earlier tests in the same process may sort before it
     lat = [ln for ln in p2.decode().splitlines()
-           if ln.startswith("es_query_latency_ms{")
+           if ln.startswith('es_query_latency_ms{index="attr",')
            and 'quantile="0.99"' in ln]
     assert lat and " # {trace_id=" in lat[0]
 
