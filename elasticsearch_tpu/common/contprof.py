@@ -24,9 +24,18 @@ carries:
   stamps the active batch's dominant (tenant, shape) around each
   dispatch (:func:`bind_dispatch`) — the slots captured both on the
   request thread at enqueue.
-- **idle/busy** — one classifier (:func:`classify_idle`) shared with
-  ``utils/hot_threads`` (which re-exports it), so the two samplers can
-  never disagree about what "parked" means.
+- **idle/busy** — one decision on the innermost two frames, made by
+  :func:`classify_idle` on ``utils/hot_threads``' full listing (which
+  re-exports it) and by the sampler's frame walk on code objects, so
+  the two samplers can never disagree about what "parked" means.
+
+The sampler reads no source lines: it walks ``f_back`` from each
+thread's frame and labels frames by their code objects (``co_filename``,
+``co_name``), each code object's label and idle traits computed once
+and kept. A parked thread costs two frame reads; only busy threads are
+walked to ``STACK_DEPTH``. ``traceback.extract_stack`` (``linecache``:
+an ``os.stat`` per distinct file and a line read per frame) stays on
+the on-demand hot-threads path, whose output carries line numbers.
 
 Windows rotate current→previous on the insights cadence
 (``contprof.window_seconds``); the trie is node-capped
@@ -84,8 +93,12 @@ SETTING_MAX_NODES = CLUSTER_SETTINGS.register(
                         scope="cluster", dynamic=False, min_value=64))
 
 #: frames kept per sampled stack (innermost) — bounds both the
-#: per-sample extract cost and the trie depth
+#: per-sample walk and the trie depth
 STACK_DEPTH = 24
+
+#: code objects whose traits the sampler keeps; past it the map starts
+#: over (code made at run time must not grow it without end)
+_TRAITS_CAP = 8192
 
 #: default row cap for the REST endpoint / capture slice
 DEFAULT_LIMIT = 256
@@ -149,9 +162,17 @@ _WAITER_NAMES = ("wait", "acquire", "select", "poll", "join", "sleep",
                  "get", "recv", "accept", "epoll")
 
 
-def _is_waiter_frame(fs: traceback.FrameSummary) -> bool:
-    return any(r in fs.filename for r in _RUNTIME_FILES) and \
-        any(w in fs.name for w in _WAITER_NAMES)
+def _parks_innermost(filename: str, name: str) -> bool:
+    """An innermost frame here means the thread is parked."""
+    probe = f"{filename}:{name}"
+    return any(h in probe for h in IDLE_HINTS)
+
+
+def _is_waiter(filename: str, name: str) -> bool:
+    """A strict runtime waiter: one frame outward of it, a thread is
+    parked whatever runs innermost."""
+    return any(r in filename for r in _RUNTIME_FILES) and \
+        any(w in name for w in _WAITER_NAMES)
 
 
 def classify_idle(stack: List[traceback.FrameSummary]) -> bool:
@@ -169,19 +190,58 @@ def classify_idle(stack: List[traceback.FrameSummary]) -> bool:
     if not stack:
         return True
     top = stack[-1]
-    probe = f"{top.filename}:{top.name}"
-    if any(h in probe for h in IDLE_HINTS):
+    if _parks_innermost(top.filename, top.name):
         return True
-    if len(stack) >= 2 and _is_waiter_frame(stack[-2]):
-        return True
-    return False
+    return len(stack) >= 2 and _is_waiter(stack[-2].filename,
+                                          stack[-2].name)
+
+
+def _code_traits(code, traits: dict) -> tuple:
+    """``(label, parks innermost, waiter)`` of one code object, computed
+    once and kept in ``traits``; the label is the folded
+    ``<basename>:<co_name>``."""
+    t = traits.get(code)
+    if t is None:
+        filename, name = code.co_filename, code.co_name
+        t = (f"{filename.rsplit('/', 1)[-1]}:{name}",
+             _parks_innermost(filename, name), _is_waiter(filename, name))
+        if len(traits) >= _TRAITS_CAP:
+            traits.clear()
+        traits[code] = t
+    return t
+
+
+def _walk_stack(frame, traits: dict) -> tuple:
+    """One thread's sample without source lines: ``(idle, labels,
+    frames_read)``. :func:`classify_idle`'s decision on the innermost
+    two frames comes first; a parked thread is walked no further and
+    has no labels. A busy one gives the labels of its innermost
+    ``STACK_DEPTH`` frames, outermost first, as
+    ``traceback.extract_stack(frame, limit=STACK_DEPTH)`` orders them."""
+    top = _code_traits(frame.f_code, traits)
+    if top[1]:
+        return True, None, 1
+    back = frame.f_back
+    if back is None:
+        return False, [top[0]], 1
+    below = _code_traits(back.f_code, traits)
+    if below[2]:
+        return True, None, 2
+    labels = [top[0], below[0]]
+    f = back.f_back
+    while f is not None and len(labels) < STACK_DEPTH:
+        labels.append(_code_traits(f.f_code, traits)[0])
+        f = f.f_back
+    labels.reverse()
+    return False, labels, len(labels)
 
 
 def sample_stacks(limit: Optional[int] = None) \
         -> Dict[int, List[traceback.FrameSummary]]:
     """One pass over every live Python thread: ``{ident: stack}`` with
-    frames outermost-first (innermost ``limit`` frames kept). The ONE
-    sampling core shared by the continuous sampler and hot_threads."""
+    frames outermost-first (innermost ``limit`` frames kept), source
+    lines included: hot_threads' full listing. The continuous sampler
+    walks code objects instead (:func:`_walk_stack`)."""
     out: Dict[int, List[traceback.FrameSummary]] = {}
     for tid, frame in sys._current_frames().items():
         try:
@@ -438,6 +498,8 @@ class ContinuousProfiler:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._duty = 0.0
+        #: code object -> (label, parks innermost, waiter)
+        self._traits: dict = {}
         if registry is None:
             from . import telemetry as _tm
             registry = _tm.DEFAULT
@@ -509,6 +571,7 @@ class ContinuousProfiler:
         t = now if now is not None else self.clock()
         t0 = time.perf_counter()
         me = threading.get_ident()
+        traits = self._traits
         frames = sys._current_frames()
         names = {th.ident: th.name for th in threading.enumerate()}
         roles, reqs, disp = _attribution_snapshot(frames)
@@ -520,12 +583,11 @@ class ContinuousProfiler:
                 if tid == me:
                     continue
                 try:
-                    stack = traceback.extract_stack(frame,
-                                                    limit=STACK_DEPTH)
+                    idle, labels, _n = _walk_stack(frame, traits)
                 except Exception:   # noqa: BLE001 — torn frame
                     continue
                 n_seen += 1
-                if classify_idle(stack):
+                if idle:
                     win.idle += 1
                     continue
                 tenant = shape = None
@@ -543,9 +605,7 @@ class ContinuousProfiler:
                         role = "rest"
                 if role is None:
                     role = thread_role(tid, names.get(tid, ""))
-                path = (role, tenant or "-", shape or "-") + tuple(
-                    f"{fs.filename.rsplit('/', 1)[-1]}:{fs.name}"
-                    for fs in stack)
+                path = (role, tenant or "-", shape or "-") + tuple(labels)
                 if win.fold(path, self.cap):
                     n_busy += 1
                 else:

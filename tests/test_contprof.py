@@ -8,8 +8,10 @@ formats, cluster merge), the ``flame_dump`` renderer, and the
 """
 
 import ast
+import functools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -120,6 +122,140 @@ def test_hot_threads_uses_shared_classifier_and_keeps_format():
     assert "Hot threads at" in out and "cpu usage by thread" in out
     assert "es-repack-ht-busy" in out
     assert "es-warmup-ht-parked" not in out
+
+
+# ---------------------------------------------------------------------------
+# the sampler's frame walk: code objects, no source lines
+# ---------------------------------------------------------------------------
+
+
+def _nest(depth, fn, *args):
+    """Call ``fn`` ``depth`` frames below here."""
+    if depth:
+        return _nest(depth - 1, fn, *args)
+    return fn(*args)
+
+
+def _hold_busy(gate):
+    """Blocked inside a C call with an application frame innermost: the
+    sampler sees a busy thread whose stack stands still."""
+    gate.acquire()
+    gate.release()
+    return True
+
+
+def _inverted_park(cond, gate):
+    # Condition.wait_for runs the predicate from threading.py, so the
+    # application frame is innermost and the runtime waiter one outward
+    with cond:
+        cond.wait_for(functools.partial(_hold_busy, gate))
+
+
+def _start_named(name, target, *args):
+    t = threading.Thread(target=target, args=args, name=name, daemon=True)
+    t.start()
+    return t
+
+
+def _reference_pass(frames, win):
+    """The sampler's fold as it was built on ``traceback.extract_stack``
+    (source lines and all), for the equivalence check."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    for tid, frame in frames.items():
+        stack = traceback.extract_stack(frame, limit=contprof.STACK_DEPTH)
+        if classify_idle(stack):
+            win.idle += 1
+            continue
+        path = (contprof.thread_role(tid, names.get(tid, "")), "-", "-") \
+            + tuple(f"{fs.filename.rsplit('/', 1)[-1]}:{fs.name}"
+                    for fs in stack)
+        win.fold(path, cap=1 << 16)
+
+
+def test_frame_walk_folds_what_extract_stack_folds(monkeypatch):
+    """Threads parked below and above STACK_DEPTH, busy below and above
+    it, and one parked under the inverted listing: one pass of the
+    code-object walk folds the same rows and counts the same idle
+    threads as a pass built on ``traceback.extract_stack``."""
+    depth = contprof.STACK_DEPTH
+    ev = threading.Event()
+    gate = threading.Lock()
+    gate.acquire()
+    cond = threading.Condition()
+    threads = [
+        _start_named("es-warmup-shallow", _nest, 3, ev.wait),
+        _start_named("es-warmup-deep", _nest, depth + 10, ev.wait),
+        _start_named("es-repack-shallow", _nest, 2, _hold_busy, gate),
+        _start_named("es-repack-deep", _nest, depth + 7, _hold_busy, gate),
+        _start_named("es-dispatcher-inv", _nest, 4, _inverted_park, cond,
+                     gate),
+    ]
+    try:
+        time.sleep(0.1)
+        frames = sys._current_frames()
+        live = {t.ident: frames[t.ident] for t in threads}
+        ref = _Window(started=0.0)
+        _reference_pass(live, ref)
+        monkeypatch.setattr(contprof.sys, "_current_frames", lambda: live)
+        prof = ContinuousProfiler(interval_ms_=50.0, cap=1 << 16)
+        assert prof.sample_once() == 2
+        win = prof._current
+        assert ref.idle == win.idle == 3
+        assert ref.busy == win.busy == 2
+        assert sorted(win.rows()) == sorted(ref.rows())
+        deep = [p for p, _n in win.rows() if p[0] == "repack"
+                and len(p) == 3 + depth]
+        assert deep and deep[0][-1] == "test_contprof.py:_hold_busy"
+        idle, labels, read = contprof._walk_stack(
+            live[threads[4].ident], {})
+        assert idle and labels is None and read == 2
+    finally:
+        ev.set()
+        gate.release()
+        for t in threads:
+            t.join(timeout=2)
+
+
+def test_parked_pass_reads_no_source_and_two_frames(monkeypatch):
+    """Cost by calls, not time: a pass over 64 parked threads makes no
+    ``linecache`` line read or cache check and no ``os.stat``, and each
+    parked thread's walk reads at most its innermost two frames."""
+    import linecache
+
+    ev = threading.Event()
+    threads = [_start_named(f"es-rest-http-park{i}", _nest, 30, ev.wait)
+               for i in range(64)]
+    me = threading.get_ident()
+    calls = []
+
+    def recorder(name, real):
+        def wrapped(*a, **kw):
+            if threading.get_ident() == me:
+                calls.append(name)
+            return real(*a, **kw)
+        return wrapped
+
+    try:
+        time.sleep(0.1)
+        for name, mod in (("getline", linecache), ("checkcache", linecache),
+                          ("lazycache", linecache), ("stat", os)):
+            monkeypatch.setattr(mod, name, recorder(name, getattr(mod,
+                                                                  name)))
+        prof = ContinuousProfiler(interval_ms_=50.0)
+        prof.sample_once()
+        monkeypatch.undo()
+        assert calls == []
+        assert prof._current.idle >= 64
+        frames = sys._current_frames()
+        traits: dict = {}
+        for t in threads:
+            idle, labels, read = contprof._walk_stack(frames[t.ident],
+                                                      traits)
+            assert idle and labels is None and read <= 2
+    finally:
+        ev.set()
+        for t in threads:
+            t.join(timeout=2)
 
 
 # ---------------------------------------------------------------------------
@@ -418,6 +554,12 @@ def test_flamegraph_workload_attributes_heavy_tenant(
     against the query-insights top shape — in the dispatcher or rest
     pool."""
     api = api_with_corpus
+    # tenant B's match_all compiles its program on first use (~0.6 s of
+    # rest samples on a CPU backend); warm it before the sampler starts
+    # so the window holds what each tenant's traffic costs, not a compile
+    st, _ct, p = api.handle("POST", "/prof/_search", "",
+                            json.dumps({"query": {"match_all": {}}}).encode())
+    assert st == 200, p
     monkeypatch.setenv("ES_TPU_CONTPROF", "1")
     monkeypatch.setenv("ES_TPU_CONTPROF_INTERVAL_MS", "2")
     contprof.close_profiler()
